@@ -1,8 +1,8 @@
 """``python -m bifromq_tpu --config conf.yml`` — standalone broker CLI."""
 
-from .utils.jaxenv import pin_jax_platform
+from .utils.jaxenv import setup_compile_cache
 
-pin_jax_platform()
+setup_compile_cache()
 
 from .starter import main  # noqa: E402
 

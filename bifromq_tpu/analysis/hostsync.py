@@ -51,10 +51,6 @@ HOT_SCOPES: Dict[str, Set[str]] = {
     # + device-hash split, wildcard kind lanes post-masked on device)
     "ops/tokenize.py": {"_hash_lanes", "hash_topics_device",
                         "device_tokenize", "device_tokenize_filters"},
-    # (+ ISSUE 19: the pallas expansion kernel body + its dispatch
-    # wrapper — the device fan-out twin of the fused walk)
-    "models/kernels.py": {"_build_fused", "fused_walk_routes",
-                          "_build_expand", "pallas_expand"},
     # ISSUE 12: the standby's per-batch device flush runs after every
     # applied delta batch — it must stay a pure dispatch wrapper (the
     # narrow scatters live in ops/match, already covered above)

@@ -14,9 +14,7 @@ import argparse
 import asyncio
 import sys
 
-from ..utils.jaxenv import pin_jax_platform
-
-pin_jax_platform()
+from ..utils.jaxenv import setup_compile_cache
 
 
 def _prewarm_serving_jit() -> None:
@@ -41,8 +39,9 @@ def _prewarm_serving_jit() -> None:
             receiver_id="r0", deliverer_key="d0", incarnation=1))
         m.refresh()
         m.match_batch([("_warm", "w/a/x")])
-    except Exception:
-        pass
+    except Exception:  # noqa: BLE001 — the first match then runs cold
+        import logging
+        logging.getLogger(__name__).exception("serving-jit pre-warm failed")
 
 
 async def serve(args) -> None:
@@ -88,6 +87,7 @@ def main(argv=None) -> None:
     p.add_argument("--node-id", default="worker0")
     p.add_argument("--data-dir", default="")
     args = p.parse_args(argv)
+    setup_compile_cache()
     try:
         asyncio.run(serve(args))
     except KeyboardInterrupt:

@@ -21,9 +21,7 @@ import asyncio
 import os
 import sys
 
-from ..utils.jaxenv import pin_jax_platform
-
-pin_jax_platform()
+from ..utils.jaxenv import setup_compile_cache
 
 
 def _coproc_factory(kind: str):
@@ -115,6 +113,7 @@ def main(argv=None) -> None:
     ap.add_argument("--data-dir", default="")
     ap.add_argument("--tick-interval", type=float, default=0.02)
     args = ap.parse_args(argv)
+    setup_compile_cache()
     try:
         asyncio.run(amain(args))
     except KeyboardInterrupt:

@@ -3,9 +3,8 @@
 The two headline ROADMAP items — real-TPU validation of the async
 pipeline and the 10M-sub sharded matcher — are capacity questions before
 they are performance questions: "will this tenant population's automaton
-tables fit in HBM on this shard" and "can the fused kernel's VMEM gate
-ever pass at this size" are answered today by dispatching and watching
-for OOMs (the fused 12MB auto-gate vs the ~67MB 1M-sub edge table).
+tables fit in HBM on this shard" is answered today by dispatching and
+watching for OOMs.
 Tailwind (PAPERS.md) argues accelerator systems need a first-class
 capacity/placement model instead; TrieJax's relational formulation makes
 trie footprints exactly computable from arena shapes. This module is
@@ -22,8 +21,7 @@ that model:
   a subscription count that has never been built, from per-subscription
   coefficients — calibrated from any live ``CompiledTrie`` or defaulting
   to the repo's measured 1M-wildcard-sub build — and renders the HBM
-  headroom verdict and the fused-kernel VMEM verdict using the *same*
-  comparison ``models.kernels.fused_enabled`` applies at dispatch time.
+  headroom verdict.
 - **Validation**: ``measure()`` reads the actually-uploaded device
   arrays, so ``GET /capacity`` can report model-vs-live parity (the
   tier-2 gate requires <10% error; the shape math makes it exact).
@@ -73,11 +71,9 @@ def compiled_trie_device_bytes(ct) -> Dict[str, int]:
     return out
 
 
-def fused_bytes_from_compiled(ct) -> int:
-    """The bytes the fused-kernel VMEM gate weighs for this base —
-    edge_tab + route_tab, the two tables ``models.kernels._table_bytes``
-    sums on the live DeviceTrie — computed host-side from shapes so the
-    verdict needs no device upload."""
+def walk_bytes_from_compiled(ct) -> int:
+    """edge_tab + route_tab bytes of this base — the two tables the
+    interval walk gathers from — computed host-side from shapes."""
     from ..ops.match import RT_COLS
     return (int(ct.edge_tab.size) + int(ct.node_tab.shape[0]) * RT_COLS) \
         * _I32
@@ -102,7 +98,7 @@ def sharded_tables_device_bytes(tables) -> Dict[str, object]:
     for i, ct in enumerate(tables.compiled):
         # the shard's REAL rows vs its padded slice: padding waste is the
         # price of one common mesh shape (build_sharded pads to the max)
-        real = fused_bytes_from_compiled(ct) \
+        real = walk_bytes_from_compiled(ct) \
             + int(ct.child_list.shape[0]) * _I32
         per_shard.append({
             "shard": i,
@@ -212,8 +208,6 @@ def measure(matcher) -> Dict[str, object]:
             max_levels=matcher.max_levels,
             max_intervals=getattr(matcher, "max_intervals", 32),
             ring_depth=ring.depth)
-    if kind == "single":
-        out["fused_table_bytes"] = fused_bytes_from_compiled(base)
     return out
 
 
@@ -294,14 +288,14 @@ class CapacityPlanner:
         return out
 
     def fits(self, n_subs: int, mesh: Optional[object] = None,
-             fused: Optional[bool] = None, *, batch: int = 16,
+             *, batch: int = 16,
              max_levels: int = 16, probe_len: int = 16,
              max_intervals: int = 32, ring_depth: Optional[int] = None,
              donated: Optional[bool] = None,
              hbm_limit_bytes: Optional[int] = None) -> Dict[str, object]:
         """The planner verdict: would ``n_subs`` subscriptions fit this
-        device (or each shard of ``mesh``), and would the fused kernel's
-        VMEM auto-gate pass — WITHOUT building or dispatching anything.
+        device (or each shard of ``mesh``) — WITHOUT building or
+        dispatching anything.
 
         ``mesh`` is ``None`` (single chip), an ``int`` shard count, or a
         ``(replicas, shards)`` tuple / ``jax.sharding.Mesh``. The HBM
@@ -310,9 +304,7 @@ class CapacityPlanner:
         double (old and new base both alive across a compaction swap) —
         against ``hbm_limit_bytes`` (default: the live device's
         ``memory_stats`` limit when probeable, else the
-        ``BIFROMQ_HBM_BYTES`` env knob, else unknown). The fused VMEM
-        verdict applies the same ``table_bytes <= budget`` comparison
-        ``models.kernels.fused_enabled`` runs per dispatch.
+        ``BIFROMQ_HBM_BYTES`` env knob, else unknown).
         """
         n_shards = 1
         n_replicas = 1
@@ -340,12 +332,6 @@ class CapacityPlanner:
             hbm_limit_bytes = _live_hbm_limit()
         headroom = (hbm_limit_bytes - peak
                     if hbm_limit_bytes is not None else None)
-        fused_tb = tables["edge_tab"] + tables["route_tab"]
-        from ..models.kernels import (fused_fits_vmem,
-                                      fused_vmem_budget_bytes)
-        vmem_budget = fused_vmem_budget_bytes()
-        # the exact comparison the dispatch-time gate applies
-        vmem_fits = fused_fits_vmem(fused_tb)
         return {
             "n_subs": n_subs,
             "mesh": {"replicas": n_replicas, "shards": n_shards},
@@ -358,16 +344,6 @@ class CapacityPlanner:
                 "limit_bytes": hbm_limit_bytes,
                 "headroom_bytes": headroom,
                 "fits": (headroom >= 0 if headroom is not None else None),
-            },
-            "fused_vmem": {
-                "table_bytes": fused_tb,
-                "budget_bytes": vmem_budget,
-                "fits": vmem_fits,
-                # why: the gate also needs a TPU backend; `fits` answers
-                # only the capacity half the planner owns
-                "note": ("auto mode additionally requires a TPU backend"
-                         if fused is None else
-                         ("forced on" if fused else "killed by env")),
             },
         }
 
@@ -515,7 +491,7 @@ def record_compile_event(base, *, reason: str, duration_s: float,
                          salt=None,
                          generation_bumped: bool = False) -> None:
     """Stamp one base build into the process compile ledger — the ONE
-    site deriving a ledger event's table bytes + fused-VMEM verdict
+    site deriving a ledger event's table bytes
     from a compiled base (single-chip or mesh). Matcher installs and
     bench builds both route here, so their records cannot diverge.
     Best-effort: accounting must never fail a build."""
@@ -525,22 +501,19 @@ def record_compile_event(base, *, reason: str, duration_s: float,
             tb = sharded_tables_device_bytes(base)["total"]["total"]
             n_nodes = sum(int(c.node_tab.shape[0])
                           for c in base.compiled)
-            vmem = None
             kind = "mesh"
             if salt is None:
                 salt = tuple(getattr(c, "salt", None)
                              for c in base.compiled)
         else:                                # single-chip CompiledTrie
-            from ..models.kernels import fused_fits_vmem
             tb = compiled_trie_device_bytes(base)["total"]
             n_nodes = base.n_nodes
-            vmem = fused_fits_vmem(fused_bytes_from_compiled(base))
             kind = "single"
             if salt is None:
                 salt = base.salt
         OBS.profiler.ledger.record(
             reason=reason, duration_s=duration_s, salt=salt,
-            n_nodes=n_nodes, table_bytes=tb, vmem_fits=vmem,
+            n_nodes=n_nodes, table_bytes=tb,
             generation_bumped=generation_bumped, kind=kind)
     except Exception:  # noqa: BLE001 — telemetry must not raise
         pass
@@ -550,9 +523,8 @@ def digest_capacity(hub) -> Dict[str, object]:
     """The compact capacity field gossiped in the health digest (ISSUE 8:
     ``GET /cluster/capacity`` federates these — no extra RPC plane).
     Host-side shape math + cached watermarks only: the digest refresh
-    must never block on the device tunnel."""
+    must never block on the device."""
     table_bytes = 0
-    vmem_fits: Optional[bool] = None
     logical: List[Tuple[str, int]] = []
     for m in hub.device.matchers():
         # ISSUE 9 satellite (PR 8 follow-up): dedup-aware LOGICAL
@@ -571,9 +543,6 @@ def digest_capacity(hub) -> Dict[str, object]:
                     base)["total"]["total"]
             else:
                 table_bytes += compiled_trie_device_bytes(base)["total"]
-                from ..models.kernels import fused_fits_vmem
-                ok = fused_fits_vmem(fused_bytes_from_compiled(base))
-                vmem_fits = ok if vmem_fits is None else (vmem_fits and ok)
         except Exception:  # noqa: BLE001 — telemetry must not raise
             continue
     out: Dict[str, object] = {"table_bytes": table_bytes,
@@ -585,8 +554,6 @@ def digest_capacity(hub) -> Dict[str, object]:
         for tenant_id, c in sorted(logical):
             h.update(f"{tenant_id}:{c};".encode("utf-8"))
         out["subs_fp"] = h.hexdigest()
-    if vmem_fits is not None:
-        out["vmem_fits"] = vmem_fits
     limit = _env_int("BIFROMQ_HBM_BYTES", 0)
     if limit > 0:
         out["hbm_limit_bytes"] = limit

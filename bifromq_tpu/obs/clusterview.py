@@ -769,7 +769,7 @@ class ClusterView:
     def capacity_table(self) -> dict:
         """``GET /cluster/capacity`` (ISSUE 8): per-node device capacity
         federated from the gossiped digests — automaton table bytes,
-        memory watermarks, fused-VMEM verdicts — plus cluster totals.
+        memory watermarks — plus cluster totals.
         Pure digest reads: no scatter-gather RPC, a dead node's row just
         goes stale with its digest."""
         from .capacity import digest_capacity

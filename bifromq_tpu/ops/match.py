@@ -224,13 +224,10 @@ def _bitonic_desc(x: jax.Array) -> jax.Array:
         pad = jnp.full((x.shape[0], n - orig), jnp.iinfo(jnp.int32).min,
                        dtype=x.dtype)
         x = jnp.concatenate([x, pad], axis=1)
-    # partner/direction WITHOUT numpy closure constants (the Pallas
-    # tracer rejects captured arrays — this one body serves both the lax
-    # walk and the fused kernel, models/kernels.py): the lane^step
-    # exchange is a REGULAR blocked swap, so it lowers as reshape + a
-    # static reversed slice (vector shuffles, no gather), and the
-    # direction mask is elementwise on an iota — lane < (lane^step) iff
-    # lane's step-bit is 0 — which XLA constant-folds.
+    # the lane^step exchange is a REGULAR blocked swap, so it lowers as
+    # reshape + a static reversed slice (vector shuffles, no gather), and
+    # the direction mask is elementwise on an iota — lane < (lane^step)
+    # iff lane's step-bit is 0 — which XLA constant-folds.
     b = x.shape[0]
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
     stage = 2
@@ -870,8 +867,10 @@ def warm_patch_scatter(shapes: tuple, *, device=None,
                 dummy = jax.device_put(jnp.zeros(shape, dtype),
                                        device=device)
                 _scatter_rows_donated(dummy, idx, rows)
-        except Exception:  # noqa: BLE001 — per-table best-effort: one
-            continue       # failed class must not abort the rest
+        except Exception:  # noqa: BLE001 — one failed class must not
+            # abort the rest; the first flush of that class traces lazily
+            from ..utils.metrics import warmup_failed
+            warmup_failed(f"patch-scatter {shape} {dtype}")
 
 
 def _expand_lib():
@@ -969,8 +968,7 @@ N_SENTINEL_BUCKETS = 2
 
 def device_expand_mode() -> str:
     """``BIFROMQ_DEVICE_EXPAND``: ``0`` host expansion (PR-18 behavior),
-    ``1`` force device expansion, ``auto`` (default) device expansion on —
-    the lax path everywhere, the Pallas expand kernel stage on real TPU."""
+    ``1``/``auto`` (default) device expansion on."""
     from ..utils.env import env_str
     mode = env_str("BIFROMQ_DEVICE_EXPAND", "auto").strip().lower()
     return mode if mode in ("0", "1", "auto") else "auto"
@@ -1141,18 +1139,12 @@ def expand_pairs(ivl_start: jax.Array, ivl_count: jax.Array, *, cap: int):
     return _expand_pairs(ivl_start, ivl_count, cap)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("cap", "n_peers", "use_kernel"))
+@functools.partial(jax.jit, static_argnames=("cap", "n_peers"))
 def _expand_routes_fn(ivl_s, ivl_c, overflow, slot_peer, *,
-                      cap: int, n_peers: int, use_kernel: bool):
+                      cap: int, n_peers: int):
     serve_c = jnp.where(overflow[:, None], 0, ivl_c)
-    if use_kernel:
-        from ..models import kernels   # lazy: kernels imports this module
-        slots, rows, row_offsets, n_pairs, trunc = kernels.pallas_expand(
-            ivl_s, serve_c, cap=cap)
-    else:
-        slots, rows, row_offsets, n_pairs, trunc = _expand_pairs(
-            ivl_s, serve_c, cap)
+    slots, rows, row_offsets, n_pairs, trunc = _expand_pairs(
+        ivl_s, serve_c, cap)
     if n_peers == 0:
         # structurally bucketed already: with no named peers every live
         # pair lands in UNKNOWN, and _expand_pairs emits live pairs as a
@@ -1175,20 +1167,17 @@ def _expand_routes_fn(ivl_s, ivl_c, overflow, slot_peer, *,
 
 
 def expand_routes(ivl: RouteIntervals, slot_peer, *, cap: int,
-                  n_peers: int, use_kernel=None) -> ExpandedRoutes:
+                  n_peers: int) -> ExpandedRoutes:
     """The serving expansion stage: walk intervals -> peer-bucketed pairs.
 
     Walk-overflow rows spend no buffer (their grids are junk and the host
     re-matches them regardless); their raw counts stay visible in
     ``.count`` for the escalation leg.
     """
-    if use_kernel is None:
-        from ..models.kernels import expand_kernel_enabled
-        use_kernel = expand_kernel_enabled()
     (slots, rows, row_offsets, n_pairs, trunc, peer_slots, peer_rows,
      peer_offsets) = _expand_routes_fn(
         ivl.start, ivl.count, ivl.overflow, slot_peer,
-        cap=cap, n_peers=n_peers, use_kernel=bool(use_kernel))
+        cap=cap, n_peers=n_peers)
     if peer_slots is None:      # n_peers == 0: alias, don't copy
         peer_slots, peer_rows = slots, rows
     # the interval grids ride along from the caller's arrays — routing

@@ -4,9 +4,10 @@ The host byte plane (``models/bytetok.py``) already removes per-row
 Python from topic prep; this module removes the HASH from the host
 entirely: the raw topic bytes ship to device as one ``[B, MAX_BYTES]``
 uint8 block plus the per-lane level boundaries (tiny int32 grids), and a
-kernel computes the ``Probes`` h1/h2 token lanes on device — at serving
-scale only bytes cross the tunnel, the "accelerator-side trie matching
-from raw token streams" move of "Vectorizing the Trie" (PAPERS.md).
+jit'd program computes the ``Probes`` h1/h2 token lanes on device — at
+serving scale only bytes cross the host↔device link, the
+"accelerator-side trie matching from raw token streams" move of
+"Vectorizing the Trie" (PAPERS.md).
 
 The kernel is BLAKE2b (RFC 7693) with digest_size=8 and the automaton
 salt, **bit-exact** with ``automaton.level_hash`` (the randomized parity
@@ -17,20 +18,16 @@ longer than one 128-byte block is unsupported by construction — the
 host marks such rows padding and they take the exact oracle fallback,
 the same bounded-work contract as the walk's overflow rows).
 
-Two lowering paths, same traced math:
-
-- ``pallas``: one ``pl.pallas_call`` over row tiles (grid streams
-  ``TILE_ROWS`` topics per program; interpret mode on CPU — a
-  correctness surface, not a serving surface, exactly like the fused
-  walk kernel's off-TPU story).
-- ``lax``: the plain jit'd twin, for A/B and as the lowering XLA can
-  fuse into the surrounding dispatch.
+One lowering: the plain jit'd XLA program (``_hash_lanes_lax``). The
+Pallas twin that used to sit beside it was refused by the v5e compiler
+(Mosaic has no lowering for its per-lane byte gather) and was deleted in
+PR 27; ``tests/test_tpu_compile.py`` keeps this program compiling for
+the chip.
 
 Deployment gate (``device_tokenize_enabled``): ``BIFROMQ_DEVICE_TOKENIZE``
-``0``/``off`` kills the path, ``1``/``on`` forces it on every backend
-(interpret-mode Pallas on CPU), unset/``auto`` enables it only on a real
-TPU backend — on CPU the native C++ tokenizer is the faster host, and
-interpreted Pallas would be a de-optimization.
+``0``/``off`` kills the path, ``1``/``on`` forces it on every backend,
+unset/``auto`` enables it only on a TPU backend — on CPU the native C++
+tokenizer is the faster host.
 """
 
 from __future__ import annotations
@@ -54,12 +51,6 @@ _LEVEL_BLOCK = bytetok.MAX_SINGLE_BLOCK_LEVEL   # 128: one BLAKE2b block
 _IV_LO = (bytetok.BLAKE2B_IV & np.uint64(0xFFFFFFFF)).astype(np.uint32)
 _IV_HI = (bytetok.BLAKE2B_IV >> np.uint64(32)).astype(np.uint32)
 
-# rows per pallas program: bounds the per-program VMEM working set
-# ([TILE, W, 128] gather blocks ≈ 0.5MB at W=17) while keeping the grid
-# short for realistic batches
-TILE_ROWS = 256
-
-
 def _mode() -> str:
     v = env_str("BIFROMQ_DEVICE_TOKENIZE", "auto").lower()
     if v in ("0", "off", "false"):
@@ -67,13 +58,6 @@ def _mode() -> str:
     if v in ("1", "on", "true"):
         return "on"
     return "auto"
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # noqa: BLE001 — backend init failure = no device
-        return False
 
 
 def device_tokenize_enabled() -> bool:
@@ -84,7 +68,7 @@ def device_tokenize_enabled() -> bool:
         return False
     if mode == "on":
         return True
-    return _on_tpu()
+    return jax.default_backend() == "tpu"
 
 
 def tok_max_bytes() -> int:
@@ -92,11 +76,6 @@ def tok_max_bytes() -> int:
     default 256 — MQTT spec allows 64KB but real topics are tens of
     bytes; longer rows take the host path via the padding contract)."""
     return max(_LEVEL_BLOCK, env_int("BIFROMQ_TOK_MAX_BYTES", 256))
-
-
-def _kernel_impl() -> str:
-    v = env_str("BIFROMQ_TOK_KERNEL", "pallas").lower()
-    return "lax" if v == "lax" else "pallas"
 
 
 # ------------------- 64-bit-as-uint32-pairs BLAKE2b ------------------------
@@ -149,34 +128,52 @@ def _hash_lanes(rows, starts, lens, nlv, h0lo, h0hi):
     shape = (b, w)
     def full(x):
         return jnp.broadcast_to(x, shape)
-    v = [(full(h0lo[0, i]), full(h0hi[0, i])) for i in range(8)]
-    v += [(full(iv_lo[i]), full(iv_hi[i])) for i in range(8)]
+    v0 = [(full(h0lo[0, i]), full(h0hi[0, i])) for i in range(8)]
+    v0 += [(full(iv_lo[i]), full(iv_hi[i])) for i in range(8)]
     t = lens.astype(jnp.uint32)                     # t0 (levels ≤ 128B)
-    v[12] = (v[12][0] ^ t, v[12][1])
-    v[14] = (~v[14][0], ~v[14][1])                  # final-block flag
+    v0[12] = (v0[12][0] ^ t, v0[12][1])
+    v0[14] = (~v0[14][0], ~v0[14][1])               # final-block flag
+    m_lo = jnp.stack([x[0] for x in m])             # [16, B, W]
+    m_hi = jnp.stack([x[1] for x in m])
+    sigma = jnp.asarray(bytetok.BLAKE2B_SIGMA, jnp.int32)
 
-    def g(a, bb, c, d, x, y):
-        v[a] = _add64(*_add64(*v[a], *v[bb]), *x)
-        v[d] = _rotr64(v[d][0] ^ v[a][0], v[d][1] ^ v[a][1], 32)
-        v[c] = _add64(*v[c], *v[d])
-        v[bb] = _rotr64(v[bb][0] ^ v[c][0], v[bb][1] ^ v[c][1], 24)
-        v[a] = _add64(*_add64(*v[a], *v[bb]), *y)
-        v[d] = _rotr64(v[d][0] ^ v[a][0], v[d][1] ^ v[a][1], 16)
-        v[c] = _add64(*v[c], *v[d])
-        v[bb] = _rotr64(v[bb][0] ^ v[c][0], v[bb][1] ^ v[c][1], 63)
+    # the 12 rounds run as a ROLLED loop (one compiled round body, the
+    # message schedule gathered per round): fully unrolled, the ~1,300
+    # dependent elementwise ops defeat XLA's fusion pass — the CPU
+    # backend grew past 40 GB without finishing the compile
+    def one_round(r, carry):
+        v_lo, v_hi = carry
+        order = sigma[r]
+        mr_lo, mr_hi = m_lo[order], m_hi[order]
+        v = [(v_lo[i], v_hi[i]) for i in range(16)]
 
-    for s in bytetok.BLAKE2B_SIGMA:
-        g(0, 4, 8, 12, m[s[0]], m[s[1]])
-        g(1, 5, 9, 13, m[s[2]], m[s[3]])
-        g(2, 6, 10, 14, m[s[4]], m[s[5]])
-        g(3, 7, 11, 15, m[s[6]], m[s[7]])
-        g(0, 5, 10, 15, m[s[8]], m[s[9]])
-        g(1, 6, 11, 12, m[s[10]], m[s[11]])
-        g(2, 7, 8, 13, m[s[12]], m[s[13]])
-        g(3, 4, 9, 14, m[s[14]], m[s[15]])
+        def g(a, bb, c, d, k):
+            x, y = (mr_lo[k], mr_hi[k]), (mr_lo[k + 1], mr_hi[k + 1])
+            v[a] = _add64(*_add64(*v[a], *v[bb]), *x)
+            v[d] = _rotr64(v[d][0] ^ v[a][0], v[d][1] ^ v[a][1], 32)
+            v[c] = _add64(*v[c], *v[d])
+            v[bb] = _rotr64(v[bb][0] ^ v[c][0], v[bb][1] ^ v[c][1], 24)
+            v[a] = _add64(*_add64(*v[a], *v[bb]), *y)
+            v[d] = _rotr64(v[d][0] ^ v[a][0], v[d][1] ^ v[a][1], 16)
+            v[c] = _add64(*v[c], *v[d])
+            v[bb] = _rotr64(v[bb][0] ^ v[c][0], v[bb][1] ^ v[c][1], 63)
 
-    out_lo = full(h0lo[0, 0]) ^ v[0][0] ^ v[8][0]
-    out_hi = full(h0hi[0, 0]) ^ v[0][1] ^ v[8][1]
+        g(0, 4, 8, 12, 0)
+        g(1, 5, 9, 13, 2)
+        g(2, 6, 10, 14, 4)
+        g(3, 7, 11, 15, 6)
+        g(0, 5, 10, 15, 8)
+        g(1, 6, 11, 12, 10)
+        g(2, 7, 8, 13, 12)
+        g(3, 4, 9, 14, 14)
+        return (jnp.stack([x[0] for x in v]), jnp.stack([x[1] for x in v]))
+
+    v_lo, v_hi = jax.lax.fori_loop(
+        0, len(bytetok.BLAKE2B_SIGMA), one_round,
+        (jnp.stack([x[0] for x in v0]), jnp.stack([x[1] for x in v0])))
+
+    out_lo = full(h0lo[0, 0]) ^ v_lo[0] ^ v_lo[8]
+    out_hi = full(h0hi[0, 0]) ^ v_hi[0] ^ v_hi[8]
     lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     active = lane < nlv          # nlv == -1 padding rows mask everything
     h1 = jnp.where(active, out_lo.astype(jnp.int32), 0)
@@ -187,77 +184,19 @@ def _hash_lanes(rows, starts, lens, nlv, h0lo, h0hi):
 _hash_lanes_lax = jax.jit(_hash_lanes)
 
 
-@functools.lru_cache(maxsize=32)
-def _build_pallas(b: int, mb: int, w: int, tile: int, interpret: bool):
-    """One compiled pallas tokenizer per shape class (jit-cache analog,
-    same idiom as models/kernels._build_fused)."""
-    from jax.experimental import pallas as pl
-
-    def kernel(rows_ref, starts_ref, lens_ref, nlv_ref, h0lo_ref,
-               h0hi_ref, h1_ref, h2_ref):
-        h1, h2 = _hash_lanes(rows_ref[...], starts_ref[...],
-                             lens_ref[...], nlv_ref[...],
-                             h0lo_ref[...], h0hi_ref[...])
-        h1_ref[...] = h1
-        h2_ref[...] = h2
-
-    grid = (b // tile,)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile, mb), lambda i: (i, 0)),
-            pl.BlockSpec((tile, w), lambda i: (i, 0)),
-            pl.BlockSpec((tile, w), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 8), lambda i: (0, 0)),
-            pl.BlockSpec((1, 8), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile, w), lambda i: (i, 0)),
-            pl.BlockSpec((tile, w), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, w), jnp.int32),
-            jax.ShapeDtypeStruct((b, w), jnp.int32),
-        ],
-        interpret=interpret,
-    )
-
-
 def hash_topics_device(rows, starts, lens, nlv, salt: int, *,
-                       device=None, impl: Optional[str] = None):
+                       device=None):
     """Upload the packed byte batch and hash every level on device.
 
     All transfers are explicit ``device_put`` (the transfer-guard
     sanitizer proves the byte plane ships only declared bytes). Returns
     (h1, h2) device arrays [B, W] int32."""
-    if impl is None:
-        impl = _kernel_impl()
     h0 = bytetok.blake2b8_h0(salt)
     h0lo = (h0 & np.uint64(0xFFFFFFFF)).astype(np.uint32).reshape(1, 8)
     h0hi = (h0 >> np.uint64(32)).astype(np.uint32).reshape(1, 8)
     put = functools.partial(jax.device_put, device=device)
-    args = (put(rows), put(starts), put(lens), put(nlv), put(h0lo),
-            put(h0hi))
-    if impl == "lax":
-        return _hash_lanes_lax(*args)
-    b, mb = rows.shape
-    w = starts.shape[1]
-    tile = min(TILE_ROWS, b)
-    if b % tile:
-        # the grid streams whole tiles: pad ragged batches up (padding
-        # rows carry nlv == -1, so every padded lane masks to zero) and
-        # slice the outputs back
-        from ..models.automaton import pad_rows
-        pb = ((b + tile - 1) // tile) * tile
-        h1p, h2p = hash_topics_device(
-            pad_rows(rows, pb), pad_rows(starts, pb),
-            pad_rows(lens, pb), pad_rows(nlv, pb, fill=_EMPTY),
-            salt, device=device, impl=impl)
-        return h1p[:b], h2p[:b]
-    fn = _build_pallas(b, mb, w, tile, not _on_tpu())
-    return tuple(fn(*args))
+    return _hash_lanes_lax(put(rows), put(starts), put(lens), put(nlv),
+                           put(h0lo), put(h0hi))
 
 
 class DeviceTokenized:
@@ -318,14 +257,13 @@ class DeviceTokenizedFilters:
 
 def device_tokenize_filters(filters, roots: Sequence[int], *,
                             max_levels: int, salt: int,
-                            batch: Optional[int] = None, device=None,
-                            impl: Optional[str] = None):
+                            batch: Optional[int] = None, device=None):
     """Device-side retained FILTER tokenization (ISSUE 17 satellite).
 
     Mirrors :func:`device_tokenize`: the host does the cheap vectorized
     structure work — pack the joined filter bytes, scan level
     boundaries, classify the single-byte ``'+'``/``'#'`` wildcard lanes
-    into ``KIND_PLUS``/``KIND_HASH`` — and the BLAKE2b kernel hashes the
+    into ``KIND_PLUS``/``KIND_HASH`` — and the BLAKE2b program hashes the
     lanes on device. Wildcard lanes are post-masked to ``h1 == h2 == 0``
     (the exact ``TokenizedFilters`` contract: only ``KIND_LIT`` lanes
     carry hashes; the retained walk branches on the kind grid).
@@ -389,7 +327,7 @@ def device_tokenize_filters(filters, roots: Sequence[int], *,
     kinds[st.lvl_row[sel], st.lvl_idx[sel]] = kind_lvl[sel]
     nlv = lengths.reshape(b, 1)
     h1, h2 = hash_topics_device(rows, starts, lens_g, nlv, salt,
-                                device=device, impl=impl)
+                                device=device)
     put = functools.partial(jax.device_put, device=device)
     kd = put(kinds)
     # zero-on-wildcard contract: inactive lanes are already zero (the
@@ -407,8 +345,7 @@ def device_tokenize_filters(filters, roots: Sequence[int], *,
 
 def device_tokenize(tb, roots: Sequence[int], *, max_levels: int,
                     salt: int, batch: Optional[int] = None,
-                    device=None, impl: Optional[str] = None
-                    ) -> Tuple[DeviceTokenized, "object"]:
+                    device=None) -> Tuple[DeviceTokenized, "object"]:
     """The byte-plane device prep: pack + structure on host (vectorized
     numpy), hash on device. Returns ``(host_mirror, Probes)``.
 
@@ -450,7 +387,7 @@ def device_tokenize(tb, roots: Sequence[int], *, max_levels: int,
         st.lvl_len[sel].astype(np.int32)
     nlv = lengths.reshape(b, 1)
     h1, h2 = hash_topics_device(rows, starts, lens_g, nlv, salt,
-                                device=device, impl=impl)
+                                device=device)
     put = functools.partial(jax.device_put, device=device)
     probes = Probes(tok_h1=h1, tok_h2=h2, lengths=put(lengths),
                     roots=put(rootv), sys_mask=put(sys_mask))

@@ -82,24 +82,6 @@ def mesh_patch_enabled() -> bool:
     return env_bool("BIFROMQ_MESH_PATCH", True)
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version-compat shard_map across three jax API generations: the
-    image's 0.4.x has only ``jax.experimental.shard_map`` with
-    ``check_rep``; mid versions expose top-level ``jax.shard_map`` still
-    with ``check_rep``; current ones renamed it ``check_vma``. Probe the
-    signature rather than the module path."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    import inspect
-    try:
-        has_vma = "check_vma" in inspect.signature(sm).parameters
-    except (TypeError, ValueError):  # C-accelerated / wrapped callables
-        has_vma = True
-    kw = {"check_vma": check_vma} if has_vma else {"check_rep": check_vma}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def tenant_shard(tenant_id: str, n_shards: int) -> int:
     """Stable tenant → shard assignment (≈ range ownership by tenant prefix)."""
     d = hashlib.blake2b(tenant_id.encode("utf-8"), digest_size=4).digest()
@@ -397,7 +379,7 @@ def make_match_step(mesh: Mesh, *, probe_len: int, k_states: int = 32,
     table_spec = P(SHARD_AXIS)
     probe_spec = P(REPLICA_AXIS, SHARD_AXIS)
     out_specs = (probe_spec, probe_spec, probe_spec, probe_spec)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(table_spec, table_spec, table_spec,
                   probe_spec, probe_spec, probe_spec, probe_spec, probe_spec),
@@ -411,30 +393,25 @@ def make_match_step(mesh: Mesh, *, probe_len: int, k_states: int = 32,
     return step
 
 
-def _ring_allreduce(x, axis_name: str, size: int, axis_names):
+def _ring_allreduce(x, axis_name: str, size: int):
     """Right-rotate ring allreduce over one mesh axis: ``size - 1``
     single-neighbor hops, each adding the predecessor's running block.
     This is the ISSUE 19 merge — per-peer delivery counts cross the
-    interconnect as neighbor permutes, never as an all-to-host psum. On
-    a real TPU each hop is the Pallas RDMA right_permute kernel
-    (models/kernels.pallas_right_permute); everywhere else it is
-    ``jax.lax.ppermute``, which doubles as the kernel's parity oracle."""
+    interconnect as neighbor permutes (``jax.lax.ppermute``, which XLA
+    lowers to collective-permute on the chip interconnect), never as an
+    all-to-host psum."""
     if size <= 1:
         return x
-    from ..models.kernels import pallas_right_permute, rdma_permute_enabled
-    rdma = rdma_permute_enabled()
     perm = [(i, (i + 1) % size) for i in range(size)]
     acc = x
     buf = x
     for _ in range(size - 1):
-        buf = (pallas_right_permute(buf, axis_name, axis_names) if rdma
-               else jax.lax.ppermute(buf, axis_name, perm))
+        buf = jax.lax.ppermute(buf, axis_name, perm)
         acc = acc + buf
     return acc
 
 
-def make_expand_step(mesh: Mesh, *, cap: int, n_peers: int,
-                     use_kernel: bool = False):
+def make_expand_step(mesh: Mesh, *, cap: int, n_peers: int):
     """The mesh's second device stage (ISSUE 19): per-shard ragged
     expansion of the walk's interval grids into dense (slot, row) pairs +
     stable per-peer bucketing, with the global per-peer totals merged by
@@ -455,13 +432,12 @@ def make_expand_step(mesh: Mesh, *, cap: int, n_peers: int,
              delivery target; nothing here ever round-trips the full
              interval grids.
     """
-    key = (mesh, "expand", cap, n_peers, use_kernel)
+    key = (mesh, "expand", cap, n_peers)
     cached = _STEP_CACHE.get(key)
     if cached is not None:
         return cached
     r = mesh.shape[REPLICA_AXIS]
     s = mesh.shape[SHARD_AXIS]
-    axis_names = (REPLICA_AXIS, SHARD_AXIS)
 
     def local_expand(ivl_s, ivl_c, overflow, slot_peer):
         ivl_s, ivl_c, ovf = ivl_s[0, 0], ivl_c[0, 0], overflow[0, 0]
@@ -469,13 +445,8 @@ def make_expand_step(mesh: Mesh, *, cap: int, n_peers: int,
         # the host oracle re-matches them regardless (same zeroing as
         # the single-chip expand_routes)
         serve_c = jnp.where(ovf[:, None], 0, ivl_c)
-        if use_kernel:
-            from ..models.kernels import pallas_expand
-            slots, rows, row_offsets, n_pairs, trunc = pallas_expand(
-                ivl_s, serve_c, cap=cap)
-        else:
-            slots, rows, row_offsets, n_pairs, trunc = _expand_pairs(
-                ivl_s, serve_c, cap)
+        slots, rows, row_offsets, n_pairs, trunc = _expand_pairs(
+            ivl_s, serve_c, cap)
         if n_peers == 0:
             # no named peers: live pairs are a contiguous prefix (all
             # UNKNOWN) with pad trailing, so the counting sort is the
@@ -489,8 +460,8 @@ def make_expand_step(mesh: Mesh, *, cap: int, n_peers: int,
             peer_slots, peer_rows, peer_offsets = _bucket_pairs(
                 slots, rows, slot_peer[0], n_peers)
         counts = peer_offsets[1:] - peer_offsets[:-1]
-        totals = _ring_allreduce(counts, SHARD_AXIS, s, axis_names)
-        totals = _ring_allreduce(totals, REPLICA_AXIS, r, axis_names)
+        totals = _ring_allreduce(counts, SHARD_AXIS, s)
+        totals = _ring_allreduce(totals, REPLICA_AXIS, r)
         expand = lambda x: x[None, None]
         return (expand(slots), expand(rows), expand(row_offsets),
                 expand(n_pairs), expand(trunc), expand(peer_slots),
@@ -498,7 +469,7 @@ def make_expand_step(mesh: Mesh, *, cap: int, n_peers: int,
 
     table_spec = P(SHARD_AXIS)
     probe_spec = P(REPLICA_AXIS, SHARD_AXIS)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         local_expand, mesh=mesh,
         in_specs=(probe_spec, probe_spec, probe_spec, table_spec),
         out_specs=(probe_spec,) * 8 + (P(),),
@@ -934,8 +905,9 @@ class MeshMatcher(TpuMatcher):
             out = self._step(dev[0], dev[1], dev[2], z, z, lengths, roots,
                              sysm)
             out[4].block_until_ready()
-        except Exception:  # noqa: BLE001 — warm-up is best-effort
-            pass
+        except Exception:  # noqa: BLE001 — the first serve compiles lazily
+            from ..utils.metrics import warmup_failed
+            warmup_failed("mesh step jit")
 
     # ---------------- per-shard patch plane (ISSUE 15 tentpole) ------------
 
@@ -1453,14 +1425,12 @@ class MeshMatcher(TpuMatcher):
             # expansion + peer bucketing, cross-mesh totals merged by the
             # right_permute ring; the fetch then reads compact buffers
             # that are already grouped by delivery broker
-            from ..models.kernels import expand_kernel_enabled
             t1 = time.perf_counter()
             with trace.span("device.expand", batch=prep.batch):
                 peer_tab, slot_peer = self._mesh_peer_table(prep.ct)
                 step = make_expand_step(
                     self.mesh, cap=prep.b * expand_cap_lanes(),
-                    n_peers=peer_tab.n_peers,
-                    use_kernel=expand_kernel_enabled())
+                    n_peers=peer_tab.n_peers)
                 (slots, rows, row_offsets, n_pairs, trunc, peer_slots,
                  peer_rows, peer_offsets, peer_totals) = step(
                     ivl_s, ivl_c, overflow, slot_peer)

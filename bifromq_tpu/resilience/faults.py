@@ -27,7 +27,7 @@ into the matcher's dispatch/fetch stages and the ring's readiness poll:
   were really ready all along), which is exactly how the chaos gate
   drives breaker recovery.
 - ``slow``: readiness is withheld for ``delay`` seconds — a saturated
-  device / long tunnel RTT.
+  device.
 - ``flaky_ready``: each readiness poll lies "not ready" with the rule's
   probability — a glitchy PJRT buffer query; completion is only delayed,
   never denied.

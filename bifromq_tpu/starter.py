@@ -23,6 +23,9 @@ Config shape (all keys optional):
       split_threshold: 100000            # route-table elasticity knobs
       load_split_threshold: 50000        # (per-range keys / load rate;
       merge_threshold: 1000              #  omit to disable a balancer)
+      mesh: true                         # tenant-shard the route table over
+                                         # ALL local devices (MeshMatcher);
+                                         # default: one device (TpuMatcher)
     inbox:
       split_threshold: 100000            # inbox-keyspace range split
     retain:
@@ -274,6 +277,13 @@ class Standalone:
         elastic = {k: dist_cfg[k] for k in
                    ("split_threshold", "load_split_threshold",
                     "merge_threshold") if k in dist_cfg}
+        if dist_cfg.get("mesh"):
+            # one shard per local device, no replicas: each device's HBM
+            # holds its tenants' tables (BASELINE config 5's layout)
+            import jax
+            from .parallel.sharded import MeshMatcher, make_mesh
+            mesh = make_mesh(1, len(jax.devices()))
+            elastic["matcher_factory"] = lambda: MeshMatcher(mesh=mesh)
         dist = None
         if dist_mode == "remote":
             from .dist.remote import RemoteDistWorker

@@ -148,7 +148,11 @@ class MemUsage:
                     return int(raw)
             except OSError:
                 continue
-        return 1 << 34  # 16 GiB fallback budget
+        # no cgroup limit: the budget is the machine's physical memory
+        try:
+            return os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
+        except (ValueError, OSError):
+            return 1 << 34  # 16 GiB fallback budget
 
     @staticmethod
     def rss_bytes() -> int:

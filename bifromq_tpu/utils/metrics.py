@@ -158,6 +158,19 @@ class FabricMetric(enum.Enum):
     # ISSUE 7: device-fault resilience plane
     DEVICE_TIMEOUT = "device_timeout_total"
     MATCH_SHED = "match_shed_total"
+    # a jit warm-up (serving walk, mesh step, patch scatter) raised: the
+    # first serve then compiles lazily — or fails the same way
+    WARMUP_FAILED = "device_warmup_failed_total"
+
+
+def warmup_failed(what: str) -> None:
+    """Count and log a jit warm-up that raised — call from the ``except``
+    block. The first serve of that shape then compiles lazily (or fails
+    the same way, visibly); a swallowed warm-up failure used to surface
+    only as every batch quietly degrading to the host oracle."""
+    import logging
+    FABRIC.inc(FabricMetric.WARMUP_FAILED)
+    logging.getLogger(__name__).exception("%s warm-up failed", what)
 
 
 class FabricMetrics:

@@ -51,3 +51,14 @@ def compile_and_load(src: str, so: str,
                                f"{type(e).__name__}: {e}") from e
         _cache[so] = lib
         return lib
+
+
+def status() -> Dict[str, str]:
+    """``{library file name: "loaded" | "failed"}`` for every native
+    library this process has tried — so a start-up report can NAME a
+    library whose build failed instead of its Python twin serving
+    unnoticed."""
+    with _lock:
+        return {os.path.basename(so): ("loaded" if lib is not False
+                                       else "failed")
+                for so, lib in _cache.items()}

@@ -136,7 +136,7 @@ assert recs and any(r.dev_expand_s > 0 for r in recs), \
     "no dev_expand attribution on the device-expand batch"
 assert "device.expand" in STAGES.snapshot(), \
     "device.expand stage histogram empty"
-split = OBS.profiler.split_snapshot(probe=False)
+split = OBS.profiler.split_snapshot()
 assert "dev_expand_ms_p50" in split, split.keys()
 print(f"serving A/B: {len(queries)} topics byte-identical across modes; "
       f"dev_expand stage attributed on {len(recs)} batch(es)")

@@ -122,7 +122,7 @@ recs = OBS.profiler.records()[-n_new:]
 assert recs, "no device batches recorded"
 assert all(r.tokenize_s > 0 for r in recs if r.kernel != "oracle"), \
     "a device batch lacked tokenize attribution"
-split = OBS.profiler.split_snapshot(probe=False)
+split = OBS.profiler.split_snapshot()
 assert "tokenize_ms_p50" in split, split.keys()
 from bifromq_tpu.utils.metrics import STAGES
 assert "tokenize" in STAGES.snapshot(), "tokenize stage histogram empty"

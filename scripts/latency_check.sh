@@ -5,10 +5,8 @@
 #   1. pipelined small-batch serving lands e2e batch p99 under a
 #      CPU-scaled threshold (default 50ms; the TPU target is <1ms),
 #   2. the pipelined p99 beats the sync full-batch baseline by >=10x
-#      (the BENCH_r01 666ms-sync failure shape),
-#   3. fused-kernel on (interpret mode on CPU) and off produce IDENTICAL
-#      match results on a randomized workload,
-#   4. the match-cache hit path does not regress: a repeated-topic
+#      (the blocking-sync failure shape),
+#   3. the match-cache hit path does not regress: a repeated-topic
 #      workload still serves >80% from cache through the async path and
 #      a pure compaction does not cold-start it.
 # Runs on CPU (JAX_PLATFORMS=cpu), hard timeout like the other gates.
@@ -86,21 +84,7 @@ assert pipe_p99 < P99_MS_MAX, \
     f"pipelined p99 {pipe_p99:.1f}ms over the {P99_MS_MAX}ms CPU bound"
 assert speedup >= 10, f"p99 speedup {speedup:.1f}x < 10x"
 
-# ---- 3: fused-kernel on/off parity --------------------------------------
-rng = random.Random(3)
-probe = [("tenant0", topics[rng.randrange(len(topics))])
-         for _ in range(64)]
-legs = {}
-for mode in ("0", "1"):
-    os.environ["BIFROMQ_FUSED_KERNEL"] = mode
-    mm = TpuMatcher.from_tries(tries, match_cache=False,
-                               auto_compact=False, k_states=8)
-    legs[mode] = [canon(r) for r in mm.match_batch(probe, batch=64)]
-os.environ.pop("BIFROMQ_FUSED_KERNEL")
-assert legs["0"] == legs["1"], "fused kernel diverged from lax walk"
-print("fused on/off parity ok (64 randomized queries)")
-
-# ---- 4: cache hit path through the async pipeline -----------------------
+# ---- 3: cache hit path through the async pipeline -----------------------
 mc = TpuMatcher.from_tries(tries, match_cache=True, auto_compact=False)
 hot = [("tenant0", topics[i]) for i in range(24)]
 

@@ -448,11 +448,9 @@ class TestCapacityProfileAPI:
         status, out = await http(api.port, "GET",
                                  "/capacity?n_subs=1000000")
         assert status == 200
-        fv = out["fits"]["fused_vmem"]
-        # acceptance: the 1M-sub table fails the 12MB VMEM gate, judged
-        # from the model alone (nothing was built or dispatched)
-        assert fv["fits"] is False
-        assert fv["table_bytes"] > fv["budget_bytes"]
+        # judged from the model alone (nothing was built or dispatched)
+        assert out["fits"]["tables"]["total"] > 100 << 20
+        assert out["fits"]["mesh"]["shards"] == 1
         status, out = await http(api.port, "GET",
                                  "/capacity?n_subs=1000&shards=4")
         assert out["fits"]["mesh"]["shards"] == 4
@@ -468,11 +466,11 @@ class TestCapacityProfileAPI:
         assert status == 200
         assert out["batches"] >= 1
         assert "dispatch_ms_p50" in out["split"]
-        assert "device_kernel_ms_est" in out["split"]
+        assert "ready_ms_p50" in out["split"]
         assert out["compile_ledger"]["total"] >= 1
         ev = out["compile_ledger"]["events"][-1]
         assert {"reason", "compile_s", "salt", "table_bytes",
-                "vmem_fits"} <= set(ev)
+                "n_nodes"} <= set(ev)
         await sub.disconnect()
 
     async def test_cluster_capacity_standalone(self, stack):

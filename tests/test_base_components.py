@@ -429,3 +429,49 @@ class TestElasticityFromYAML:
             await c.disconnect()
         finally:
             await node.stop()
+
+
+class TestSortedBytesMap:
+    """The in-memory KV's key order is merged on demand (PR 27: an insort
+    per put made 1M-route bulk loads quadratic) — ordered reads must still
+    see every interleaving of puts and deletes exactly sorted."""
+
+    def test_random_interleaving_matches_sorted_dict(self):
+        import random
+        from bifromq_tpu.kv.engine import _SortedBytesMap
+        rng = random.Random(5)
+        m, ref = _SortedBytesMap(), {}
+        for step in range(4000):
+            k = b"k%04d" % rng.randrange(600)
+            op = rng.random()
+            if op < 0.6:
+                m.put(k, b"v%d" % step)
+                ref[k] = b"v%d" % step
+            elif op < 0.8:
+                m.delete(k)
+                ref.pop(k, None)
+            elif op < 0.85:
+                lo, hi = sorted((b"k%04d" % rng.randrange(600),
+                                 b"k%04d" % rng.randrange(600)))
+                m.delete_range(lo, hi)
+                for d in [x for x in ref if lo <= x < hi]:
+                    del ref[d]
+            else:
+                lo = b"k%04d" % rng.randrange(600)
+                assert list(m.scan(lo, None)) == sorted(
+                    (x, v) for x, v in ref.items() if x >= lo)
+        assert list(m.scan(None, None)) == sorted(ref.items())
+        assert list(m.scan(None, None, reverse=True)) == sorted(
+            ref.items(), reverse=True)
+        assert len(m) == len(ref)
+
+    def test_bulk_puts_defer_the_sort_and_copy_sees_them(self):
+        from bifromq_tpu.kv.engine import _SortedBytesMap
+        m = _SortedBytesMap()
+        for i in reversed(range(5000)):
+            m.put(b"%06d" % i, b"")
+        assert len(m._pending) == 5000 and not m._keys     # O(1) puts
+        c = m.copy()
+        assert [k for k, _ in c.scan(None, None)] == [
+            b"%06d" % i for i in range(5000)]
+        assert not m._pending                              # merged once

@@ -1,5 +1,5 @@
 """Device capacity model & planner (ISSUE 8): model-vs-live byte parity
-on the CPU backend, planner calibration round trips, the fused-VMEM
+on the CPU backend, planner calibration round trips, the HBM
 verdict reproducing the serving gate's comparison without a dispatch,
 mesh per-shard accounting, and the federated capacity surfaces."""
 
@@ -83,36 +83,30 @@ class TestPlanner:
         assert pred["edge_tab"] == \
             int(m._base_ct.edge_tab.size) * 4
 
-    def test_fits_reproduces_fused_vmem_gate_verdict(self, monkeypatch):
-        """fits() must apply the SAME comparison the dispatch-time gate
-        runs — for the 1M-sub table the default coefficients predict
-        ~118MB of edge+route bytes against the 12MB budget: exceeds,
-        without building or dispatching anything."""
-        from bifromq_tpu.models.kernels import (fused_fits_vmem,
-                                                fused_vmem_budget_bytes)
-        monkeypatch.delenv("BIFROMQ_FUSED_VMEM_MB", raising=False)
-        verdict = cap.CapacityPlanner().fits(1_000_000)
-        fv = verdict["fused_vmem"]
-        assert fv["budget_bytes"] == fused_vmem_budget_bytes()
-        assert fv["fits"] is fused_fits_vmem(fv["table_bytes"])
-        assert fv["fits"] is False          # 1M subs >> 12MB VMEM
-        # a tiny table passes the same gate
-        small = cap.CapacityPlanner().fits(100)
-        assert small["fused_vmem"]["fits"] is True
+    def test_fits_1m_subs_against_v5e_hbm(self):
+        """The 1M-sub verdict from the model alone (nothing built or
+        dispatched): tables + in-flight buffers + the compile-time
+        double fit one v5e chip's 16 GB."""
+        verdict = cap.CapacityPlanner().fits(
+            1_000_000, hbm_limit_bytes=16 << 30)
+        assert verdict["hbm"]["fits"] is True
+        assert verdict["per_device_peak_bytes"] == (
+            2 * verdict["tables"]["total"] + verdict["inflight"]["total"])
+        assert verdict["tables"]["total"] > 100 << 20
 
-    def test_fits_honors_vmem_budget_env(self, monkeypatch):
-        monkeypatch.setenv("BIFROMQ_FUSED_VMEM_MB", "1024")
-        verdict = cap.CapacityPlanner().fits(1_000_000)
-        assert verdict["fused_vmem"]["budget_bytes"] == 1024 << 20
-        assert verdict["fused_vmem"]["fits"] is True
+    def test_fits_mesh_divides_the_tables(self):
+        single = cap.CapacityPlanner().fits(1_000_000)
+        mesh = cap.CapacityPlanner().fits(1_000_000, mesh=4)
+        assert mesh["mesh"] == {"replicas": 1, "shards": 4}
+        assert mesh["per_device_bytes"] < single["per_device_bytes"]
 
-    def test_live_gate_agrees_with_model_on_installed_base(self):
-        """The model's fused byte count equals the number the serving
-        gate weighs on the actually-uploaded DeviceTrie."""
-        from bifromq_tpu.models.kernels import fused_table_bytes
+    def test_walk_bytes_agree_with_uploaded_tables(self):
+        """The model's edge+route byte count equals what the serving
+        walk actually gathers from on the uploaded DeviceTrie."""
         m = build_matcher(200)
-        assert cap.fused_bytes_from_compiled(m._base_ct) == \
-            fused_table_bytes(m._device_trie)
+        dev = m._device_trie
+        assert cap.walk_bytes_from_compiled(m._base_ct) == \
+            int(dev.edge_tab.nbytes) + int(dev.route_tab.nbytes)
 
     def test_hbm_headroom_math(self):
         verdict = cap.CapacityPlanner().fits(
@@ -179,7 +173,7 @@ class TestReportSurfaces:
         assert rep["table_bytes"] >= \
             cap.measure(m)["measured_device_bytes"]
         assert rep["parity_error"] == 0.0
-        assert "fused_vmem" in rep["fits"]
+        assert "hbm" in rep["fits"]
         assert rep["planner"]["calibrated_from"] is not None
 
     def test_digest_capacity_is_cheap_and_compact(self):
@@ -189,7 +183,7 @@ class TestReportSurfaces:
         d = cap.digest_capacity(hub)
         assert d["table_bytes"] == \
             cap.measure(m)["measured_device_bytes"]
-        assert d["vmem_fits"] is True
+        assert "vmem_fits" not in d
 
     def test_hbm_env_override(self, monkeypatch):
         monkeypatch.setenv("BIFROMQ_HBM_BYTES", str(1 << 31))

@@ -890,7 +890,6 @@ class TestClusterCapacity:
         view = ClusterView("me", FakeHost("me"), hub=hub)
         digest = view.build_digest()
         assert digest["capacity"]["table_bytes"] > 0
-        assert digest["capacity"]["vmem_fits"] is True
 
     async def test_capacity_table_federates_from_digests(self):
         host = FakeHost("me")
@@ -898,7 +897,7 @@ class TestClusterCapacity:
             "addr": "127.0.0.1:6000",
             "digest": _peer_digest(
                 capacity={"table_bytes": 12345,
-                          "mem_peak_bytes": 777, "vmem_fits": False})}
+                          "mem_peak_bytes": 777})}
         view = ClusterView("me", host, hub=_fresh_hub())
         table = view.capacity_table()
         assert table["nodes"]["me"]["self"] is True

@@ -322,13 +322,10 @@ class TestArenaGrowth:
             + [("T", ["seed", "1"])], "post-regrow")
 
 
-class TestFusedKernelPatched:
-    def test_fused_walk_reads_patched_arenas(self, monkeypatch):
-        """The fused Pallas kernel (interpret mode on CPU) serves from the
-        same patched tables — a narrow flush is visible on the next
-        launch with no rebuild, and tombstones die in the shared host
-        expansion."""
-        monkeypatch.setenv("BIFROMQ_FUSED_KERNEL", "1")
+class TestWalkReadsPatchedArenas:
+    def test_narrow_flush_visible_on_next_launch_without_rebuild(self):
+        """A narrow flush is visible on the next launch with no rebuild,
+        and tombstones die in the shared host expansion."""
         m = TpuMatcher(max_levels=6, k_states=8, auto_compact=False)
         m.add_route("T", mk_route("a/b", "r1"))
         m.refresh()
@@ -341,6 +338,7 @@ class TestFusedKernelPatched:
                        (0, "r2", "d0"))
         res = m.match_batch([("T", ["a", "b"])])[0]
         assert sorted(r.receiver_id for r in res.normal) == ["r1", "r3"]
+        assert m.compile_count == 1 and m.patch_count == 3
 
 
 class TestInFlightSafety:

@@ -31,7 +31,7 @@ class TestProfilerCore:
         p = ContinuousProfiler()
         p.record_batch(n_queries=3, batch=16, kernel="lax",
                        dispatch_s=0.001, ready_s=0.002, fetch_s=0.003)
-        p.record_batch(n_queries=8, batch=16, kernel="fused",
+        p.record_batch(n_queries=8, batch=16, kernel="lax_donated",
                        dispatch_s=0.002, path="sync")
         assert p.batches_total == 2
         assert p.queries_total == 11
@@ -39,7 +39,7 @@ class TestProfilerCore:
         snap = p.snapshot()
         assert snap["padding_waste_ratio"] == pytest.approx(
             21 / (11 + 21), abs=1e-3)
-        assert snap["split"]["kernels"] == {"lax": 1, "fused": 1}
+        assert snap["split"]["kernels"] == {"lax": 1, "lax_donated": 1}
         assert snap["split"]["dispatch_ms_p50"] > 0
 
     def test_frontend_and_degraded_counters(self):
@@ -84,7 +84,7 @@ class TestProfilerCore:
         p = ContinuousProfiler()
         p.record_batch(n_queries=1, batch=2, kernel="lax", dispatch_s=0.0)
         p.ledger.record(reason="refresh", duration_s=0.1, salt=0,
-                        n_nodes=10, table_bytes=100, vmem_fits=True,
+                        n_nodes=10, table_bytes=100,
                         generation_bumped=False)
         p.reset()
         snap = p.snapshot()
@@ -92,53 +92,40 @@ class TestProfilerCore:
         assert snap["compile_ledger"]["total"] == 0
 
 
-class TestRTTPerBackend:
-    """ISSUE 9 satellite (PR 8 follow-up): the tunnel-RTT probe caches
-    per device_kind and a backend change reads its own slot instead of
-    blending the other backend's split."""
+class TestHostOnlySurfaces:
+    """The profiler and the memory gauge read host state only: a scrape
+    must never be the thing that initializes (and claims) the device."""
 
-    def test_backend_change_invalidates_cached_split(self):
-        clock = [1000.0]
-        kind = ["cpu"]
-        p = ContinuousProfiler(clock=lambda: clock[0])
-        p._backend_kind = lambda: kind[0]
-        # seed two backend slots directly (the probe path itself needs a
-        # live device; the caching contract is what's under test)
-        p._rtt_cache["cpu"] = (0.05, clock[0])
-        p._rtt_cache["TPU v5e"] = (70.0, clock[0])
-        assert p.rtt_probe_ms() == 0.05
-        snap = p.split_snapshot(probe=False)
-        assert snap["rtt_device_kind"] == "cpu"
-        assert snap["tunnel_rtt_ms"] == 0.05
-        # the process falls over to the TPU tunnel: same TTL window, but
-        # the split must speak for the NEW backend immediately
-        kind[0] = "TPU v5e"
-        assert p.rtt_probe_ms() == 70.0
-        snap = p.split_snapshot(probe=False)
-        assert snap["rtt_device_kind"] == "TPU v5e"
-        assert snap["tunnel_rtt_ms"] == 70.0
-
-    def test_live_probe_stamps_kind_and_caches(self):
-        # the real path against the initialized CPU backend
-        import jax
-        jax.devices()
+    def test_split_snapshot_is_host_stage_times_only(self):
         p = ContinuousProfiler()
-        ms = p.rtt_probe_ms()
-        assert ms is not None and ms >= 0
-        kind = p._rtt_kind
-        assert kind and p._rtt_cache[kind][0] == ms
-        snap = p.split_snapshot(probe=False)
-        assert snap["rtt_device_kind"] == kind
+        p.record_batch(n_queries=4, batch=16, kernel="lax",
+                       dispatch_s=0.001, ready_s=0.004, fetch_s=0.002)
+        snap = p.split_snapshot()
+        assert snap["window_batches"] == 1
+        assert snap["ready_ms_p50"] == pytest.approx(4.0)
+        assert snap["fetch_ms_p50"] == pytest.approx(2.0)
+        assert not any("rtt" in k or "kernel_ms" in k for k in snap)
 
-    def test_no_backend_keeps_ttl_on_failure(self):
-        clock = [0.0]
-        p = ContinuousProfiler(clock=lambda: clock[0])
-        p._backend_kind = lambda: None
-        assert p.rtt_probe_ms() is None
-        at0 = p._rtt_at
-        clock[0] += 1.0                 # inside the TTL: no re-probe
-        assert p.rtt_probe_ms() is None
-        assert p._rtt_at == at0
+    def test_memory_stats_unavailable_until_a_base_is_installed(self):
+        from bifromq_tpu.obs.device import DeviceGauges
+        g = DeviceGauges()
+        assert g.memory_stats()["available"] is False
+        m = TpuMatcher(match_cache=False)
+        g.register_matcher(m)
+        assert g.memory_stats()["available"] is False   # no tables yet
+
+    def test_warmup_failure_is_counted_not_swallowed(self, monkeypatch):
+        from bifromq_tpu.ops import match as om
+        from bifromq_tpu.utils.metrics import FABRIC, FabricMetric
+
+        def boom(*a, **kw):
+            raise RuntimeError("compiler refused the walk")
+        monkeypatch.setattr(om, "walk_routes", boom)
+        before = FABRIC.get(FabricMetric.WARMUP_FAILED)
+        m = TpuMatcher(match_cache=False)
+        m.add_route("T", mk_route("w/+", "r0"))
+        m.refresh()
+        assert FABRIC.get(FabricMetric.WARMUP_FAILED) == before + 1
 
 
 class TestMatcherIntegration:
@@ -157,7 +144,7 @@ class TestMatcherIntegration:
         assert recs, "sync match must record a batch profile"
         last = recs[-1]
         assert last.path == "sync"
-        assert last.kernel in ("lax", "lax_donated", "fused")
+        assert last.kernel in ("lax", "lax_donated")
         assert last.n_queries == 2 and last.batch >= 2
         assert last.dispatch_s > 0 and last.fetch_s > 0
 
@@ -199,7 +186,6 @@ class TestMatcherIntegration:
         for e in events:
             assert e["compile_s"] >= 0
             assert e["table_bytes"] > 0
-            assert e["vmem_fits"] is True
             assert e["kind"] == "single"
         # pure same-salt compactions never bump the generation
         assert OBS.profiler.ledger.generation_bumps == 1
@@ -265,7 +251,7 @@ class TestSegmentStore:
                                   dispatch_s=0.001)
         hub.profiler.ledger.record(
             reason="refresh", duration_s=0.2, salt=0, n_nodes=5,
-            table_bytes=123, vmem_fits=True, generation_bumped=True)
+            table_bytes=123, generation_bumped=True)
         assert hub.start_persistence(SegmentStore(str(tmp_path)))
         n = hub.persist_now()
         assert n > 0

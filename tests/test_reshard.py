@@ -570,8 +570,7 @@ class TestDeviceFilterTokenize:
         ref = tokenize_filters(filters, roots, max_levels=8,
                                salt=987654321, batch=256)
         mir, pr = device_tokenize_filters(filters, roots, max_levels=8,
-                                          salt=987654321, batch=256,
-                                          impl="lax")
+                                          salt=987654321, batch=256)
         sup = np.asarray(mir.lengths) != -1
         assert sup.sum() > 150
         assert np.array_equal(np.asarray(mir.lengths)[sup],
@@ -592,7 +591,7 @@ class TestDeviceFilterTokenize:
         filters = [["ok", "row"], ["x"] * 20, ["em/bed"], [],
                    ["a" * 200]]
         mir, _ = device_tokenize_filters(filters, [0] * 5, max_levels=8,
-                                         salt=1, batch=8, impl="lax")
+                                         salt=1, batch=8)
         L = np.asarray(mir.lengths)
         assert L[0] == 2          # supported
         assert L[1] == -1         # too deep → host fallback
@@ -605,7 +604,6 @@ class TestDeviceFilterTokenize:
                                                  match_filter_host)
         from bifromq_tpu.utils import topic as tp
         monkeypatch.setenv("BIFROMQ_DEVICE_TOKENIZE", "1")
-        monkeypatch.setenv("BIFROMQ_TOK_KERNEL", "lax")
         idx = RetainedIndex()
         rng = random.Random(4)
         for i in range(60):

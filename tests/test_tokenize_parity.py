@@ -2,7 +2,7 @@
 
 The ingest byte plane has four tokenizer legs — the per-row Python
 reference, the vectorized numpy BLAKE2b, the native C++ tokenizer, and
-the device hash kernel (Pallas, interpret on CPU) — and they must be
+the device hash program (jit'd XLA) — and they must be
 BIT-EXACT with ``automaton.level_hash`` over adversarial topics:
 multi-byte UTF-8, empty levels / separator runs, ``$share``/``$SYS``
 roots, max-levels truncation, >1-block levels. Plus the serving
@@ -76,18 +76,18 @@ class TestHashParity:
         np.testing.assert_array_equal(py.lengths, nat.lengths)
         np.testing.assert_array_equal(py.sys_mask, nat.sys_mask)
 
-    @pytest.mark.parametrize("impl", ["lax", "pallas"])
-    def test_device_kernel_bit_exact_on_supported_rows(self, impl):
+    @pytest.mark.parametrize("salt", [11, 0x5EEDBEEF])
+    def test_device_kernel_bit_exact_on_supported_rows(self, salt):
         from bifromq_tpu.ops.tokenize import device_tokenize
         rng = random.Random(11)
         topics = _adversarial_topics(rng, n=96)
         roots = list(range(len(topics)))
         n = len(topics)
         tb = TopicBytes.from_topics(topics)
-        py = tokenize(topics, roots, max_levels=16, salt=11,
+        py = tokenize(topics, roots, max_levels=16, salt=salt,
                       native=False)
         mirror, probes = device_tokenize(tb, roots, max_levels=16,
-                                         salt=11, impl=impl)
+                                         salt=salt)
         sup = mirror.lengths[:n] >= 0
         dh1 = np.asarray(probes.tok_h1)[:n]
         dh2 = np.asarray(probes.tok_h2)[:n]
@@ -106,21 +106,21 @@ class TestHashParity:
                     or max(len(s.encode("utf-8"))
                            for s in topic_util.parse(topics[i])) > 128)
 
-    def test_pallas_ragged_batch_matches_lax(self):
-        # regression: a batch not divisible by the pallas row tile must
-        # still hash every row (the grid pads up and slices back)
+    def test_ragged_batch_padding_rows_hash_to_zero(self):
+        # a batch padded past its topics must hash every real row and
+        # leave the padding rows (nlv == -1) all-zero
         from bifromq_tpu.ops import tokenize as dtok
-        topics = [f"a/b/{i}" for i in range(dtok.TILE_ROWS + 3)]
+        topics = [f"a/b/{i}" for i in range(259)]
         roots = [0] * len(topics)
         tb = TopicBytes.from_topics(topics)
-        _, pl = dtok.device_tokenize(tb, roots, max_levels=16, salt=2,
-                                     impl="pallas")
-        _, lx = dtok.device_tokenize(tb, roots, max_levels=16, salt=2,
-                                     impl="lax")
-        np.testing.assert_array_equal(np.asarray(pl.tok_h1),
-                                      np.asarray(lx.tok_h1))
-        np.testing.assert_array_equal(np.asarray(pl.tok_h2),
-                                      np.asarray(lx.tok_h2))
+        py = tokenize(topics, roots, max_levels=16, salt=2, native=False)
+        mirror, dev = dtok.device_tokenize(tb, roots, max_levels=16,
+                                           salt=2, batch=512)
+        h1, h2 = np.asarray(dev.tok_h1), np.asarray(dev.tok_h2)
+        np.testing.assert_array_equal(h1[:259], py.tok_h1)
+        np.testing.assert_array_equal(h2[:259], py.tok_h2)
+        assert not h1[259:].any() and not h2[259:].any()
+        assert (mirror.lengths[259:] == -1).all()
 
     def test_multiblock_level_hashlib_leg(self):
         # levels > 128 bytes exercise the multi-block hashlib fallback
@@ -263,8 +263,7 @@ class TestMatcherByteQueries:
         new = recs[-n_new:]
         assert any(r.tokenize_s > 0 for r in new)
         assert "tokenize_ms" in new[-1].to_dict()
-        assert "tokenize_ms_p50" in OBS.profiler.split_snapshot(
-            probe=False)
+        assert "tokenize_ms_p50" in OBS.profiler.split_snapshot()
 
 
 class TestSyncWatchdog:
