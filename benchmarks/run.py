@@ -1,0 +1,577 @@
+#!/usr/bin/env python3
+"""One run of one cell: a started broker, a load generator of its own,
+a measured window, the comparison, one JSON line.
+
+    python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+This process alone touches JAX. It starts ``starter.Standalone`` (MQTT
+listener -> DistService -> DistWorker -> TpuMatcher -> deliverer), gives
+the worker the configuration's subscription table, starts
+``loadgen.py`` as a child (real MQTT over loopback TCP), and reads the
+program's counters at the window's two ends. Set-up is everything from
+process start to the first measured publish's due time.
+
+Builder's options (not used by the driver): ``--rehearse-cpu`` skips the
+look for a chip (the line then says platform "cpu" and carries no device
+metric); ``--sweep r1,r2,..`` and ``--seeds s1,s2,..`` run several
+windows on one set-up and print a line for each; ``--control <name>``
+puts a broken guarantee in the matcher's place (see ``sut.CONTROLS``).
+"""
+
+from __future__ import annotations
+
+import time
+
+T_START_NS = time.monotonic_ns()
+
+import argparse  # noqa: E402
+import asyncio  # noqa: E402
+import gc  # noqa: E402
+import importlib  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import statistics  # noqa: E402
+import sys  # noqa: E402
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+
+import reference  # noqa: E402
+import sut  # noqa: E402  (imports the program and JAX only inside its functions)
+import traffic as traffic_mod  # noqa: E402
+from readers import percentile  # noqa: E402
+from sut import MASK, log  # noqa: E402
+
+OUT_DIR = os.path.join(HERE, ".out")
+log.t0 = T_START_NS / 1e9
+
+
+# ---------------------------------------------------------------- the child
+
+class LoadGen:
+    def __init__(self, proc) -> None:
+        self.proc = proc
+
+    @classmethod
+    async def start(cls, port, workload, seconds, windows,
+                    bench_file) -> "LoadGen":
+        proc = await asyncio.create_subprocess_exec(
+            sys.executable, os.path.join(HERE, "loadgen.py"),
+            "--port", str(port), "--workload", workload,
+            "--seconds", str(seconds), "--windows", json.dumps(windows),
+            "--bench-file", bench_file, stdin=asyncio.subprocess.PIPE,
+            stdout=asyncio.subprocess.PIPE, limit=1 << 30)
+        return cls(proc)
+
+    async def event(self, kind: str, timeout: float) -> dict:
+        """The child's next line, which has to be of ``kind``."""
+        line = await asyncio.wait_for(self.proc.stdout.readline(), timeout)
+        if not line:
+            rc = await self.proc.wait()
+            raise RuntimeError(f"load generator ended (exit {rc})")
+        ev = json.loads(line)
+        if ev.get("event") != kind:
+            raise RuntimeError(f"load generator said {ev.get('event')!r}, "
+                               f"not {kind!r}")
+        return ev
+
+    async def say(self, word: str) -> None:
+        self.proc.stdin.write(word.encode() + b"\n")
+        await self.proc.stdin.drain()
+
+    async def stop(self) -> None:
+        if self.proc.returncode is None:
+            try:
+                await asyncio.wait_for(self.proc.wait(), 20)
+            except asyncio.TimeoutError:
+                self.proc.kill()
+                await self.proc.wait()
+
+
+# ------------------------------------------------------------ the reference
+
+class FleetReference:
+    """What the plain reference says the fleet must be handed, worked out
+    after the window from the generated rows alone."""
+
+    def __init__(self, rows, with_prefixes: bool) -> None:
+        self.table = reference.Table()
+        self.prefixes = {} if with_prefixes else None
+        for tenant, levels, rid, dkey in rows:
+            self.table.add(tenant, levels, (rid, dkey))
+            if with_prefixes:
+                seen = self.prefixes.setdefault(tenant, set())
+                for k in range(len(levels) + 1):
+                    seen.add(levels[:k])
+        self._cache = {}
+
+    def expect(self, tenant: str, topic: str):
+        key = (tenant, topic)
+        hit = self._cache.get(key)
+        if hit is None:
+            rows = self.table.match(tenant, topic)
+            hit = (len(rows), sum(hash(r[0]) for r in rows) & MASK, rows)
+            self._cache[key] = hit
+        return hit
+
+    def visited(self, tenant: str, topic: str) -> int:
+        """Trie nodes a plain walk visits: the existing filter prefixes
+        among the topic's generalised prefixes."""
+        seen = self.prefixes.get(tenant, ())
+        levels = topic.split("/")
+        n, frontier = 0, [()]
+        for lv in levels:
+            nxt = []
+            for p in frontier:
+                for step in (lv, reference.PLUS):
+                    q = p + (step,)
+                    if q in seen:
+                        nxt.append(q)
+            n += len(frontier)
+            frontier = nxt
+        return n + len(frontier)
+
+
+def fleet_verdict(stand_in, report, plan, ref: FleetReference) -> dict:
+    tenants, pop = plan["tenants"], plan["population"]
+    pubs = report["publishes"]
+    mismatch = lost_qos0 = qos_wrong = set_mismatch = 0
+    matched_total = 0
+    first_bad = None
+    for seq, t, k, qos, _conn, _due, _sent, _ack in pubs:
+        want_n, want_d, _rows = ref.expect(tenants[t], pop[k])
+        matched_total += want_n
+        got_n = stand_in.count.get(seq, 0)
+        if got_n == 0 and want_n and qos == 0:
+            lost_qos0 += 1
+            continue
+        if got_n != want_n or (want_n and stand_in.digest.get(seq) != want_d):
+            mismatch += 1
+            if first_bad is None:
+                first_bad = (seq, tenants[t], pop[k], got_n, want_n)
+        if want_n and stand_in.qos.get(seq) != {qos}:
+            qos_wrong += 1
+    for seq, got in stand_in.sets.items():
+        if seq >= len(pubs):
+            continue
+        _s, t, k = pubs[seq][:3]
+        want = {}
+        for rid, dkey in ref.expect(tenants[t], pop[k])[2]:
+            want.setdefault((tenants[t], dkey), []).append(rid)
+        if {a: sorted(b) for a, b in got.items()} != \
+                {a: sorted(b) for a, b in want.items()}:
+            if not (pubs[seq][3] == 0 and not got):
+                set_mismatch += 1
+    foreign = sum(1 for seq in stand_in.count if seq >= len(pubs))
+    return {"fleet_mismatch": mismatch, "fleet_set_mismatch": set_mismatch,
+            "fleet_qos_wrong": qos_wrong, "fleet_unknown_seq": foreign,
+            "fleet_lost_qos0": lost_qos0, "sampled_sets": len(stand_in.sets),
+            "matched_total": matched_total, "first_bad": first_bad}
+
+
+# ------------------------------------------------------------------ one run
+
+async def run(args, cell) -> list:
+    cfg = cell["config"]
+    chips = int(cell["cell"]["chips"])
+    devices = sut.claim_devices(chips, rehearse_cpu=args.rehearse_cpu)
+    platform = devices[0].platform
+    compiles = sut.CompileCounter()
+    sut.install_stage_sums()
+
+    # ---- the table, from the configuration's own seed
+    freeze = bool(cfg.get("runtime", {}).get("gc_freeze_after_setup"))
+    if freeze:
+        gc.disable()
+    t0 = time.perf_counter()
+    gen = traffic_mod.generator_of(cfg)
+    rows = list(gen.subscriptions(cfg))
+    tries, n_rows = sut.build_tries(rows)
+    log(f"table: {n_rows:,} subscriptions over {len(tries):,} tenant(s) "
+        f"generated in {time.perf_counter() - t0:.1f}s")
+
+    # ---- the broker, through the entry point a user starts
+    from bifromq_tpu.starter import Standalone
+    settings_cls = sut.install_settings(cfg.get("settings", {}))
+    node_cfg = json.loads(json.dumps(cfg["broker"]))
+    node_cfg.setdefault("plugins", {})["settings"] = "sut:Settings"
+    node = Standalone(node_cfg)
+    await node.start()
+    gen_proc = None
+    try:
+        broker = node.broker
+        if not isinstance(broker.settings, settings_cls):
+            raise RuntimeError("the settings seat was not taken")
+        stand_in = sut.FleetStandIn()
+        broker.sub_brokers.register(stand_in)
+        worker = broker.dist.worker
+        seeded = sut.seed_worker(worker, tries)
+        matcher = seeded["matcher"]
+        log(f"worker seeded: from_tries {seeded['from_tries_s']:.1f}s "
+            f"(compile_count {matcher.compile_count}), KV fill "
+            f"{seeded['kv_fill_s']:.1f}s; cache {compiles.hits} hit(s) / "
+            f"{compiles.misses} miss(es)")
+        if freeze:
+            gc.freeze()
+            gc.enable()
+        if args.control:
+            sut.CONTROLS[args.control](worker)
+            log(f"CONTROL in place: {args.control}")
+        mu = broker.mem_usage
+        log(f"host rss {mu.rss_bytes() >> 20:,} MiB of budget "
+            f"{mu.budget_bytes >> 20:,} MiB")
+
+        windows = [[s, r] for r in (args.sweep or [None])
+                   for s in (args.seeds or [args.seed])]
+        gen_proc = await LoadGen.start(broker.port, args.workload,
+                                       args.seconds, windows,
+                                       args.bench_file)
+        ev = await gen_proc.event("subscribed", 600)
+        log(f"load generator connected: {ev['connections']} connections, "
+            f"live subscribers SUBACKed; patched {matcher.patch_count}")
+
+        # ---- settle: churn until the tables have stopped changing shape
+        st = cell["traffic"].get("settle", {})
+        shapes, grown = sut.table_shapes(matcher), 0
+        while True:
+            ev = await gen_proc.event("settled", 600)
+            now = sut.table_shapes(matcher)
+            changed, shapes = now != shapes, now
+            grown += changed
+            if ev["round"] < 0 or (ev["round"] >= int(st.get("min_rounds", 0))
+                                   and not changed):
+                await gen_proc.say("go")
+                break
+            await gen_proc.say("more")
+        log(f"settled after {ev['round']} churn round(s): table shapes "
+            f"{shapes} changed in {grown} of them, fill "
+            f"{sut.table_fill(matcher)}; patched "
+            f"{matcher.patch_count}, fallbacks {matcher.patch_fallbacks}")
+
+        results, shared = [], {}
+        drain = sut.BatchDrain()
+        for seed, rate in windows:
+            tr = dict(cell["traffic"])
+            if rate is not None:
+                tr["rate_per_s"] = rate
+            plan = traffic_mod.build_plan(cfg, tr, seed, args.seconds)
+            res = await one_window(args, cell, plan, gen_proc, stand_in,
+                                   matcher, drain, compiles, rows, platform,
+                                   devices, shared)
+            res["seed"], res["rate"] = seed, rate
+            results.append(res)
+    finally:
+        if gen_proc is not None:
+            await gen_proc.stop()
+        await node.stop()
+    log(f"compile cache: {compiles.hits} hit(s), {compiles.misses} miss(es)")
+    return results
+
+
+async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
+                     compiles, rows, platform, devices, shared) -> dict:
+    import jax
+    loop = asyncio.get_running_loop()
+    ev = await gen_proc.event("window", 900)
+    t0_ns, t1_ns = ev["t0_ns"], ev["t1_ns"]
+    stand_in.arm(t0_ns, t1_ns, *plan["sample"])
+    snaps = {}
+
+    def at(t_ns, fn):
+        loop.call_later(max(0.0, (t_ns - time.monotonic_ns()) / 1e9), fn)
+
+    def open_window():
+        drain.drain()
+        drain.reset()
+        snaps["before"] = sut.counters(matcher, stand_in)
+
+    def close_window():
+        drain.drain()
+        snaps["batches"] = {"n": drain.n, "rows": drain.rows,
+                            "padded": drain.padded, "missed": drain.missed,
+                            "kernels": dict(drain.kernels),
+                            "sums": dict(drain.sums)}
+        snaps["after"] = sut.counters(matcher, stand_in)
+    # A traced run traces the window's last seconds and takes the program
+    # counters over the part BEFORE the profiler started: the profiler's
+    # Python tracer slows the host by half, and that must not be read as
+    # the program's own cost.
+    tracing = bool(args.trace) and platform != "cpu"
+    span = min(4.0, max(0.5, args.seconds - 2.0)) if tracing else 0.0
+    t_trace_ns = t1_ns - int((span + 0.5) * 1e9)
+    at(t0_ns, open_window)
+    at(t_trace_ns if tracing else t1_ns, close_window)
+
+    async def tick():       # past the counters' close too: the traced
+        while time.monotonic_ns() < t1_ns:      # batches' stamps are wanted
+            await asyncio.sleep(0.5)
+            if "before" in snaps:
+                drain.drain()
+    ticker = asyncio.ensure_future(tick())
+
+    trace_info = {}
+    if tracing:
+        trace_dir = os.path.join(OUT_DIR, "trace")
+        shutil.rmtree(trace_dir, ignore_errors=True)
+
+        async def traced():
+            await asyncio.sleep(max(0.0, (t_trace_ns - time.monotonic_ns())
+                                    / 1e9 + 0.05))
+            await loop.run_in_executor(None, jax.profiler.start_trace,
+                                       trace_dir)
+            stand_in.annotate = jax.profiler.TraceAnnotation
+            a, wall_a = time.monotonic(), time.time()
+            await asyncio.sleep(span)
+            b = time.monotonic()
+            stand_in.annotate = None
+            await loop.run_in_executor(None, jax.profiler.stop_trace)
+            trace_info.update(dir=trace_dir, window_s=b - a, wall_a=wall_a,
+                              wall_b=wall_a + (b - a))
+        tracer = asyncio.ensure_future(traced())
+    else:
+        tracer = None
+
+    report = await gen_proc.event("report", args.seconds + 600)
+    await ticker
+    if tracer is not None:
+        await tracer
+    drain.drain()
+    after_drain = sut.counters(matcher, stand_in)
+    peak = sut.memory_peak_bytes()
+    state = sut.device_state(matcher, platform)
+
+    # ---- the comparison (after the window; not part of set-up)
+    t_cmp = time.perf_counter()
+    if "ref" not in shared:
+        shared["ref"] = FleetReference(rows, with_prefixes=bool(args.trace))
+    ref = shared["ref"]
+    fleet = fleet_verdict(stand_in, report, plan, ref)
+    kernels = snaps["batches"]["kernels"]
+    in_window_compiles = compiles.between(t0_ns, t1_ns)
+    pubs = report["publishes"]
+    checks = [  # (name, value, limit): every one an exact comparison
+        ("fleet_mismatch", fleet["fleet_mismatch"], 0),
+        ("fleet_set_mismatch", fleet["fleet_set_mismatch"], 0),
+        ("fleet_qos_wrong", fleet["fleet_qos_wrong"], 0),
+        ("fleet_unknown_seq", fleet["fleet_unknown_seq"], 0),
+        ("live_missing", report["live_missing"], 0),
+        ("live_unexpected", report["live_unexpected"], 0),
+        ("live_surplus", report["live_surplus"], 0),
+        ("order_violations", report["order_violations"], 0),
+        ("qos_violations", report["qos_violations"], 0),
+        ("unacked_qos1", report["unacked_qos1"], 0),
+        ("loadgen_errors", report["n_errors"], 0),
+        ("oracle_batches", kernels.get("oracle", 0), 0),
+        ("match_degraded", after_drain["match_degraded"], 0),
+        ("device_timeout", after_drain["device_timeout"], 0),
+        ("warmup_failed", after_drain["warmup_failed"], 0),
+        ("compiles_in_window", len(in_window_compiles), 0),
+        ("full_rebuilds_in_window", snaps["after"]["compile_count"]
+         - snaps["before"]["compile_count"], 0),
+        ("tables_off_device", 0 if state["all_on_platform"]
+         and state["n_devices"] == int(cell["cell"]["chips"]) else 1, 0),
+    ]
+    floors = [  # (name, value, at least)
+        ("device_batches", snaps["batches"]["n"], 1),
+        ("publishes", len(pubs), 1),
+        ("live_samples", len(report["latencies_ms"]), 1),
+        ("sampled_sets", fleet["sampled_sets"], 1),
+    ]
+    correct = all(v <= lim for _n, v, lim in checks) and \
+        all(v >= lim for _n, v, lim in floors)
+    compared = {n: [v, lim] for n, v, lim in checks}
+    compared.update({n: [v, f">={lim}"] for n, v, lim in floors})
+    cmp_s = time.perf_counter() - t_cmp
+
+    # ---- end-to-end numbers, all from the load generator's clock
+    seconds = (t1_ns - t0_ns) / 1e9
+    counted_s = (snaps["after"]["t_ns"] - snaps["before"]["t_ns"]) / 1e9
+    lat = report["latencies_ms"]
+    route_deliveries = stand_in.in_window + report["live_in_window"]
+    e2e = {
+        "deliver_p50_ms": percentile(lat, 50),
+        "deliver_p95_ms": percentile(lat, 95),
+        "delivered_per_s": route_deliveries / seconds,
+        "subscribe_p50_ms": (statistics.median(report["subscribe_ms"])
+                             if report["subscribe_ms"] else None),
+        "setup_s": (t0_ns - T_START_NS) / 1e9,
+    }
+    failed = (fleet["fleet_lost_qos0"] + report["live_lost_qos0"]
+              + report["unacked_qos1"] + report["live_missing"])
+    b = snaps["batches"]
+    log(f"window {seconds:.1f}s: {len(pubs):,} "
+        f"publishes ({len(pubs) / seconds:,.1f}/s), {stand_in.in_window:,} "
+        f"fleet + {report['live_in_window']:,} live route deliveries in "
+        f"window, {len(lat):,} latency samples; p50 "
+        f"{e2e['deliver_p50_ms']:.2f} p95 {e2e['deliver_p95_ms']:.2f} p99 "
+        f"{percentile(lat, 99):.2f} max {max(lat):.2f} ms; gen_late p95 "
+        f"{percentile(report['gen_late_ms'], 95):.3f} ms; churn "
+        f"{report['churn_subs']} sub / {report['churn_unsubs']} unsub, "
+        f"subscribe p50 {e2e['subscribe_p50_ms']} ms")
+    log(f"counters over {counted_s:.1f}s: device batches {b['n']:,} by kernel {b['kernels']}, rows "
+        f"{b['rows']:,} padded to {b['padded']:,} (ring missed "
+        f"{b['missed']}); pub cache hits/misses "
+        f"{snaps['after']['pubcache.hits'] - snaps['before']['pubcache.hits']}"
+        f"/{snaps['after']['pubcache.misses'] - snaps['before']['pubcache.misses']}"
+        f"; patches {snaps['after']['patch.count'] - snaps['before']['patch.count']}"
+        f" in {snaps['after']['patch.flushes'] - snaps['before']['patch.flushes']}"
+        f" flushes, fallbacks {snaps['after']['patch.fallbacks']}; stand-in "
+        f"{stand_in.spent_s / max(1, stand_in.total) * 1e6:.3f} us/route over "
+        f"{stand_in.total:,} routes; mean fan-out "
+        f"{fleet['matched_total'] / max(1, len(pubs)):,.1f}; comparison "
+        f"{cmp_s:.1f}s; failed {failed} (lost QoS 0: fleet "
+        f"{fleet['fleet_lost_qos0']}, live {report['live_lost_qos0']})")
+    log(f"resident tables {state['resident_bytes']:,} B on {state['on']}, "
+        f"row bytes {state['record_bytes']}; peak device memory {peak:,} B; "
+        f"connections {report['connections']}")
+    log(f"tables now: shapes {sut.table_shapes(matcher)}, fill "
+        f"{sut.table_fill(matcher)}")
+    if in_window_compiles:
+        log(f"compiled INSIDE the window: {in_window_compiles}")
+    log(f"programs built or fetched so far: "
+        f"{[(n, s) for _t, n, s in compiles.compiles]}")
+    if fleet["first_bad"]:
+        log(f"first fleet mismatch (seq, tenant, topic, got, want): "
+            f"{fleet['first_bad']}")
+    for e in report["errors"]:
+        log(f"load generator error: {e}")
+
+    res = {"correct": correct, "attempted": len(pubs), "failed": failed,
+           "e2e": e2e, "compared": compared, "peak": peak,
+           "report": report, "snaps": snaps, "state": state}
+
+    # ---- per-layer numbers (a traced run's)
+    if args.trace:
+        reduced = None
+        if trace_info:
+            import trace_reduce
+            path = trace_reduce.find_xplane(trace_info["dir"])
+            if path:
+                reduced = trace_reduce.reduce_trace(path,
+                                                    trace_info["window_s"])
+                log(f"trace {os.path.getsize(path):,} B: busy "
+                    f"{reduced['busy_s']:.4f}s of {reduced['window_s']:.3f}s; "
+                    f"programs {json.dumps(reduced['programs'])}")
+                if args.keep_trace:
+                    os.makedirs(args.keep_trace, exist_ok=True)
+                    shutil.copy(path, args.keep_trace)
+            shutil.rmtree(trace_info["dir"], ignore_errors=True)
+        tenants, pop = plan["tenants"], plan["population"]
+        uniq = {(tenants[p[1]], pop[p[2]]) for p in pubs}
+        n_uniq = max(1, len(uniq))
+        ref_work = {
+            "visited_per_topic": sum(ref.visited(t, k) for t, k in uniq) / n_uniq,
+            "matched_per_topic": sum(ref.expect(t, k)[0] for t, k in uniq) / n_uniq,
+            "traced_topics": (sum(n for ts, n in drain.stamps
+                                  if trace_info["wall_a"] <= ts
+                                  <= trace_info["wall_b"])
+                              if trace_info else 0)}
+        import roofline
+        ctx = {"report": report, "before": snaps["before"],
+               "after": snaps["after"], "batches": b, "trace": reduced,
+               "reference": ref_work, "device": state, "seconds": counted_s,
+               "route_deliveries": (snaps["after"]["fleet.total"]
+                                    - snaps["before"]["fleet.total"]),
+               "peaks": (roofline.peaks_for(devices[0].device_kind)
+                         if platform != "cpu" else None)}
+        layer = {}
+        for m in cell["bench"]["per_layer"]:
+            if args.workload not in m.get("workloads", [args.workload]):
+                continue
+            spec = traffic_mod.load_json("layer_metrics", m["name"] + ".json")
+            reader = importlib.import_module(f"readers.{spec['reader']}")
+            value = reader.read(ctx)
+            if value is not None:
+                layer[m["name"]] = {"value": value, "unit": m["unit"]}
+        res["layer"] = layer
+        res["reduced"] = reduced
+        log(f"reference work per walked topic: {ref_work}")
+    return res
+
+
+# --------------------------------------------------------------- the line
+
+def result_line(args, cell, res, devices) -> dict:
+    bench = cell["bench"]
+    if args.trace:
+        metrics = res["layer"]
+    else:
+        metrics = {}
+        for m in bench["end_to_end"]:
+            if args.workload in m.get("workloads", [args.workload]):
+                v = res["e2e"].get(m["name"])
+                if v is not None:
+                    metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+    device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
+              "count": len(devices), "memory_peak_bytes": res["peak"]}
+    line = {"correct": res["correct"], "attempted": res["attempted"],
+            "failed": res["failed"], "metrics": metrics, "device": device}
+    if args.trace and res.get("reduced"):
+        device["busy_s"] = res["reduced"]["busy_s"]
+        device["window_s"] = res["reduced"]["window_s"]
+        line["breakdown"] = {"device_ops": res["reduced"]["device_ops"],
+                             "idle_gaps": res["reduced"]["idle_gaps"]}
+    line["compared"] = res["compared"]
+    return line
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, default=0)
+    ap.add_argument("--rehearse-cpu", action="store_true")
+    ap.add_argument("--sweep", type=lambda s: [float(x) for x in s.split(",")])
+    ap.add_argument("--seeds", type=lambda s: [int(x) for x in s.split(",")])
+    ap.add_argument("--control", default="")
+    ap.add_argument("--keep-trace", default="")
+    ap.add_argument("--bench-file", default="")
+    args = ap.parse_args(argv)
+    cell = traffic_mod.load_cell(args.workload, args.bench_file)
+    if not os.path.isdir(os.path.join(os.path.dirname(HERE), "bifromq_tpu")):
+        print("benchmark: the program (bifromq_tpu/) is not in this "
+              "directory — nothing to measure", file=sys.stderr)
+        return 3
+    os.makedirs(OUT_DIR, exist_ok=True)
+    results = asyncio.run(run(args, cell))
+    import jax
+    devices = jax.devices()
+    if len(results) > 1:
+        for res in results:
+            summarise(res)
+    res = results[-1]
+    line = result_line(args, cell, res, devices)
+    for name, (value, limit) in line["compared"].items():
+        print(f"compared {name}: {value} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
+    print(json.dumps(line), flush=True)
+    return 0
+
+
+def summarise(res) -> None:
+    """Sweep / many-seed lines: what a builder reads off one set-up."""
+    rep = res["report"]
+    # ack lateness of the QoS 1 publishes, first quarter against last:
+    # a backlog that grows through the window shows here
+    lat = sorted((p[5], (p[7] - p[5]) / 1e6) for p in rep["publishes"]
+                 if p[3] == 1 and p[7])
+    q = max(1, len(lat) // 4)
+    first = statistics.median(v for _d, v in lat[:q]) if lat else 0
+    last = statistics.median(v for _d, v in lat[-q:]) if lat else 0
+    bad = {k: v for k, v in res["compared"].items()
+           if isinstance(v[1], int) and v[0] > v[1]}
+    print(json.dumps({
+        "seed": res["seed"], "rate": res["rate"], "correct": res["correct"],
+        "publishes": res["attempted"], "failed": res["failed"],
+        "p50_ms": res["e2e"]["deliver_p50_ms"],
+        "p95_ms": res["e2e"]["deliver_p95_ms"],
+        "delivered_per_s": res["e2e"]["delivered_per_s"],
+        "subscribe_p50_ms": res["e2e"]["subscribe_p50_ms"],
+        "ack_after_due_first_quarter_ms": first,
+        "ack_after_due_last_quarter_ms": last, "over_limit": bad}), flush=True)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,180 @@
+#!/usr/bin/env python3
+"""Checks of the yardstick itself. CPU, no chip, no broker, seconds.
+
+    python3 benchmarks/selfcheck.py
+
+- the trace reduction on the small recorded trace beside it gives the
+  busy / idle numbers written down when it was recorded, and its interval
+  arithmetic gives hand-worked answers;
+- the plain reference agrees with hand-written ``+`` / ``#`` / ``$share``
+  / ``$``-topic cases, and its two forms agree with each other;
+- the schedule is a pure function of ``--seed``, and seeds share the work;
+- every file under ``configs/``, ``traffic/``, ``layer_metrics/`` loads,
+  every name and unit holds only the allowed characters, every per-layer
+  metric of BENCHMARK.json has its file and its reader.
+"""
+
+from __future__ import annotations
+
+import glob
+import importlib
+import json
+import os
+import random
+import re
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+
+import reference  # noqa: E402
+import trace_reduce  # noqa: E402
+import traffic  # noqa: E402
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+def check(cond, what: str) -> None:
+    if not cond:
+        raise SystemExit(f"selfcheck FAILED: {what}")
+
+
+def check_intervals() -> None:
+    iv = [(0, 10), (5, 20), (30, 40), (32, 35)]
+    check(trace_reduce.union_ns(iv) == 30, "union of overlapping intervals")
+    check(trace_reduce.gaps_ns(iv, 0, 50) == [(20, 30), (40, 50)], "gaps")
+    check(trace_reduce.program_name("jit_walk_routes_donated(123)")
+          == "walk_routes_donated", "program name")
+
+
+def check_recorded_trace() -> None:
+    with open(os.path.join(HERE, "testdata", "small_trace.expected.json")) as f:
+        want = json.load(f)
+    got = trace_reduce.reduce_trace(
+        os.path.join(HERE, "testdata", "small_trace.xplane.pb"),
+        want["window_s"])
+    check(got is not None, "recorded trace has a device plane")
+    check(abs(got["busy_s"] - want["busy_s"]) < 1e-9,
+          f"busy_s {got['busy_s']} != recorded {want['busy_s']}")
+    for prog, secs in want["programs"].items():
+        check(abs(got["programs"][prog]["seconds"] - secs) < 1e-9,
+              f"device time of {prog}")
+    idle = 100.0 * (1 - got["busy_s"] / got["window_s"])
+    check(abs(idle - want["idle_share"]) < 1e-6, "idle share")
+
+
+CASES = [  # (filter, topic, matches)
+    ("a/b", "a/b", True), ("a/b", "a/b/c", False), ("a/+", "a/b", True),
+    ("a/+", "a", False), ("a/+", "a/b/c", False), ("+/+", "a/b", True),
+    ("a/#", "a", True), ("a/#", "a/b/c", True), ("#", "a/b", True),
+    ("+", "a", True), ("+", "a/b", False), ("a/+/c", "a/b/c", True),
+    ("a/+/c", "a//c", True), ("a/b/#", "a", False),
+    ("#", "$SYS/x", False), ("+/x", "$SYS/x", False),
+    ("$SYS/#", "$SYS/x", True), ("$SYS/+", "$SYS/x", True),
+    ("a/+", "a/", True), ("/", "/", True), ("+/+", "/", True),
+]
+
+
+def check_reference() -> None:
+    for flt, topic, want in CASES:
+        got = reference.filter_matches(flt.split("/"), topic.split("/"))
+        check(got == want, f"filter_matches({flt!r}, {topic!r}) = {got}")
+        via = tuple(flt.split("/")) in set(
+            reference.generalisations(topic.split("/")))
+        check(via == want, f"generalisations({topic!r}) vs {flt!r} = {via}")
+    check(reference.split_filter("$share/g1/a/+") == ("$share/g1", ("a", "+")),
+          "$share prefix")
+    check(reference.split_filter("$oshare/g/a/#") == ("$oshare/g", ("a", "#")),
+          "$oshare prefix")
+    check(reference.split_filter("a/$share/x") == (None, ("a", "$share", "x")),
+          "a filter that merely holds $share")
+    gen = importlib.import_module("generators.zipf_tree")
+    rng = random.Random(7)
+    names, cum = gen.level_names(12)
+    table = reference.Table()
+    filters = []
+    for i in range(3000):
+        levels = tuple(gen.gen_filter(rng, names, cum, max_depth=4,
+                                      p_plus=0.3, p_hash=0.2))
+        filters.append(levels)
+        table.add("t", levels, (i,))
+    for _ in range(300):
+        topic = gen.gen_topic(rng, names, cum, max_depth=4)
+        brute = sorted(i for i, f in enumerate(filters)
+                       if reference.filter_matches(f, topic))
+        fast = sorted(r[0] for r in table.match("t", "/".join(topic)))
+        check(brute == fast, f"table.match != definition on {topic}")
+    check(table.match("other", "l0") == [], "no row crosses a tenant")
+    check(len(reference.truncated(list(range(100)), 64)) == 64, "control")
+
+
+def check_schedule() -> None:
+    cfg = traffic.load_json("configs", "rehearsal_20k.json")
+    for mix in ("rehearsal_open", "rehearsal_closed"):
+        tr = traffic.load_json("traffic", mix + ".json")
+        big = 2 ** 31 + 12345
+        a = traffic.build_plan(cfg, tr, big, 5.0)
+        b = traffic.build_plan(cfg, tr, big, 5.0)
+        c = traffic.build_plan(cfg, tr, big + 1, 5.0)
+        check(traffic.fingerprint(a) == traffic.fingerprint(b),
+              f"{mix}: the same seed gives another plan")
+        check(traffic.fingerprint(a) != traffic.fingerprint(c),
+              f"{mix}: another seed gives the same plan")
+        key = "arrivals" if tr["loop"] == "open" else "cycle"
+        check(sorted(x[-2:] for x in a[key]) == sorted(x[-2:] for x in c[key]),
+              f"{mix}: seeds do not share the multiset of (tenant, topic)")
+        check(a["subs"] == c["subs"], f"{mix}: live filters differ by seed")
+        if tr["loop"] == "open":
+            check(all(0 <= x[0] < 5.0 for x in a["arrivals"]),
+                  "an arrival outside the window")
+
+
+def check_files() -> None:
+    root = os.path.dirname(HERE)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for kind in ("configs", "traffic", "layer_metrics"):
+        for path in glob.glob(os.path.join(HERE, kind, "*.json")):
+            with open(path) as f:
+                data = json.load(f)
+            stem = os.path.basename(path)[:-5]
+            check(NAME.match(stem), f"file name {stem!r}")
+            if kind == "layer_metrics":
+                check(data["name"] == stem, f"{path}: name != file name")
+                check(UNIT.match(data["unit"]), f"{path}: unit")
+                check(data["better"] in ("lower", "higher"), f"{path}: better")
+                importlib.import_module(f"readers.{data['reader']}").read
+            if kind == "configs":
+                importlib.import_module(f"generators.{data['generator']}")
+                for key in data.get("reduced", []):
+                    check(NAME.match(key), f"{path}: reduced key {key!r}")
+    cells = {w["name"] for w in bench["workloads"]}
+    e2e = {m["name"] for m in bench["end_to_end"]}
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        check(NAME.match(m["name"]) and UNIT.match(m["unit"]),
+              f"metric {m['name']!r} / unit {m['unit']!r}")
+        check(set(m.get("workloads", [])) <= cells, f"{m['name']}: cells")
+    for m in bench["per_layer"]:
+        check(m["moves"] in e2e, f"{m['name']} moves {m['moves']!r}")
+        spec = traffic.load_json("layer_metrics", m["name"] + ".json")
+        for key in ("unit", "better", "source", "layer", "moves"):
+            check(spec[key] == m[key], f"{m['name']}: {key} differs from "
+                  "its file under layer_metrics/")
+    for w in bench["workloads"]:
+        check(NAME.match(w["name"]) and len(w["why"]) <= 200, w["name"])
+        traffic.load_cell(w["name"])
+    peaks = traffic.load_json("peaks.json")
+    check(peaks["source"] and "TPU v5 lite" in peaks["peaks"], "peaks.json")
+
+
+def main() -> None:
+    for fn in (check_intervals, check_reference, check_schedule, check_files,
+               check_recorded_trace):
+        fn()
+        print(f"ok  {fn.__name__}")
+    print("selfcheck passed")
+
+
+if __name__ == "__main__":
+    main()

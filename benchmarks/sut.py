@@ -1,0 +1,392 @@
+"""The system under test, as the benchmark holds it: everything that
+imports the program (``bifromq_tpu``) or JAX lives here.
+
+From the program it takes the entry point (``starter.Standalone``), the
+plug-in seats (``ISubBroker``, ``ISettingProvider``), its counters
+(``STAGES``, ``FABRIC``, the profiler's ``BatchRecord``s and patch
+ledger) and the names of its jitted programs. Copied from
+``chip_smoke.py``: ``claim_devices``, the compile-cache counter, the
+fleet stand-in's seat and ``device_verdict``'s conditions.
+"""
+
+from __future__ import annotations
+
+import os
+import struct
+import sys
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+if ROOT not in sys.path:
+    sys.path.insert(1, ROOT)
+
+FLEET_BROKER_ID = 7
+WARM_FLAG = 1 << 62
+HEADER = struct.Struct(">Qq")
+MASK = (1 << 64) - 1
+
+
+def log(msg: str) -> None:
+    print(f"[bench +{time.monotonic() - log.t0:7.1f}s] {msg}", flush=True)
+
+
+log.t0 = time.monotonic()
+
+
+# ------------------------------------------------------------------ device
+
+def claim_devices(n: int, rehearse_cpu: bool = False):
+    """The platform assertion, before anything else touches the broker.
+    No chip -> exit 2 and no result line. ``rehearse_cpu`` is the
+    builder's rehearsal: it reports platform "cpu" and no device metric."""
+    import jax
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    from bifromq_tpu.utils.jaxenv import setup_compile_cache
+    cache_dir = setup_compile_cache()
+    devices = jax.devices()
+    if not rehearse_cpu:
+        if jax.default_backend() != "tpu" or len(devices) < n \
+                or any(d.platform != "tpu" for d in devices):
+            print(f"benchmark: need {n} tpu device(s), JAX found "
+                  f"{[str(d) for d in devices]} — no chip, no run",
+                  file=sys.stderr)
+            sys.exit(2)
+    log(f"devices: {len(devices)} x {devices[0].device_kind}; "
+        f"compile cache: {cache_dir}")
+    return devices
+
+
+class CompileCounter:
+    """Persistent-cache hits and misses, and every backend compile with
+    its time, so that compiles inside the window can be counted."""
+
+    def __init__(self) -> None:
+        import jax
+        self.hits = self.misses = 0
+        self.compiles = []           # (monotonic_ns at its end, name, s)
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(self._on_dur)
+
+    def _on_event(self, name: str, **_kw) -> None:
+        if name == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif name == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def _on_dur(self, name: str, secs: float, **kw) -> None:
+        # fires for every program JAX builds or fetches from the
+        # persistent cache: either way a shape the process had not met
+        if name == "/jax/core/compile/backend_compile_duration":
+            self.compiles.append((time.monotonic_ns(),
+                                  str(kw.get("fun_name")), secs))
+
+    def between(self, t0_ns: int, t1_ns: int) -> list:
+        return [(n, round(s, 3)) for t, n, s in self.compiles
+                if t0_ns <= t < t1_ns]
+
+
+# ------------------------------------------------------------ plug-in seats
+
+Settings = None      # the class the starter's plug-in loader finds: "sut:Settings"
+
+
+def install_settings(values: dict):
+    """ISettingProvider seat: the configuration file's ``settings``, by
+    ``Setting`` name. Returns the class now bound to ``sut:Settings``."""
+    global Settings
+    from bifromq_tpu.plugin.settings import ISettingProvider
+    values = dict(values)
+
+    class _Settings(ISettingProvider):
+        def provide(self, setting, tenant_id):
+            return values.get(setting.name)
+    Settings = _Settings
+    return _Settings
+
+
+class FleetStandIn:
+    """The receiver fleet the table's subscriptions belong to, on the
+    ISubBroker seat. Cheap inside the window: per deliver call a count and
+    an order-independent digest of receiver ids under the publish's seq;
+    full receiver sets only for the sampled publishes."""
+
+    id = FLEET_BROKER_ID
+
+    def __init__(self) -> None:
+        from bifromq_tpu.plugin.subbroker import DeliveryResult
+        self._ok = DeliveryResult.OK
+        self.t0 = self.t1 = 0
+        self.stride, self.offset = 1 << 62, 0
+        self.reset()
+        self.annotate = None         # TraceAnnotation factory while tracing
+
+    def reset(self) -> None:
+        self.count = {}              # seq -> route deliveries
+        self.digest = {}             # seq -> sum of hash(receiver id)
+        self.qos = {}                # seq -> set of pub qos seen
+        self.sets = {}               # sampled seq -> {dkey: [receiver ids]}
+        self.calls = 0
+        self.in_window = 0
+        self.total = 0
+        self.spent_s = 0.0
+
+    def arm(self, t0: int, t1: int, stride: int, offset: int) -> None:
+        self.reset()
+        self.t0, self.t1, self.stride, self.offset = t0, t1, stride, offset
+
+    async def deliver(self, tenant_id, deliverer_key, packs):
+        t_in = time.perf_counter()
+        span = self.annotate("fleet.deliver") if self.annotate else None
+        if span is not None:
+            span.__enter__()
+        now = time.monotonic_ns()
+        out = {}
+        for dp in packs:
+            infos = dp.match_infos
+            n = len(infos)
+            rids = [mi.receiver_id for mi in infos]
+            dig = sum(map(hash, rids))
+            for pmp in dp.message_pack.packs:
+                for msg in pmp.messages:
+                    self.total += n
+                    if self.t0 <= now < self.t1:
+                        self.in_window += n
+                    payload = msg.payload
+                    if len(payload) < HEADER.size:
+                        continue
+                    seq = HEADER.unpack_from(payload)[0]
+                    if seq >= WARM_FLAG:
+                        continue
+                    self.count[seq] = self.count.get(seq, 0) + n
+                    self.digest[seq] = (self.digest.get(seq, 0) + dig) & MASK
+                    self.qos.setdefault(seq, set()).add(int(msg.pub_qos))
+                    if seq % self.stride == self.offset:
+                        self.sets.setdefault(seq, {}).setdefault(
+                            (tenant_id, deliverer_key), []).extend(rids)
+            out.update(dict.fromkeys(infos, self._ok))
+        self.calls += 1
+        if span is not None:
+            span.__exit__(None, None, None)
+        self.spent_s += time.perf_counter() - t_in
+        return out
+
+    async def check_subscriptions(self, tenant_id, match_infos):
+        return [True] * len(match_infos)
+
+
+# ------------------------------------------------------------ build + seed
+
+def build_tries(rows):
+    """The generated rows as the program's input types. Returns the tries
+    and the row count. ``rows`` yields (tenant, levels, receiver, dkey)."""
+    from bifromq_tpu.models.oracle import Route, SubscriptionTrie
+    from bifromq_tpu.types import RouteMatcher, RouteMatcherType
+    normal = RouteMatcherType.NORMAL
+    tries, n = {}, 0
+    for tenant, levels, rid, dkey in rows:
+        trie = tries.get(tenant)
+        if trie is None:
+            trie = tries[tenant] = SubscriptionTrie()
+        trie.add(Route(
+            matcher=RouteMatcher(type=normal, filter_levels=levels,
+                                 mqtt_topic_filter="/".join(levels)),
+            broker_id=FLEET_BROKER_ID, receiver_id=rid, deliverer_key=dkey))
+        n += 1
+    return tries, n
+
+
+def seed_worker(worker, tries) -> dict:
+    """Give the started worker the state a restarted one holds: the
+    route keyspace in its range's KV space, and the matcher derived from
+    it, built in bulk by ``TpuMatcher.from_tries``. (``matcher_factory``
+    cannot carry it: the range's ``reset`` on open replaces whatever
+    matcher the factory made with ``clone_empty()``.)"""
+    import jax
+    from bifromq_tpu.kv import schema
+    from bifromq_tpu.models.matcher import TpuMatcher
+    (rid, coproc), = worker.store.coprocs.items()
+    t0 = time.perf_counter()
+    matcher = TpuMatcher.from_tries(tries, device=jax.devices()[0])
+    t_build = time.perf_counter() - t0
+    coproc.matcher = matcher
+    coproc._wire_repl_hooks()
+    t0 = time.perf_counter()
+    space = worker.store.ranges[rid].space
+    w = space.writer()
+    value = schema.route_value(0)
+    for tenant_id, trie in tries.items():
+        for route in trie.routes():
+            w.put(schema.route_key(tenant_id, route.matcher,
+                                   route.receiver_url), value)
+    w.done()
+    coproc._fact_reader = space
+    coproc._fact_dirty = True
+    return {"from_tries_s": t_build, "kv_fill_s": time.perf_counter() - t0,
+            "matcher": matcher}
+
+
+# -------------------------------------------------------------- counters
+
+def install_stage_sums():
+    """The program's stage histograms are log2 buckets with no sum. Put a
+    summing twin in each named slot, so that a mean is exact."""
+    from bifromq_tpu.utils.metrics import STAGES, LatencyHistogram
+
+    class Summing(LatencyHistogram):
+        def __init__(self) -> None:
+            super().__init__()
+            self.sum_s = 0.0
+            self.n = 0
+
+        def record(self, seconds: float) -> None:
+            self.sum_s += seconds
+            self.n += 1
+            super().record(seconds)
+    for stage in ("ingest", "queue_wait", "device", "deliver"):
+        STAGES._hists[stage] = Summing()
+    return STAGES
+
+
+def counters(matcher, stand_in) -> dict:
+    """One reading of every program counter the layer metrics use."""
+    from bifromq_tpu.obs import OBS
+    from bifromq_tpu.utils.metrics import FABRIC, MATCH_CACHE, STAGES, \
+        FabricMetric
+    prof = OBS.profiler
+    out = {"t_ns": time.monotonic_ns()}
+    for stage, h in STAGES._hists.items():
+        if hasattr(h, "sum_s"):
+            out[f"stage.{stage}.sum_s"] = h.sum_s
+            out[f"stage.{stage}.n"] = h.n
+    mc = MATCH_CACHE.snapshot()
+    pub = mc.get("pub", {}) if isinstance(mc, dict) else {}
+    out["pubcache.hits"] = pub.get("hits", 0)
+    out["pubcache.misses"] = pub.get("misses", 0)
+    out["frontend.queries"] = prof.frontend_queries_total
+    out["frontend.hits"] = prof.cache_hits_total
+    out["batches"] = prof.batches_total
+    out["queries"] = prof.queries_total
+    out["padded_rows"] = prof.padded_rows_total
+    out["patch.count"] = matcher.patch_count
+    out["patch.fallbacks"] = matcher.patch_fallbacks
+    out["patch.flushes"] = matcher.patch_flushes
+    out["patch.host_s"] = matcher.patch_host_s
+    out["patch.device_s"] = matcher.patch_device_s
+    out["compile_count"] = matcher.compile_count
+    out["match_degraded"] = FABRIC.get(FabricMetric.MATCH_DEGRADED)
+    out["device_timeout"] = FABRIC.get(FabricMetric.DEVICE_TIMEOUT)
+    out["warmup_failed"] = FABRIC.get(FabricMetric.WARMUP_FAILED)
+    out["fleet.total"] = stand_in.total
+    out["fleet.calls"] = stand_in.calls
+    out["fleet.spent_s"] = stand_in.spent_s
+    return out
+
+
+class BatchDrain:
+    """Drains the profiler's ring (2,048 records) into sums by kernel
+    while the window runs, so that no batch is lost to the wrap."""
+
+    FIELDS = ("tokenize_s", "dispatch_s", "ready_s", "fetch_s", "expand_s",
+              "dev_expand_s")
+
+    def __init__(self) -> None:
+        from bifromq_tpu.obs import OBS
+        self.prof = OBS.profiler
+        _recs, self.cursor, _missed = self.prof.since(0)
+        self.reset()
+
+    def reset(self) -> None:
+        self.kernels = {}
+        self.sums = dict.fromkeys(self.FIELDS, 0.0)
+        self.n = self.rows = self.padded = self.missed = 0
+        self.stamps = []             # (wall ts, n_queries) per batch
+
+    def drain(self) -> None:
+        recs, self.cursor, missed = self.prof.since(self.cursor)
+        self.missed += missed
+        for r in recs:
+            self.n += 1
+            self.rows += r.n_queries
+            self.padded += r.batch
+            self.kernels[r.kernel] = self.kernels.get(r.kernel, 0) + 1
+            for f in self.FIELDS:
+                self.sums[f] += getattr(r, f)
+            self.stamps.append((r.ts, r.n_queries))
+
+
+def device_state(matcher, platform: str) -> dict:
+    """Where the resident tables are, and their record widths."""
+    import jax
+    dev = matcher._device_trie
+    leaves = [a for a in jax.tree_util.tree_leaves(dev) if a is not None]
+    on = set()
+    for a in leaves:
+        on |= set(a.devices())
+    widths = {}
+    for name in ("edge_tab", "child_list", "route_tab", "node_tab"):
+        a = getattr(dev, name, None)
+        if a is not None and getattr(a, "ndim", 0) >= 1:
+            row = int(a.dtype.itemsize)
+            for d in a.shape[1:]:
+                row *= int(d)
+            widths[name] = row
+    return {"resident_bytes": sum(int(a.nbytes) for a in leaves),
+            "on": sorted(str(d) for d in on),
+            "all_on_platform": all(d.platform == platform for d in on),
+            "n_devices": len(on), "record_bytes": widths}
+
+
+def table_shapes(matcher) -> tuple:
+    """Shapes of the host arenas the next flush ships: a change means the
+    device tables reshape and the walk re-traces."""
+    base = matcher._base_ct
+    return tuple(tuple(getattr(base, n).shape)
+                 for n in ("node_tab", "edge_tab", "child_list"))
+
+
+def table_fill(matcher) -> dict:
+    base = matcher._base_ct
+    return {k: int(getattr(base, k, -1)) for k in
+            ("n_live", "child_used", "edge_regrows", "node_grows")}
+
+
+def memory_peak_bytes() -> int:
+    import jax
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+             for d in jax.local_devices()]
+    return int(max(peaks)) if peaks else 0
+
+
+# ------------------------------------------------- controls and faults
+
+def _wrap_match(worker, alter) -> None:
+    inner = worker.match_batch
+
+    async def match_batch(queries, **kw):
+        out = await inner(queries, **kw)
+        for m in out:
+            alter(m)
+        return out
+    worker.match_batch = match_batch
+
+
+def _truncate64(m) -> None:
+    # the device's rows hold 64 matches; a fuller row is re-expanded on
+    # the host. The answer WITHOUT that step is the first 64.
+    if len(m.normal) > 64:
+        m.normal = m.normal[:64]
+
+
+def _drop_one(m) -> None:
+    if m.normal:
+        m.normal = m.normal[:-1]
+
+
+# name -> fn(worker): put a broken guarantee in the matcher's place. The
+# benchmark's own runs never use them; ``--control`` and the tests do.
+CONTROLS = {
+    "truncate64": lambda worker: _wrap_match(worker, _truncate64),
+    "drop_one": lambda worker: _wrap_match(worker, _drop_one),
+}
