@@ -2,23 +2,28 @@
 
 Observability names are stringly-typed: a typo'd span or stage name
 silently creates a new series nobody dashboards, and a README table row
-for a deleted span misleads the operator reading a live trace. Checks:
+for a deleted span misleads the operator reading a live trace. There is
+ONE registry of boundary names, the table in ``trace/names.py``; the
+README's span table is generated from it. Checks:
 
-- **R5/span-doc**: every span name opened in code (``trace.span(...)``,
-  ``trace.record_finished(...)``) must appear in the README (backticked
-  anywhere); every row of the README "Span taxonomy" table must still be
-  opened somewhere in code.
+- **R5/span**: every span or counter name opened in code
+  (``trace.span(...)``, ``trace.record_finished(...)``,
+  ``trace.count(...)``) must have a row in the registry, and every
+  ``span`` / ``counter`` row must still be opened somewhere in code.
+- **R5/span-doc**: the README's span table must hold exactly the
+  registry's names (regenerate it: ``python -m bifromq_tpu.trace
+  --write``).
 - **R5/stage**: every literal stage fed to the always-on stage
-  histograms (``STAGES.record``, ``Batcher(stage=...)``,
-  ``OBS.record_latency``) must be in ``utils.metrics.KNOWN_STAGES``, and
-  every registered stage must be emitted somewhere (dead registry
-  entries fail too).
+  histograms by hand (``STAGES.record``, ``Batcher(stage=...)``,
+  ``OBS.record_latency``) must be a stage some row names, and every
+  such stage must be emitted somewhere: by a literal, or by a span whose
+  row feeds it (dead registry entries fail too).
 - **R5/cache-field**: literal fields passed to ``MATCH_CACHE.inc`` must
   be declared in ``MatchCacheMetrics._FIELDS``.
 
-Both registries are parsed from the analyzed tree's
-``utils/metrics.py``; when the root has none (fixture runs), the
-installed package's registry is used so fixture snippets still check.
+The registries are parsed from the analyzed tree's ``trace/names.py``
+and ``utils/metrics.py``; when the root has none (fixture runs), the
+installed package's are used so fixture snippets still check.
 """
 
 from __future__ import annotations
@@ -29,7 +34,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 from .core import Context, Finding, ParsedFile, Rule, dotted_name
 
-_SPAN_OPENERS = {"span", "record_finished"}
+_SPAN_OPENERS = {"span", "record_finished", "count"}
 _SPAN_NAME_RE = re.compile(r"^[a-z_]+(\.[a-z_]+)+$")
 _BACKTICK_RE = re.compile(r"`([^`]+)`")
 
@@ -72,32 +77,52 @@ def _readme_span_table(readme: str) -> Set[str]:
     return out
 
 
-def _parse_registries(pf: Optional[ParsedFile]) -> Tuple[Set[str],
-                                                         Set[str]]:
-    """(KNOWN_STAGES, MatchCacheMetrics._FIELDS) from a metrics module's
-    AST; falls back to the installed package when the analyzed root has
-    no utils/metrics.py."""
+def _find(ctx: Context, suffix: str) -> Optional[ParsedFile]:
+    for pf in ctx.files:
+        if pf.path.replace("\\", "/").endswith(suffix):
+            return pf
+    return None
+
+
+def _parse_boundaries(pf: Optional[ParsedFile]) -> Dict[str, dict]:
+    """``{name: {kind, stage, window, by_hand}}`` from the ``_row(...)``
+    calls of a ``trace/names.py`` AST; the installed package's table when
+    the analyzed root has none."""
     if pf is None:
-        from ..utils.metrics import KNOWN_STAGES, MatchCacheMetrics
-        return set(KNOWN_STAGES), set(MatchCacheMetrics._FIELDS)
-    stages: Set[str] = set()
-    fields: Set[str] = set()
+        from ..trace.names import BOUNDARIES
+        return {b.name: {"kind": b.kind, "stage": b.stage,
+                         "window": b.window, "by_hand": b.by_hand}
+                for b in BOUNDARIES.values()}
+    rows: Dict[str, dict] = {}
+    for node in ast.walk(pf.tree):
+        if not (isinstance(node, ast.Call)
+                and dotted_name(node.func) == "_row"
+                and len(node.args) >= 2
+                and all(isinstance(a, ast.Constant) for a in node.args[:2])):
+            continue
+        row = {"kind": node.args[1].value, "stage": None, "window": None,
+               "by_hand": False}
+        for kw in node.keywords:
+            if kw.arg in row and isinstance(kw.value, ast.Constant):
+                row[kw.arg] = kw.value.value
+        rows[node.args[0].value] = row
+    return rows
 
-    def str_elts(node: ast.AST) -> Set[str]:
-        vals: Set[str] = set()
-        for n in ast.walk(node):
-            if isinstance(n, ast.Constant) and isinstance(n.value, str):
-                vals.add(n.value)
-        return vals
 
+def _parse_cache_fields(pf: Optional[ParsedFile]) -> Set[str]:
+    """``MatchCacheMetrics._FIELDS`` from a metrics module's AST; the
+    installed package's when the analyzed root has no utils/metrics.py."""
+    if pf is None:
+        from ..utils.metrics import MatchCacheMetrics
+        return set(MatchCacheMetrics._FIELDS)
     for node in ast.walk(pf.tree):
         if isinstance(node, ast.Assign) and len(node.targets) == 1:
             t = node.targets[0]
-            if isinstance(t, ast.Name) and t.id == "KNOWN_STAGES":
-                stages = str_elts(node.value)
-            elif isinstance(t, ast.Name) and t.id == "_FIELDS":
-                fields = str_elts(node.value)
-    return stages, fields
+            if isinstance(t, ast.Name) and t.id == "_FIELDS":
+                return {n.value for n in ast.walk(node.value)
+                        if isinstance(n, ast.Constant)
+                        and isinstance(n.value, str)}
+    return set()
 
 
 class RegistryDriftRule(Rule):
@@ -106,33 +131,40 @@ class RegistryDriftRule(Rule):
 
     def run(self, ctx: Context) -> List[Finding]:
         out: List[Finding] = []
-        metrics_pf = None
-        for pf in ctx.files:
-            if pf.path.replace("\\", "/").endswith("utils/metrics.py"):
-                metrics_pf = pf
-                break
-        known_stages, cache_fields = _parse_registries(metrics_pf)
+        names_pf = _find(ctx, "trace/names.py")
+        rows = _parse_boundaries(names_pf)
+        cache_fields = _parse_cache_fields(_find(ctx, "utils/metrics.py"))
+        known_stages = {s for r in rows.values()
+                        for s in (r["stage"], r["window"]) if s}
         spans = _collect_spans(ctx)
 
-        # -- span <-> README ------------------------------------------------
-        if ctx.readme_text is not None:
-            # substring check, not backtick pairing: README code fences
-            # make global backtick pairing ambiguous
-            for name, sites in sorted(spans.items()):
-                if name not in ctx.readme_text:
-                    path, line, scope = sites[0]
+        # -- span <-> registry <-> README ------------------------------------
+        for name, sites in sorted(spans.items()):
+            if name not in rows:
+                path, line, scope = sites[0]
+                out.append(Finding(
+                    rule=self.rule_id, path=path, line=line,
+                    scope=scope, symbol=name,
+                    message=(f"boundary `{name}` is opened in code but "
+                             f"has no row in trace/names.py")))
+        if names_pf is not None:
+            for name, row in sorted(rows.items()):
+                if row["kind"] != "stage" and name not in spans:
                     out.append(Finding(
-                        rule=self.rule_id, path=path, line=line,
-                        scope=scope, symbol=name,
-                        message=(f"span `{name}` is opened in code but "
-                                 f"not documented in README")))
-            for name in sorted(_readme_span_table(ctx.readme_text)):
-                if name not in spans:
+                        rule=self.rule_id, path=names_pf.path, line=0,
+                        scope="<BOUNDARIES>", symbol=name,
+                        message=(f"registry row `{name}` is opened "
+                                 f"nowhere in code — dead entry")))
+            if ctx.readme_text is not None:
+                documented = _readme_span_table(ctx.readme_text)
+                for name in sorted(set(rows) ^ documented):
                     out.append(Finding(
                         rule=self.rule_id, path="README.md", line=0,
                         scope="<span-table>", symbol=name,
-                        message=(f"README span-taxonomy row `{name}` is "
-                                 f"opened nowhere in code — stale doc")))
+                        message=(f"README span table and trace/names.py "
+                                 f"disagree on `{name}` — regenerate the "
+                                 f"table (python -m bifromq_tpu.trace "
+                                 f"--write)")))
 
         # -- stage registry --------------------------------------------------
         emitted: Dict[str, List[Tuple[str, int, str]]] = {}
@@ -152,16 +184,23 @@ class RegistryDriftRule(Rule):
                     out.append(Finding(
                         rule=self.rule_id, path=path, line=line,
                         scope=scope, symbol=stage,
-                        message=(f"stage `{stage}` recorded but not in "
-                                 f"utils.metrics.KNOWN_STAGES — typo'd "
+                        message=(f"stage `{stage}` recorded but no row of "
+                                 f"trace/names.py names it — typo'd "
                                  f"stage names create silent orphan "
                                  f"histograms")))
-            if metrics_pf is not None:
-                for stage in sorted(known_stages - set(emitted)):
+            if names_pf is not None:
+                # a stage is alive when a literal feeds it, or a span
+                # whose row feeds it (not by hand) is opened in code
+                fed = set(emitted)
+                for name, row in rows.items():
+                    if name in spans and not row["by_hand"]:
+                        fed.update(s for s in (row["stage"], row["window"])
+                                   if s)
+                for stage in sorted(known_stages - fed):
                     out.append(Finding(
-                        rule=self.rule_id, path=metrics_pf.path, line=0,
+                        rule=self.rule_id, path=names_pf.path, line=0,
                         scope="<KNOWN_STAGES>", symbol=stage,
-                        message=(f"KNOWN_STAGES entry `{stage}` is "
+                        message=(f"registered stage `{stage}` is "
                                  f"emitted nowhere — dead registry "
                                  f"entry")))
         return out

@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import hashlib
 import random
-import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -39,7 +38,6 @@ from ..types import (ClientInfo, MatchInfo, Message, PublisherMessagePack,
                      RouteMatcher, TopicMessagePack)
 from ..obs import OBS
 from ..utils import topic as topic_util
-from ..utils.metrics import STAGES
 
 
 @dataclass
@@ -287,18 +285,20 @@ class DistService:
         route = Route(matcher=matcher, broker_id=broker_id,
                       receiver_id=receiver_id, deliverer_key=deliverer_key,
                       incarnation=incarnation)
-        try:
-            out = await self.worker.add_route(tenant_id, route)
-        except Exception:  # noqa: BLE001 — consensus/transport failure
-            self.events.report(Event(EventType.MATCH_ERROR, tenant_id,
-                                     {"filter":
-                                      matcher.mqtt_topic_filter}))
-            raise
-        ok = out in ("ok", "exists")
-        if ok:
-            # filter-aware (ISSUE 4): an exact filter evicts one topic
-            # key, a wildcard bumps the tenant epoch
-            self._match_cache.invalidate(tenant_id, matcher.filter_levels)
+        with trace.span("sub.dist", tenant=tenant_id):
+            try:
+                out = await self.worker.add_route(tenant_id, route)
+            except Exception:  # noqa: BLE001 — consensus/transport failure
+                self.events.report(Event(EventType.MATCH_ERROR, tenant_id,
+                                         {"filter":
+                                          matcher.mqtt_topic_filter}))
+                raise
+            ok = out in ("ok", "exists")
+            if ok:
+                # filter-aware (ISSUE 4): an exact filter evicts one topic
+                # key, a wildcard bumps the tenant epoch
+                self._match_cache.invalidate(tenant_id,
+                                             matcher.filter_levels)
         self.events.report(Event(
             EventType.MATCHED if ok else EventType.MATCH_ERROR, tenant_id,
             {"filter": matcher.mqtt_topic_filter}
@@ -454,9 +454,8 @@ class DistService:
     async def _fan_out(self, tenant_id: str, call: PubCall,
                        matched: MatchedRoutes) -> int:
         """Span-wrapped fan-out (ISSUE 2): one "deliver.fanout" span per
-        publish with the achieved fan-out, feeding the "deliver" stage
-        histogram either way."""
-        t0 = time.perf_counter()
+        publish with the achieved fan-out; its exit feeds the "deliver"
+        stage histogram and the tenant's window either way."""
         fanout = 0
         # ISSUE 12 byte plane: wire-bytes topics decode ONCE here, at the
         # delivery boundary — the match path upstream never did
@@ -469,16 +468,81 @@ class DistService:
                 sp.set_tag("fanout", fanout)
                 return fanout
         finally:
-            dt = time.perf_counter() - t0
-            STAGES.record("deliver", dt)
-            # ISSUE 3: achieved fan-out + deliver latency feed the tenant's
-            # SLO windows (fan-out share is the detector's first signal)
-            OBS.record_latency(tenant_id, "deliver", dt)
+            # ISSUE 3: the achieved fan-out feeds the tenant's SLO window
+            # (fan-out share is the detector's first signal)
             OBS.record_fanout(tenant_id, fanout)
 
     async def _fan_out_inner(self, tenant_id: str, call: PubCall,
                              matched: MatchedRoutes,
                              topic_s: str) -> int:
+        """group -> per (broker, deliverer key): one call, its results
+        settled at once. The grouping and each call are boundaries of
+        their own (the calls are the finest grain timed, never one span
+        per route); what is left of ``deliver.fanout`` is the fan-out's
+        own time: match infos built, results read back. One group's
+        match infos and results die before the next group's are made: a
+        publish that keeps all 9k of them alive to its end pays a third
+        more, in collections."""
+        with trace.span("deliver.group"):
+            pack, by_deliverer = self._group_targets(tenant_id, call,
+                                                     matched, topic_s)
+        if not by_deliverer:
+            return 0
+        fanout = n_routes = 0
+        # cross-broker delivery (≈ mqtt-broker-client deliver RPC): a
+        # deliverer key owned by ANOTHER server makes one RPC hop to that
+        # broker node, whose local sub-brokers finish it
+        remote = self.deliverer_registry is not None and self.server_id
+        for (broker_id, dkey), routes in by_deliverer.items():
+            n_routes += len(routes)
+            match_infos = tuple(
+                MatchInfo(matcher=r.matcher, receiver_id=r.receiver_id,
+                          incarnation=r.incarnation) for r in routes)
+            owner = None
+            if remote:
+                from .deliverer import server_of
+                owner = server_of(dkey)
+            try:
+                if owner and owner != self.server_id:
+                    from .deliverer import remote_deliver
+                    with trace.span("deliver.call"):
+                        res = await remote_deliver(
+                            self.deliverer_registry, owner, tenant_id,
+                            broker_id, dkey, pack, match_infos)
+                elif not self.sub_brokers.has(broker_id):
+                    continue
+                else:
+                    broker = self.sub_brokers.get(broker_id)
+                    dp = DeliveryPack(message_pack=pack,
+                                      match_infos=match_infos)
+                    with trace.span("deliver.call"):
+                        res = await broker.deliver(tenant_id, dkey, [dp])
+            except Exception as e:  # noqa: BLE001
+                self.events.report(Event(EventType.DELIVER_ERROR,
+                                         tenant_id, {"error": repr(e)}))
+                OBS.record_delivery_violation(tenant_id, 0,
+                                              "deliver_error")
+                continue
+            for route, mi in zip(routes, match_infos):
+                outcome = res.get(mi, DeliveryResult.ERROR)
+                if outcome == DeliveryResult.OK:
+                    fanout += 1
+                elif outcome in (DeliveryResult.NO_SUB,
+                                 DeliveryResult.NO_RECEIVER):
+                    # dead route cleanup (≈ BatchDeliveryCall NO_SUB handling)
+                    await self.worker.remove_route(
+                        tenant_id, route.matcher, route.receiver_url,
+                        route.incarnation)
+                    self._match_cache.invalidate(
+                        tenant_id, route.matcher.filter_levels)
+        trace.count("deliver.routes", n_routes)
+        return fanout
+
+    def _group_targets(self, tenant_id: str, call: PubCall,
+                       matched: MatchedRoutes, topic_s: str):
+        """Election, byte cap and grouping: the message pack and the
+        routes of each sub-broker call, by ``(broker_id, deliverer_key)``.
+        Never yields to the loop."""
         if matched.max_persistent_fanout_exceeded:
             self.events.report(Event(EventType.PERSISTENT_FANOUT_THROTTLED,
                                      tenant_id, {"topic": topic_s}))
@@ -516,7 +580,7 @@ class DistService:
                 EventType.PERSISTENT_FANOUT_BYTES_THROTTLED, tenant_id,
                 {"topic": topic_s, "allowed": allowed}))
         if not targets:
-            return 0
+            return None, {}
         # group per (broker, deliverer_key) ≈ BatchDeliveryCall grouping
         by_deliverer: Dict[Tuple[int, str], List[Route]] = {}
         for r in targets:
@@ -526,59 +590,7 @@ class DistService:
             topic=topic_s,
             packs=(PublisherMessagePack(publisher=call.publisher,
                                         messages=(call.message,)),))
-        fanout = 0
-        for (broker_id, dkey), routes in by_deliverer.items():
-            match_infos = tuple(
-                MatchInfo(matcher=r.matcher, receiver_id=r.receiver_id,
-                          incarnation=r.incarnation) for r in routes)
-            # cross-broker delivery (≈ mqtt-broker-client deliver RPC):
-            # a deliverer key owned by ANOTHER server makes one RPC hop
-            # to that broker node, whose local sub-brokers finish it
-            owner = None
-            if self.deliverer_registry is not None and self.server_id:
-                from .deliverer import server_of
-                owner = server_of(dkey)
-            if owner and owner != self.server_id:
-                from .deliverer import remote_deliver
-                try:
-                    res = await remote_deliver(
-                        self.deliverer_registry, owner, tenant_id,
-                        broker_id, dkey, pack, match_infos)
-                except Exception as e:  # noqa: BLE001
-                    self.events.report(Event(EventType.DELIVER_ERROR,
-                                             tenant_id,
-                                             {"error": repr(e)}))
-                    OBS.record_delivery_violation(tenant_id, 0,
-                                                  "deliver_error")
-                    continue
-            elif not self.sub_brokers.has(broker_id):
-                continue
-            else:
-                broker = self.sub_brokers.get(broker_id)
-                dp = DeliveryPack(message_pack=pack,
-                                  match_infos=match_infos)
-                try:
-                    res = await broker.deliver(tenant_id, dkey, [dp])
-                except Exception as e:  # noqa: BLE001
-                    self.events.report(Event(EventType.DELIVER_ERROR,
-                                             tenant_id,
-                                             {"error": repr(e)}))
-                    OBS.record_delivery_violation(tenant_id, 0,
-                                                  "deliver_error")
-                    continue
-            for route, mi in zip(routes, match_infos):
-                outcome = res.get(mi, DeliveryResult.ERROR)
-                if outcome == DeliveryResult.OK:
-                    fanout += 1
-                elif outcome in (DeliveryResult.NO_SUB,
-                                 DeliveryResult.NO_RECEIVER):
-                    # dead route cleanup (≈ BatchDeliveryCall NO_SUB handling)
-                    await self.worker.remove_route(
-                        tenant_id, route.matcher, route.receiver_url,
-                        route.incarnation)
-                    self._match_cache.invalidate(
-                        tenant_id, route.matcher.filter_levels)
-        return fanout
+        return pack, by_deliverer
 
     def _elect(self, tenant_id: str, mqtt_filter: str,
                members: List[Route], topic: str) -> Optional[Route]:

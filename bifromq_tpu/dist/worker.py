@@ -31,8 +31,7 @@ from ..resilience.faults import get_injector
 from ..resilience.policy import current_deadline
 from ..types import RouteMatcher
 from ..utils import topic as topic_util
-from ..obs import OBS
-from ..utils.metrics import FABRIC, STAGES, FabricMetric
+from ..utils.metrics import FABRIC, FabricMetric
 
 _OP_ADD = 0
 _OP_REMOVE = 1
@@ -670,7 +669,22 @@ class DistWorker:
         happens on readiness; the `device.dispatch`/`device.sync` span
         pair of the sync era becomes dispatch/ready/fetch inside
         ``match_batch_async``."""
-        t0 = _time.perf_counter()
+        # ONE boundary around the device attempt AND its host-oracle
+        # fallback: its exit feeds the "device" stage once an event, and
+        # each tenant's window its row share of the batch
+        with trace.span("match.device", tenant=sub[0][0],
+                        n_queries=len(sub)) as sp:
+            out, waited_s = await self._match_or_degrade(
+                coproc, sub, max_persistent_fanout, max_group_fanout,
+                deadline, sp)
+            sp.charge(shares=self._tenant_shares(sub), waited_s=waited_s)
+        return out
+
+    async def _match_or_degrade(self, coproc, sub, max_persistent_fanout,
+                                max_group_fanout, deadline, sp):
+        """``(results, waited_s)``: the device serve, or on any fault the
+        host oracle. ``waited_s`` is the ring-admission wait the matcher
+        reported: queue time, not this batch's match cost."""
         cache = getattr(coproc.matcher, "match_cache", None)
         c0 = cache.counts() if cache is not None else (0, 0)
         try:
@@ -678,34 +692,32 @@ class DistWorker:
             if deadline is not None and _time.monotonic() >= deadline:
                 raise TimeoutError("match deadline budget exhausted")
             stats: dict = {}
-            with trace.span("match.device", tenant=sub[0][0],
-                            n_queries=len(sub)) as sp:
-                amatch = getattr(coproc.matcher, "match_batch_async", None)
-                if amatch is not None:
-                    out = await amatch(
-                        sub, max_persistent_fanout=max_persistent_fanout,
-                        max_group_fanout=max_group_fanout, stats=stats)
-                else:
-                    out = coproc.matcher.match_batch(
-                        sub, max_persistent_fanout=max_persistent_fanout,
-                        max_group_fanout=max_group_fanout)
-                if cache is not None and sp is not trace.NOOP:
-                    # ISSUE 4: cache disposition on the device span —
-                    # "hit" = the whole batch skipped the device,
-                    # "dedup" = misses collapsed into fewer walks. Only
-                    # computed for a RECORDED span: the O(n) dedup set is
-                    # not worth building for a no-op.
-                    hits = cache.counts()[0] - c0[0]
-                    misses = cache.counts()[1] - c0[1]
-                    dup = len(sub) - len(
-                        {(t, tuple(lv)) for t, lv in sub})
-                    sp.set_tag("cache",
-                               "hit" if misses == 0
-                               else ("dedup" if dup else "miss"))
-                    sp.set_tag("cache_hits", hits)
-                    sp.set_tag("cache_misses", misses)
-                if stats.get("degraded") and sp is not trace.NOOP:
-                    sp.set_tag("degraded", stats["degraded"])
+            amatch = getattr(coproc.matcher, "match_batch_async", None)
+            if amatch is not None:
+                out = await amatch(
+                    sub, max_persistent_fanout=max_persistent_fanout,
+                    max_group_fanout=max_group_fanout, stats=stats)
+            else:
+                out = coproc.matcher.match_batch(
+                    sub, max_persistent_fanout=max_persistent_fanout,
+                    max_group_fanout=max_group_fanout)
+            if cache is not None and sp.sampled:
+                # ISSUE 4: cache disposition on the device span —
+                # "hit" = the whole batch skipped the device,
+                # "dedup" = misses collapsed into fewer walks. Only
+                # computed for a RECORDED span: the O(n) dedup set is
+                # not worth building for a no-op.
+                hits = cache.counts()[0] - c0[0]
+                misses = cache.counts()[1] - c0[1]
+                dup = len(sub) - len(
+                    {(t, tuple(lv)) for t, lv in sub})
+                sp.set_tag("cache",
+                           "hit" if misses == 0
+                           else ("dedup" if dup else "miss"))
+                sp.set_tag("cache_hits", hits)
+                sp.set_tag("cache_misses", misses)
+            if stats.get("degraded"):
+                sp.set_tag("degraded", stats["degraded"])
             # ISSUE 7: the matcher now absorbs device faults internally
             # (breaker open / watchdog timeout / device error all serve
             # its host oracle without raising) and reports the reason via
@@ -716,18 +728,13 @@ class DistWorker:
                 cb = self.on_degraded
                 if cb is not None:
                     cb(len(sub), f"device:{stats['degraded']}")
-            # overlapped pipeline: the outer wall clock also counts
-            # ring-acquire waits and CONCURRENT batches' host work, so
-            # per-tenant device shares use the matcher-reported per-batch
-            # time (this batch's cache probe + dispatch+ready+fetch +
-            # expand — the same span the sync wall clock covers, so the
-            # "device" stage measures the same thing either side of
-            # BIFROMQ_PIPELINE); the sync fallback keeps wall time,
-            # which there IS that span
-            dt = stats.get("device_s", _time.perf_counter() - t0)
-            STAGES.record("device", dt)
-            self._attribute_device_time(sub, dt)
-            return out
+            # overlapped pipeline: the span also covers the ring-acquire
+            # wait (queue time under a saturated pipeline, not match
+            # cost), so the matcher reports it and the span's exit leaves
+            # it out of the "device" stage and the per-tenant shares —
+            # the stage then measures the same thing either side of
+            # BIFROMQ_PIPELINE (the sync fallback has no such wait)
+            return out, stats.get("acquire_s", 0.0)
         except Exception as e:  # noqa: BLE001 — degrade, don't fail
             oracle = getattr(coproc.matcher, "match_from_tries", None)
             if oracle is None:
@@ -746,24 +753,22 @@ class DistWorker:
                 out = oracle(sub,
                              max_persistent_fanout=max_persistent_fanout,
                              max_group_fanout=max_group_fanout)
-            dt = _time.perf_counter() - t0
-            STAGES.record("device", dt)
-            self._attribute_device_time(sub, dt)
-            return out
+            return out, 0.0
 
     @staticmethod
-    def _attribute_device_time(sub, dt: float) -> None:
+    def _tenant_shares(sub) -> dict:
         """Per-row tenant attribution of a range batch's device time
         (ISSUE 4 satellite, closing the PR-3 follow-up): each tenant's SLO
         window gets its row-count share of the batch instead of the whole
         batch landing on the representative tenant — /tenants device
         shares stay honest under mixed batches."""
+        n = len(sub)
+        if n == 1:
+            return {sub[0][0]: 1.0}
         counts: dict = {}
         for tenant_id, _levels in sub:
             counts[tenant_id] = counts.get(tenant_id, 0) + 1
-        n = len(sub)
-        for tenant_id, c in counts.items():
-            OBS.record_latency(tenant_id, "device", dt * c / n)
+        return {t: c / n for t, c in counts.items()}
 
     async def match_batch(self, queries, *, max_persistent_fanout,
                           max_group_fanout, linearized: bool = False,

@@ -18,6 +18,8 @@ import bisect
 import threading
 from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
+from .. import trace
+
 
 class KVWriteBatch:
     """Atomic multi-op write (≈ IKVSpaceWriter)."""
@@ -135,9 +137,10 @@ class _SortedBytesMap:
 
     def _sorted(self) -> List[bytes]:
         if self._pending:
-            self._keys.extend(self._pending)
-            self._pending.clear()
-            self._keys.sort()
+            with trace.span("kv.resort"):
+                self._keys.extend(self._pending)
+                self._pending.clear()
+                self._keys.sort()
         return self._keys
 
     def put(self, key: bytes, value: bytes) -> None:

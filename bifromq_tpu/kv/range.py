@@ -20,6 +20,7 @@ from __future__ import annotations
 import struct
 from typing import Awaitable, Callable, List, Optional, Tuple
 
+from .. import trace
 from ..raft.node import LogEntry, RaftNode
 from .engine import IKVSpace, KVWriteBatch
 
@@ -178,6 +179,10 @@ class ReplicatedKVRange:
         data = entry.data
         if not data:
             return
+        with trace.span("raft.apply"):
+            self._apply_entry(entry, data)
+
+    def _apply_entry(self, entry: LogEntry, data: bytes) -> None:
         kind = data[0]
         if kind == 0:
             if not self.sealed:  # sealed: content is frozen for the merge
@@ -290,7 +295,8 @@ class ReplicatedKVRange:
         guess = self.raft.last_index + 1
         self._pending_results.add(guess)
         try:
-            index = await self.raft.propose(_enc_coproc(payload))
+            with trace.span("raft.propose"):
+                index = await self.raft.propose(_enc_coproc(payload))
         finally:
             self._pending_results.discard(guess)
         return self._mutation_results.pop(index, b"")

@@ -40,7 +40,6 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 import numpy as np
 
 from .. import trace
-from ..utils.metrics import STAGES
 from ..utils import topic as topic_util
 from .automaton import (
     CompiledTrie, GroupMatching, Matching, PatchableTrie, PatchFallback,
@@ -433,8 +432,17 @@ class TpuMatcher:
         targets = self._patch_targets(op[1])
         if not targets:
             return False
+        with trace.span("patch.host") as sp:
+            ok = self._fold_into(op, targets)
+        if ok:
+            self.patch_count += 1
+            self.patch_host_s += sp.duration_s
+        else:
+            self.patch_fallbacks += 1
+        return ok
+
+    def _fold_into(self, op: Tuple, targets) -> bool:
         from ..types import RouteMatcherType
-        t0 = time.perf_counter()
         try:
             if op[0] == "add":
                 _, tenant_id, route = op
@@ -452,10 +460,7 @@ class TpuMatcher:
                     base.patch_remove(tenant_id, matcher, url,
                                       group_members=gm)
         except PatchFallback:
-            self.patch_fallbacks += 1
             return False
-        self.patch_count += 1
-        self.patch_host_s += time.perf_counter() - t0
         return True
 
     def _flush_patches(self, own_slots: int = 0) -> None:
@@ -485,11 +490,12 @@ class TpuMatcher:
         # old tables exists. Mutation-side callers never flush.
         donate = ring is None or (ring.in_flight <= own_slots
                                   and not len(ring.quarantine))
-        t0 = time.perf_counter()
-        dev, stats = patch_device_trie(self._device_trie, base,
-                                       device=self.device, donate=donate)
+        with trace.span("patch.flush") as sp:
+            dev, stats = patch_device_trie(self._device_trie, base,
+                                           device=self.device,
+                                           donate=donate)
         self._device_trie = dev
-        dt = time.perf_counter() - t0
+        dt = sp.duration_s
         self.patch_flushes += 1
         self.patch_device_s += dt
         # ISSUE 9: every flush lands in the compile ledger's patch stream
@@ -968,15 +974,16 @@ class TpuMatcher:
         batch N is still walking, and the event loop keeps serving between
         readiness polls instead of blocking inside ``device_get``.
 
-        ``stats`` (optional dict) receives ``device_s``: THIS batch's own
-        match cost — cache probe + dispatch+ready+fetch + host expansion
-        and cache fill, i.e. the same work the sync path's wall clock
-        covers, minus only the ring-acquire wait. Callers attributing
-        device cost (the dist worker's per-tenant SLO shares) must use it
-        instead of their outer wall clock, which under an overlapped
-        pipeline also counts that wait and concurrent batches' work —
-        and with it, toggling ``BIFROMQ_PIPELINE`` does not shift what
-        the "device" stage histograms measure. ``stats["degraded"]``
+        ``stats`` (optional dict) receives ``acquire_s``: the
+        ring-acquire wait inside this call (the ``device.acquire``
+        span's time less the prep it overlaps). A caller attributing
+        device cost (the dist worker's ``match.device`` span and its
+        per-tenant SLO shares) takes it off its own span, which under an
+        overlapped pipeline also counts that wait — so THIS batch's
+        match cost is cache probe + dispatch+ready+fetch + host
+        expansion and cache fill, the same work the sync path's wall
+        clock covers, and toggling ``BIFROMQ_PIPELINE`` does not shift
+        what the "device" stage histograms measure. ``stats["degraded"]``
         carries the reason when the batch was served from the host
         oracle (ISSUE 7: breaker open, watchdog timeout, device error)
         so the worker can emit MATCH_DEGRADED events without a raising
@@ -1002,7 +1009,6 @@ class TpuMatcher:
                             f"{sorted(device_kw)}")
         caps = (max_persistent_fanout, max_group_fanout)
         cache = self.match_cache
-        t_front = time.perf_counter()
         if cache is not None:
             self._apply_pending_swap()
             out, uniq, uniq_queries, miss_rows, tokens = \
@@ -1010,12 +1016,7 @@ class TpuMatcher:
         else:
             out = [None] * len(queries)
             uniq_queries = list(queries)
-        front_s = time.perf_counter() - t_front
-        if stats is not None:
-            # all-hit batches: the cache probe IS the whole match cost
-            stats["device_s"] = front_s
         if uniq_queries:
-            t_disp = time.perf_counter()
             res, degraded, acquire_s = await self._device_serve_async(
                 uniq_queries, batch, max_persistent_fanout,
                 max_group_fanout)
@@ -1025,13 +1026,11 @@ class TpuMatcher:
             else:
                 out = res
             if stats is not None:
-                # probe + this batch's dispatch→expand→fill: everything
-                # the sync wall clock covers except the ring-acquire wait
-                # (queue time under a saturated pipeline, not match cost —
-                # folding it in would inflate the "device" stage and the
-                # per-tenant attribution feeding the noisy detector)
-                stats["device_s"] = front_s + (
-                    time.perf_counter() - t_disp - acquire_s)
+                # the ring-acquire wait is queue time under a saturated
+                # pipeline, not match cost — the caller's span leaves it
+                # out of the "device" stage and the per-tenant
+                # attribution feeding the noisy detector
+                stats["acquire_s"] = acquire_s
                 if degraded is not None:
                     stats["degraded"] = degraded
         if cache is not None:
@@ -1146,33 +1145,34 @@ class TpuMatcher:
         # prep-ahead wait un-uploaded, keeping the capacity model's
         # in-flight byte accounting honest. The dispatch half re-preps
         # iff a compaction swapped the base during the admission wait.
-        t_acq = time.perf_counter()
-        await ring.acquire_prep()
+        ticket = False
         try:
-            if batch is None:
-                # queue-depth-adaptive pow2 floor: idle ring ⇒ small pad
-                # to cut time-to-first-result, busy ring ⇒ the
-                # throughput floor. Read before slot admission
-                # (planned_floor = the pre-acquire twin).
-                batch = _pow2_batch(len(uniq_queries),
-                                    floor=ring.planned_floor())
-            prep = self._prepare_probes(uniq_queries, batch)
-            await ring.acquire()
+            with trace.span("device.acquire") as acq:
+                await ring.acquire_prep()
+                ticket = True
+                if batch is None:
+                    # queue-depth-adaptive pow2 floor: idle ring ⇒ small
+                    # pad to cut time-to-first-result, busy ring ⇒ the
+                    # throughput floor. Read before slot admission
+                    # (planned_floor = the pre-acquire twin).
+                    batch = _pow2_batch(len(uniq_queries),
+                                        floor=ring.planned_floor())
+                prep = self._prepare_probes(uniq_queries, batch)
+                await ring.acquire()
             if timing is not None:
                 # queue time: prep-ticket wait + slot wait, minus the
                 # prep work itself (match cost, attributed via the
                 # tokenize stage)
                 timing["acquire_s"] = max(
-                    0.0, time.perf_counter() - t_acq - prep.tokenize_s)
+                    0.0, acq.duration_s - prep.tokenize_s)
             try:
                 fl = self._dispatch_prepared(prep,
                                              donate=donation_enabled(),
                                              watchdogged=True)
                 ring.start_fetch(fl.res)
-                t0 = time.perf_counter()
                 try:
                     with trace.span("device.ready", batch=fl.batch,
-                                    kernel=fl.kernel):
+                                    kernel=fl.kernel) as ready:
                         await self._await_ready(ring, fl)
                 except DeviceTimeoutError:
                     ring.reclaim(fl.res,
@@ -1201,8 +1201,6 @@ class TpuMatcher:
                                         tag=getattr(fl, "quarantine_tag",
                                                     None))
                     raise
-                ready_s = time.perf_counter() - t0
-                STAGES.record("device.ready", ready_s)
                 # a step that completes clears the single-chip degraded
                 # mark (per-shard marks clear on their own ready rows)
                 from ..obs import OBS as _obs
@@ -1214,23 +1212,23 @@ class TpuMatcher:
             # in-flight batches together at depth+1, so at most ONE
             # uploaded-but-undispatched probe set exists when the ring
             # is full — the exact +1 the capacity model counts
-            ring.release_prep()
-        t0 = time.perf_counter()
-        with trace.span("device.fetch"):
+            if ticket:
+                ring.release_prep()
+        with trace.span("device.fetch") as fetch:
             overflow, starts_a, counts_a = self._fetch_walk(fl.res)
-        fetch_s = time.perf_counter() - t0
-        STAGES.record("device.fetch", fetch_s)
-        t0 = time.perf_counter()
-        out = self._expand_walk(fl, overflow, starts_a, counts_a,
-                                max_persistent_fanout, max_group_fanout)
-        # ISSUE 8: the continuous profiler's per-batch stage record —
-        # attribute increments + one ring store, nothing else
+        with trace.span("match.expand") as expand:
+            out = self._expand_walk(fl, overflow, starts_a, counts_a,
+                                    max_persistent_fanout,
+                                    max_group_fanout)
+        # ISSUE 8: the continuous profiler's per-batch stage record,
+        # built from the spans' own durations — attribute increments +
+        # one ring store, nothing else
         from ..obs import OBS
         OBS.profiler.record_batch(
             n_queries=len(fl.queries), batch=fl.batch, kernel=fl.kernel,
             tokenize_s=fl.tokenize_s, dispatch_s=fl.dispatch_s,
-            ready_s=ready_s, fetch_s=fetch_s,
-            expand_s=time.perf_counter() - t0,
+            ready_s=ready.duration_s, fetch_s=fetch.duration_s,
+            expand_s=expand.duration_s,
             dev_expand_s=fl.dev_expand_s, path="async")
         return out
 
@@ -1319,22 +1317,19 @@ class TpuMatcher:
                     max_group_fanout=max_group_fanout)
         try:
             fl = self._dispatch_device(queries, batch)
-            t0 = time.perf_counter()
-            with trace.span("device.fetch"):
+            with trace.span("device.fetch") as fetch:
                 self._await_ready_sync(fl.res)
                 overflow, starts_a, counts_a = self._fetch_walk(fl.res)
-            fetch_s = time.perf_counter() - t0
-            STAGES.record("device.fetch", fetch_s)
-            t0 = time.perf_counter()
-            out = self._expand_walk(fl, overflow, starts_a, counts_a,
-                                    max_persistent_fanout,
-                                    max_group_fanout)
+            with trace.span("match.expand") as expand:
+                out = self._expand_walk(fl, overflow, starts_a, counts_a,
+                                        max_persistent_fanout,
+                                        max_group_fanout)
             from ..obs import OBS
             OBS.profiler.record_batch(
                 n_queries=len(fl.queries), batch=fl.batch,
                 kernel=fl.kernel, tokenize_s=fl.tokenize_s,
                 dispatch_s=fl.dispatch_s,
-                fetch_s=fetch_s, expand_s=time.perf_counter() - t0,
+                fetch_s=fetch.duration_s, expand_s=expand.duration_s,
                 dev_expand_s=fl.dev_expand_s, path="sync")
         except DeviceTimeoutError as e:
             # the watchdog fired on the SYNC leg: reclaimed slot
@@ -1408,9 +1403,8 @@ class TpuMatcher:
         if batch is None:
             batch = _pow2_batch(len(queries))
         roots = [ct.root_of(t) for t, _ in queries]
-        t0 = time.perf_counter()
         with trace.span("device.tokenize", batch=batch,
-                        queries=len(queries)):
+                        queries=len(queries)) as sp:
             topics = [levels for _, levels in queries]
             byte_rows = all(isinstance(t, (str, bytes)) for t in topics)
             tok = probes = None
@@ -1433,11 +1427,9 @@ class TpuMatcher:
                                cache=self._tok_cache)
             if probes is None:
                 probes = Probes.from_tokenized(tok, device=self.device)
-        tokenize_s = time.perf_counter() - t0
-        STAGES.record("tokenize", tokenize_s)
         return _Prepared(queries=list(queries), ct=ct, tok=tok,
                          probes=probes, roots=roots, batch=batch,
-                         tokenize_s=tokenize_s)
+                         tokenize_s=sp.duration_s)
 
     def _dispatch_device(self, queries, batch: Optional[int] = None, *,
                          donate: bool = False,
@@ -1488,18 +1480,16 @@ class TpuMatcher:
         # (_expand_walk) — fusing it into this jit would compile the
         # high-K escalation walk on the first serving query, doubling
         # cold-start latency for a pass that almost never runs
-        t0 = time.perf_counter()
         with trace.span("device.dispatch", batch=batch,
                         queries=len(prep.queries)) as sp:
             res, kernel = self._walk_primary(prep.probes, ct,
                                              donate=donate)
-            if sp is not trace.NOOP:
-                sp.set_tag("kernel", kernel)
+            sp.set_tag("kernel", kernel)
         # ISSUE 6: the `device.sync` stage of the sync era is replaced by
         # the dispatch/ready/fetch split in the always-on stage
-        # histograms (/metrics "stages" + the bench breakdown)
-        dispatch_s = time.perf_counter() - t0
-        STAGES.record("device.dispatch", dispatch_s)
+        # histograms (/metrics "stages" + the bench breakdown), each fed
+        # by its span's exit
+        dispatch_s = sp.duration_s
         # ISSUE 19: the second device stage — fan-out expansion + peer
         # bucketing enqueued right behind the walk, so the host fetch
         # reads pre-bucketed (slot, row) pairs instead of interval grids
@@ -1512,14 +1502,12 @@ class TpuMatcher:
         # those batches keep the host expander
         if device_expand_enabled() and isinstance(res.start, jax.Array):
             from ..ops.match import expand_cap_lanes, expand_routes
-            t0 = time.perf_counter()
-            with trace.span("device.expand", batch=batch):
+            with trace.span("device.expand", batch=batch) as sp:
                 peer_tab, slot_peer = self._peer_table(ct)
                 res = expand_routes(
                     res, slot_peer, cap=batch * expand_cap_lanes(),
                     n_peers=peer_tab.n_peers)
-            dev_expand_s = time.perf_counter() - t0
-            STAGES.record("device.expand", dev_expand_s)
+            dev_expand_s = sp.duration_s
         return _InFlight(queries=prep.queries, ct=ct,
                          dev=self._device_trie, tok=tok, roots=roots,
                          res=res, tomb=self._tomb, delta=self._delta,
@@ -1604,6 +1592,16 @@ class TpuMatcher:
         (overflow, starts, counts) grids otherwise."""
         from ..resilience.faults import get_injector
         get_injector().check_raise("device", "tpu-device", "fetch")
+        # the wait for the device apart from the copy: after a readiness
+        # poll said "ready" this is nothing; where the poll slept past
+        # the completion, or the sync leg fell through, it is the rest
+        # of the device's time
+        with trace.span("device.fetch.wait"):
+            ready = getattr(res, "ready_leaves", None)
+            for leaf in (ready() if ready is not None
+                         else (res.start, res.count, res.overflow)):
+                if hasattr(leaf, "block_until_ready"):  # not duck-typed
+                    leaf.block_until_ready()
         overflow = np.array(res.overflow)
         if hasattr(res, "slots"):
             pairs = _HostPairs(

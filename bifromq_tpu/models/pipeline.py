@@ -29,6 +29,7 @@ import asyncio
 import time
 from typing import Optional
 
+from .. import trace
 from ..resilience.device import (BoundedSlots, BufferQuarantine,
                                  DeviceTimeoutError, device_deadline_s)
 from ..utils.env import env_bool, env_int
@@ -237,29 +238,36 @@ class DispatchRing(BoundedSlots):
         if fault is not None:
             from ..resilience.faults import get_injector
             injector = get_injector()
-        while True:
-            faulted = False
-            if fault is not None:
-                if fault.action == "hang":
-                    faulted = injector.rule_active(fault)
-                elif fault.action == "slow":
-                    faulted = time.monotonic() - t0 < fault.delay
-                elif fault.action == "flaky_ready":
-                    # the documented contract is delayed-never-denied:
-                    # clamp the per-poll lie below 1.0 so a rule with the
-                    # default probability (1.0) stays a flake, not a hang
-                    # (hang is its own action)
-                    faulted = (injector.rule_active(fault)
-                               and injector.rng.random()
-                               < min(fault.probability, 0.95))
-            if not faulted:
-                try:
-                    if all(leaf.is_ready() for leaf in leaves):
+        try:
+            while True:
+                faulted = False
+                if fault is not None:
+                    if fault.action == "hang":
+                        faulted = injector.rule_active(fault)
+                    elif fault.action == "slow":
+                        faulted = time.monotonic() - t0 < fault.delay
+                    elif fault.action == "flaky_ready":
+                        # the documented contract is delayed-never-
+                        # denied: clamp the per-poll lie below 1.0 so a
+                        # rule with the default probability (1.0) stays
+                        # a flake, not a hang (hang is its own action)
+                        faulted = (injector.rule_active(fault)
+                                   and injector.rng.random()
+                                   < min(fault.probability, 0.95))
+                if not faulted:
+                    try:
+                        if all(leaf.is_ready() for leaf in leaves):
+                            return
+                    except AttributeError:
                         return
-                except AttributeError:
-                    return
-            if (deadline_s is not None
-                    and time.monotonic() - t0 >= deadline_s):
-                raise DeviceTimeoutError(deadline_s)
-            await asyncio.sleep(0 if polls < spin_polls else poll_s)
-            polls += 1
+                if (deadline_s is not None
+                        and time.monotonic() - t0 >= deadline_s):
+                    raise DeviceTimeoutError(deadline_s)
+                await asyncio.sleep(0 if polls < spin_polls else poll_s)
+                polls += 1
+        finally:
+            # polls that found the walk unfinished, and how many of them
+            # slept (ROADMAP S6: the poll was suspected and never counted)
+            trace.count("ready.polls", polls)
+            if polls > spin_polls:
+                trace.count("ready.sleeps", polls - spin_polls)

@@ -12,9 +12,11 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 import uuid
 from typing import Optional
 
+from .. import trace
 from ..dist.service import DistService
 from ..plugin.auth import (AllowAllAuthProvider, AuthData, IAuthProvider,
                            MQTTAction)
@@ -135,7 +137,9 @@ class Connection:
                 if not data:
                     await self.session.close(fire_will=True)
                     return
-                for packet in self.decoder.feed(data):
+                with trace.span("mqtt.decode"):
+                    packets = self.decoder.feed(data)
+                for packet in packets:
                     if isinstance(packet, pk.Connect):
                         await self.protocol_error("duplicate CONNECT")
                         return
@@ -761,6 +765,8 @@ class MQTTBroker:
         self._redirect_task = asyncio.get_running_loop().create_task(
             self._redirect_sweep(
                 get(SysProp.CLIENT_REDIRECT_CHECK_INTERVAL_SECONDS)))
+        self._heartbeat_task = asyncio.get_running_loop().create_task(
+            self._loop_heartbeat())
         # push telemetry export (ISSUE 3): refcounted on the process-global
         # hub; a no-op unless a sink is configured (BIFROMQ_OBS_EXPORT /
         # BIFROMQ_OBS_EXPORT_URL). Only a broker that actually acquired a
@@ -840,9 +846,25 @@ class MQTTBroker:
                 except Exception:  # noqa: BLE001
                     log.exception("redirect sweep failed for one session")
 
+    HEARTBEAT_S = 0.020
+
+    async def _loop_heartbeat(self) -> None:
+        """The serving loop's fixed heartbeat: how late each beat fires is
+        how long the loop's one thread was held by something else
+        (`loop.lag`: n, total and the slice's max in the window totals)."""
+        period_ns = int(self.HEARTBEAT_S * 1e9)
+        due = time.monotonic_ns() + period_ns
+        while True:
+            await asyncio.sleep((due - time.monotonic_ns()) / 1e9)
+            now = time.monotonic_ns()
+            trace.record_finished("loop.lag", None, start_ns=min(due, now),
+                                  end_ns=now)
+            due = max(due + period_ns, now)
+
     async def stop(self) -> None:
-        if getattr(self, "_redirect_task", None) is not None:
-            self._redirect_task.cancel()
+        for name in ("_redirect_task", "_heartbeat_task"):
+            if getattr(self, name, None) is not None:
+                getattr(self, name).cancel()
         if self._server is not None:
             self._server.close()
         if self._tls_server is not None:

@@ -21,7 +21,6 @@ from ..inbox.store import LWT
 from ..plugin.events import Event, EventType
 from ..types import Message, QoS, TopicFilterOption
 from ..utils.hlc import HLC
-from ..utils.metrics import STAGES
 from . import packets as pk
 from .protocol import PROTOCOL_MQTT5, ReasonCode
 from .session import BLOCKED, Session, Subscription
@@ -202,7 +201,6 @@ class PersistentSession(Session):
                     # (live traffic) bypass the governor.
                     catchup = False
                     governor = getattr(self.inbox, "drain_governor", None)
-                    t0 = time.perf_counter()
                     with trace.span("inbox.drain", tenant=tenant,
                                     inbox=self.inbox_id) as sp:
                         if governor is not None:
@@ -210,12 +208,7 @@ class PersistentSession(Session):
                                 fetched = await self._drain_pages(tenant)
                         else:
                             fetched = await self._drain_pages(tenant)
-                        if sp is not trace.NOOP:
-                            sp.set_tag("fetched", fetched or 0)
-                    dt = time.perf_counter() - t0
-                    STAGES.record("inbox.drain", dt)
-                    from ..obs import OBS
-                    OBS.record_latency(tenant, "inbox.drain", dt)
+                        sp.set_tag("fetched", fetched or 0)
                     if fetched is None:
                         return      # inbox gone (kicked/deleted)
                 else:

@@ -36,7 +36,6 @@ from ..utils.hlc import HLC
 from ..obs import OBS
 from ..obs.e2e import DELIVERY_PATH
 from ..utils.env import env_float
-from ..utils.metrics import STAGES
 from . import packets as pk
 from .protocol import (PROTOCOL_MQTT5, PropertyId, ReasonCode,
                        CONNACK_ACCEPTED)
@@ -729,20 +728,13 @@ class Session:
                                  self.client_info.tenant_id,
                                  {"topic": topic_s, "qos": p.qos}))
         # ISSUE 2: the publish→match→deliver ROOT span — the per-tenant
-        # sampling draw for the whole distributed trace happens here; the
-        # "ingest" stage histogram records regardless of sampling.
-        # ISSUE 3: the same measurement feeds the tenant's windowed RED
-        # duration (the /tenants "is this tenant slow NOW" signal)
-        t0 = time.monotonic()
-        try:
-            with trace.span("pub.ingest", tenant=self.client_info.tenant_id,
-                            topic=topic_s, qos=p.qos):
-                await self._ingest_publish(p, topic, msg,
-                                           topic_s=topic_s)
-        finally:
-            dt = time.monotonic() - t0
-            STAGES.record("ingest", dt)
-            OBS.record_latency(self.client_info.tenant_id, "ingest", dt)
+        # sampling draw for the whole distributed trace happens here; its
+        # exit feeds the "ingest" stage histogram and the tenant's
+        # windowed RED duration (the /tenants "is this tenant slow NOW"
+        # signal) regardless of sampling (trace/names.py)
+        with trace.span("pub.ingest", tenant=self.client_info.tenant_id,
+                        topic=topic_s, qos=p.qos):
+            await self._ingest_publish(p, topic, msg, topic_s=topic_s)
 
     async def _ingest_publish(self, p: pk.Publish, topic,
                               msg: Message, topic_s: str = None) -> None:
@@ -782,12 +774,15 @@ class Session:
             if p.qos > 0:
                 await INGEST_GATE.acquire()
                 try:
-                    result = await self.dist.pub(self.client_info, topic,
-                                                 msg)
+                    with trace.span("dist.pub"):
+                        result = await self.dist.pub(self.client_info,
+                                                     topic, msg)
                 finally:
                     INGEST_GATE.release()
             else:
-                result = await self.dist.pub(self.client_info, topic, msg)
+                with trace.span("dist.pub"):
+                    result = await self.dist.pub(self.client_info, topic,
+                                                 msg)
         except Exception:  # noqa: BLE001 — dist backend failure
             log.exception("dist.pub failed")
             # ≈ QoS{0,1,2}DistError events; QoS1/2 get an error ack so the
@@ -811,17 +806,13 @@ class Session:
                         packet_id=p.packet_id,
                         reason_code=ReasonCode.UNSPECIFIED_ERROR))
             return
-        if p.qos == 1:
-            rc = (ReasonCode.SUCCESS if result.fanout > 0
-                  else ReasonCode.NO_MATCHING_SUBSCRIBERS)
-            await self.conn.send(pk.PubAck(
-                packet_id=p.packet_id,
-                reason_code=(rc if self.protocol_level >= PROTOCOL_MQTT5
-                             else 0)))
-        elif p.qos == 2:
-            rc = (ReasonCode.SUCCESS if result.fanout > 0
-                  else ReasonCode.NO_MATCHING_SUBSCRIBERS)
-            await self.conn.send(pk.PubRec(
+        if p.qos == 0:
+            return
+        rc = (ReasonCode.SUCCESS if result.fanout > 0
+              else ReasonCode.NO_MATCHING_SUBSCRIBERS)
+        ack = pk.PubAck if p.qos == 1 else pk.PubRec
+        with trace.span("pub.ack"):
+            await self.conn.send(ack(
                 packet_id=p.packet_id,
                 reason_code=(rc if self.protocol_level >= PROTOCOL_MQTT5
                              else 0)))
@@ -894,11 +885,13 @@ class Session:
                         ReasonCode.SUBSCRIPTION_IDENTIFIERS_NOT_SUPPORTED)
                     return
                 sub_id = sids[0]
-        codes: List[int] = []
-        for req in s.subscriptions:
-            codes.append(await self._subscribe_one(req, sub_id))
-        await self.conn.send(pk.SubAck(packet_id=s.packet_id,
-                                       reason_codes=codes))
+        with trace.span("sub.route", tenant=self.client_info.tenant_id,
+                        filters=len(s.subscriptions)):
+            codes: List[int] = []
+            for req in s.subscriptions:
+                codes.append(await self._subscribe_one(req, sub_id))
+            await self.conn.send(pk.SubAck(packet_id=s.packet_id,
+                                           reason_codes=codes))
         self.events.report(Event(EventType.SUB_ACKED,
                                  self.client_info.tenant_id,
                                  {"filters": [r.topic_filter

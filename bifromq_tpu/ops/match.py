@@ -570,37 +570,43 @@ def _route_walk(trie: DeviceTrie, probes: Probes, probe_len: int,
                                        compaction)
         return new_act, em_s, em_c, overflow | overflowed
 
-    em_s = jnp.zeros((b, width, 2 * k), dtype=jnp.int32)
-    em_c = jnp.zeros((b, width, 2 * k), dtype=jnp.int32)
-    overflow = jnp.zeros((b,), dtype=bool)
-    act = jnp.where(probes.lengths >= 0, probes.roots, -1)[:, None]
-    i = 0
-    while act.shape[1] < k and i < width:
-        act, em_s, em_c, overflow = step(jnp.int32(i), act, em_s, em_c,
-                                         overflow)
-        i += 1
-    if i < width:
-        def body(j, carry):
-            return step(j, *carry)
-        upper = jnp.clip(jnp.max(probes.lengths, initial=-1) + 1, i, width)
-        act, em_s, em_c, overflow = jax.lax.fori_loop(
-            i, upper, body, (act, em_s, em_c, overflow))
+    # the named scopes land in every op's metadata: a device trace then
+    # splits the walk's time into its step loop, its emit (compaction)
+    # and, in ``_walk_routes_fn``, its escalation
+    with jax.named_scope("walk.steps"):
+        em_s = jnp.zeros((b, width, 2 * k), dtype=jnp.int32)
+        em_c = jnp.zeros((b, width, 2 * k), dtype=jnp.int32)
+        overflow = jnp.zeros((b,), dtype=bool)
+        act = jnp.where(probes.lengths >= 0, probes.roots, -1)[:, None]
+        i = 0
+        while act.shape[1] < k and i < width:
+            act, em_s, em_c, overflow = step(jnp.int32(i), act, em_s, em_c,
+                                             overflow)
+            i += 1
+        if i < width:
+            def body(j, carry):
+                return step(j, *carry)
+            upper = jnp.clip(jnp.max(probes.lengths, initial=-1) + 1, i,
+                             width)
+            act, em_s, em_c, overflow = jax.lax.fori_loop(
+                i, upper, body, (act, em_s, em_c, overflow))
 
     # ---- single compaction pass: dense emissions -> [B, A] interval lanes
-    a = max_intervals
-    flat_c = em_c.reshape(b, -1)
-    flat_s = em_s.reshape(b, -1)
-    keep = flat_c > 0
-    n_ivl = keep.sum(axis=1, dtype=jnp.int32)
-    n_routes = flat_c.sum(axis=1, dtype=jnp.int32)
-    pos = jnp.cumsum(keep.astype(jnp.int32), axis=1) - 1
-    pos = jnp.where(keep, pos, a)          # a == out of range -> dropped
-    rows = jnp.broadcast_to(jnp.arange(b)[:, None], flat_c.shape)
-    ivl_s = jnp.zeros((b, a), jnp.int32).at[rows, pos].set(flat_s,
-                                                           mode="drop")
-    ivl_c = jnp.zeros((b, a), jnp.int32).at[rows, pos].set(flat_c,
-                                                           mode="drop")
-    return ivl_s, ivl_c, n_routes, overflow | (n_ivl > a)
+    with jax.named_scope("walk.emit"):
+        a = max_intervals
+        flat_c = em_c.reshape(b, -1)
+        flat_s = em_s.reshape(b, -1)
+        keep = flat_c > 0
+        n_ivl = keep.sum(axis=1, dtype=jnp.int32)
+        n_routes = flat_c.sum(axis=1, dtype=jnp.int32)
+        pos = jnp.cumsum(keep.astype(jnp.int32), axis=1) - 1
+        pos = jnp.where(keep, pos, a)      # a == out of range -> dropped
+        rows = jnp.broadcast_to(jnp.arange(b)[:, None], flat_c.shape)
+        ivl_s = jnp.zeros((b, a), jnp.int32).at[rows, pos].set(
+            flat_s, mode="drop")
+        ivl_c = jnp.zeros((b, a), jnp.int32).at[rows, pos].set(
+            flat_c, mode="drop")
+        return ivl_s, ivl_c, n_routes, overflow | (n_ivl > a)
 
 
 def _walk_routes_fn(trie: DeviceTrie, probes: Probes, *, probe_len: int,
@@ -656,8 +662,9 @@ def _walk_routes_fn(trie: DeviceTrie, probes: Probes, *, probe_len: int,
                 jnp.where(succ_full, nr2_full, n_routes),
                 overflow & jnp.logical_not(succ_full))
 
-    out = jax.lax.cond(overflow.any(), escalate, lambda a: a,
-                       (ivl_s, ivl_c, n_routes, overflow))
+    with jax.named_scope("walk.escalate"):
+        out = jax.lax.cond(overflow.any(), escalate, lambda a: a,
+                           (ivl_s, ivl_c, n_routes, overflow))
     return RouteIntervals(*out)
 
 
@@ -709,12 +716,14 @@ _PATCH_PAD_FLOOR = 8
 
 @jax.jit
 def _scatter_rows(tab, idx, vals):
-    return tab.at[idx].set(vals)
+    with jax.named_scope("patch.scatter"):
+        return tab.at[idx].set(vals)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter_rows_donated(tab, idx, vals):
-    return tab.at[idx].set(vals)
+    with jax.named_scope("patch.scatter"):
+        return tab.at[idx].set(vals)
 
 
 def _pad_patch_idx(idx: np.ndarray) -> np.ndarray:
@@ -1142,9 +1151,10 @@ def expand_pairs(ivl_start: jax.Array, ivl_count: jax.Array, *, cap: int):
 @functools.partial(jax.jit, static_argnames=("cap", "n_peers"))
 def _expand_routes_fn(ivl_s, ivl_c, overflow, slot_peer, *,
                       cap: int, n_peers: int):
-    serve_c = jnp.where(overflow[:, None], 0, ivl_c)
-    slots, rows, row_offsets, n_pairs, trunc = _expand_pairs(
-        ivl_s, serve_c, cap)
+    with jax.named_scope("expand.pairs"):
+        serve_c = jnp.where(overflow[:, None], 0, ivl_c)
+        slots, rows, row_offsets, n_pairs, trunc = _expand_pairs(
+            ivl_s, serve_c, cap)
     if n_peers == 0:
         # structurally bucketed already: with no named peers every live
         # pair lands in UNKNOWN, and _expand_pairs emits live pairs as a
@@ -1160,8 +1170,9 @@ def _expand_routes_fn(ivl_s, ivl_c, overflow, slot_peer, *,
             [jnp.zeros((), jnp.int32), n_pairs,
              jnp.full((), cap, jnp.int32)])
     else:
-        peer_slots, peer_rows, peer_offsets = _bucket_pairs(
-            slots, rows, slot_peer, n_peers)
+        with jax.named_scope("expand.bucket"):
+            peer_slots, peer_rows, peer_offsets = _bucket_pairs(
+                slots, rows, slot_peer, n_peers)
     return (slots, rows, row_offsets, n_pairs, trunc, peer_slots,
             peer_rows, peer_offsets)
 

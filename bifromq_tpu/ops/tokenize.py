@@ -108,21 +108,24 @@ def _hash_lanes(rows, starts, lens, nlv, h0lo, h0hi):
     contract."""
     b, mb = rows.shape
     w = starts.shape[1]
-    # gather each lane's level bytes into a [B, W, 128] block (on
-    # device — the host ships only the packed rows + tiny grids)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (b, w, _LEVEL_BLOCK), 2)
-    gidx = jnp.clip(starts[:, :, None] + iota, 0, mb - 1)
-    byte = rows[jnp.arange(b)[:, None, None], gidx].astype(jnp.uint32)
-    byte = jnp.where(iota < lens[:, :, None], byte, jnp.uint32(0))
-    # 16 message words as (lo, hi) uint32 pairs, little-endian
-    wb = byte.reshape(b, w, 16, 8)
-    m = []
-    for i in range(16):
-        lo = (wb[..., i, 0] | (wb[..., i, 1] << 8)
-              | (wb[..., i, 2] << 16) | (wb[..., i, 3] << 24))
-        hi = (wb[..., i, 4] | (wb[..., i, 5] << 8)
-              | (wb[..., i, 6] << 16) | (wb[..., i, 7] << 24))
-        m.append((lo, hi))
+    # the named scopes land in every op's metadata: a device trace then
+    # splits the program's time by phase (gather / rounds / mask)
+    with jax.named_scope("tokenize.gather"):
+        # gather each lane's level bytes into a [B, W, 128] block (on
+        # device — the host ships only the packed rows + tiny grids)
+        iota = jax.lax.broadcasted_iota(jnp.int32, (b, w, _LEVEL_BLOCK), 2)
+        gidx = jnp.clip(starts[:, :, None] + iota, 0, mb - 1)
+        byte = rows[jnp.arange(b)[:, None, None], gidx].astype(jnp.uint32)
+        byte = jnp.where(iota < lens[:, :, None], byte, jnp.uint32(0))
+        # 16 message words as (lo, hi) uint32 pairs, little-endian
+        wb = byte.reshape(b, w, 16, 8)
+        m = []
+        for i in range(16):
+            lo = (wb[..., i, 0] | (wb[..., i, 1] << 8)
+                  | (wb[..., i, 2] << 16) | (wb[..., i, 3] << 24))
+            hi = (wb[..., i, 4] | (wb[..., i, 5] << 8)
+                  | (wb[..., i, 6] << 16) | (wb[..., i, 7] << 24))
+            m.append((lo, hi))
     iv_lo = [jnp.uint32(v) for v in _IV_LO]
     iv_hi = [jnp.uint32(v) for v in _IV_HI]
     shape = (b, w)
@@ -168,16 +171,18 @@ def _hash_lanes(rows, starts, lens, nlv, h0lo, h0hi):
         g(3, 4, 9, 14, 14)
         return (jnp.stack([x[0] for x in v]), jnp.stack([x[1] for x in v]))
 
-    v_lo, v_hi = jax.lax.fori_loop(
-        0, len(bytetok.BLAKE2B_SIGMA), one_round,
-        (jnp.stack([x[0] for x in v0]), jnp.stack([x[1] for x in v0])))
+    with jax.named_scope("tokenize.rounds"):
+        v_lo, v_hi = jax.lax.fori_loop(
+            0, len(bytetok.BLAKE2B_SIGMA), one_round,
+            (jnp.stack([x[0] for x in v0]), jnp.stack([x[1] for x in v0])))
 
-    out_lo = full(h0lo[0, 0]) ^ v_lo[0] ^ v_lo[8]
-    out_hi = full(h0hi[0, 0]) ^ v_hi[0] ^ v_hi[8]
-    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    active = lane < nlv          # nlv == -1 padding rows mask everything
-    h1 = jnp.where(active, out_lo.astype(jnp.int32), 0)
-    h2 = jnp.where(active, out_hi.astype(jnp.int32), 0)
+    with jax.named_scope("tokenize.mask"):
+        out_lo = full(h0lo[0, 0]) ^ v_lo[0] ^ v_lo[8]
+        out_hi = full(h0hi[0, 0]) ^ v_hi[0] ^ v_hi[8]
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        active = lane < nlv      # nlv == -1 padding rows mask everything
+        h1 = jnp.where(active, out_lo.astype(jnp.int32), 0)
+        h2 = jnp.where(active, out_hi.astype(jnp.int32), 0)
     return h1, h2
 
 

@@ -441,12 +441,10 @@ class TenantMigration:
             raise RuntimeError(f"migration of {sorted(inflight)} in "
                                f"flight — one live move at a time")
         self._check_migratable_base()
-        t0 = time.perf_counter()
         with trace.span("mesh.migrate.begin", tenant=self.tenant,
                         src=self.src, dst=self.dst):
             emit_migration_op(self.matcher, ("mig_begin", self.tenant,
                                              self.src, self.dst))
-        STAGES.record("mesh.migrate.begin", time.perf_counter() - t0)
         self.state = "copying"
         self._stamp("begin")
         _inflight(self.matcher)[self.tenant] = self
@@ -468,7 +466,6 @@ class TenantMigration:
         if self.state != "copying":
             raise RuntimeError(f"step() in state {self.state!r}")
         self._check_target()
-        t0 = time.perf_counter()
         from ..replication.records import encode_op
         chunk = reshard_chunk() if n is None else max(1, n)
         trie = self.matcher.tries.get(self.tenant)
@@ -494,15 +491,10 @@ class TenantMigration:
                 self.abort(f"copy error: {e!r}")
                 raise MigrationAborted(self.abort_reason) from e
         self.chunks += 1
-        dt = time.perf_counter() - t0
-        STAGES.record("mesh.migrate", dt)
-        STAGES.record("mesh.migrate.copy", dt)
         if self._cursor >= len(self.pending):
-            t1 = time.perf_counter()
             with trace.span("mesh.migrate.ready", tenant=self.tenant,
                             rows=self.copied_n):
                 emit_migration_op(self.matcher, ("mig_ready", self.tenant))
-            STAGES.record("mesh.migrate.ready", time.perf_counter() - t1)
             self.state = "ready"
             self._stamp("ready")
             return True
@@ -515,12 +507,10 @@ class TenantMigration:
         if self.state != "ready":
             raise RuntimeError(f"cutover() in state {self.state!r}")
         self._check_target()
-        t0 = time.perf_counter()
         with trace.span("mesh.migrate.cutover", tenant=self.tenant,
                         src=self.src, dst=self.dst):
             emit_migration_op(self.matcher, ("mig_cutover", self.tenant,
                                              self.src, self.dst))
-        STAGES.record("mesh.migrate.cutover", time.perf_counter() - t0)
         self.state = "cutover"
         self._stamp("cutover")
         return self
@@ -538,12 +528,10 @@ class TenantMigration:
         ring = self.matcher._ring
         if ring is not None and ring.in_flight > 0:
             return False
-        t0 = time.perf_counter()
         with trace.span("mesh.migrate.tombstone", tenant=self.tenant,
                         src=self.src):
             emit_migration_op(self.matcher, ("mig_tombstone", self.tenant,
                                              self.src))
-        STAGES.record("mesh.migrate.tombstone", time.perf_counter() - t0)
         self.state = "done"
         self._stamp("tombstone")
         self._retire("done")

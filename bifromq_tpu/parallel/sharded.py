@@ -1276,7 +1276,6 @@ class MeshMatcher(TpuMatcher):
             self.refresh()
         tables: ShardedTables = self._base_ct
         r, s = self.n_replicas, self.n_shards
-        t0 = time.perf_counter()
         slots = self._route_slots(queries, tables)
         # per-shard fault domain: an OPEN shard's rows never dispatch —
         # they serve from the exact host oracle while healthy shards
@@ -1317,7 +1316,7 @@ class MeshMatcher(TpuMatcher):
         salts = {ct.salt for ct in tables.compiled}
         cache = self._tok_cache if len(salts) == 1 else None
         with trace.span("device.tokenize", batch=r * s * b,
-                        queries=len(queries)):
+                        queries=len(queries)) as sp:
             for rep in range(r):
                 for sh in range(s):
                     idxs = slots[rep * s + sh]
@@ -1342,8 +1341,7 @@ class MeshMatcher(TpuMatcher):
             grids = None if split else tuple(
                 jax.device_put(a, self._probe_sharding)
                 for a in (tok_h1, tok_h2, lengths, roots, sys_mask))
-        tokenize_s = time.perf_counter() - t0
-        STAGES.record("tokenize", tokenize_s)
+        tokenize_s = sp.duration_s
         dispatch_shards = sorted({
             sh for sh in range(s)
             if any(slots[j * s + sh] for j in range(r))})
@@ -1404,7 +1402,6 @@ class MeshMatcher(TpuMatcher):
             return self._dispatch_split(prep, fault, fault_shards)
         dev_edge, dev_child, dev_route = self._device_trie
         use_expand = device_expand_enabled()
-        t0 = time.perf_counter()
         with trace.span("device.dispatch", batch=prep.batch,
                         queries=len(prep.queries)) as sp:
             if use_expand:
@@ -1413,10 +1410,8 @@ class MeshMatcher(TpuMatcher):
             else:
                 ivl_s, ivl_c, _n_routes, overflow, _total = self._step(
                     dev_edge, dev_child, dev_route, *prep.grids)
-            if sp is not trace.NOOP:
-                sp.set_tag("kernel", "mesh")
-        dispatch_s = time.perf_counter() - t0
-        STAGES.record("device.dispatch", dispatch_s)
+            sp.set_tag("kernel", "mesh")
+        dispatch_s = sp.duration_s
         res = _MeshResult(start=ivl_s, count=ivl_c, overflow=overflow)
         dev_expand_s = 0.0
         peer_tab = None
@@ -1425,8 +1420,7 @@ class MeshMatcher(TpuMatcher):
             # expansion + peer bucketing, cross-mesh totals merged by the
             # right_permute ring; the fetch then reads compact buffers
             # that are already grouped by delivery broker
-            t1 = time.perf_counter()
-            with trace.span("device.expand", batch=prep.batch):
+            with trace.span("device.expand", batch=prep.batch) as sp:
                 peer_tab, slot_peer = self._mesh_peer_table(prep.ct)
                 step = make_expand_step(
                     self.mesh, cap=prep.b * expand_cap_lanes(),
@@ -1440,8 +1434,7 @@ class MeshMatcher(TpuMatcher):
                     n_pairs=n_pairs, trunc=trunc, peer_slots=peer_slots,
                     peer_rows=peer_rows, peer_offsets=peer_offsets,
                     peer_totals=peer_totals)
-            dev_expand_s = time.perf_counter() - t1
-            STAGES.record("device.expand", dev_expand_s)
+            dev_expand_s = sp.duration_s
         tag = "mesh"
         if fault_shards:
             tag = "mesh:" + ",".join(f"shard{sh}"
@@ -1560,7 +1553,6 @@ class MeshMatcher(TpuMatcher):
             [(sh,) for sh in sorted(prep.canaries.pending)
              if sh in prep.dispatch_shards]
         groups: List[_SplitGroup] = []
-        t0 = time.perf_counter()
         with trace.span("device.dispatch", batch=prep.batch,
                         queries=len(prep.queries)) as sp:
             for cols in group_cols:
@@ -1583,10 +1575,8 @@ class MeshMatcher(TpuMatcher):
                 groups.append(_SplitGroup(
                     cols, _MeshResult(start=ivl_s, count=ivl_c,
                                       overflow=overflow), gf, tag))
-            if sp is not trace.NOOP:
-                sp.set_tag("kernel", "mesh_split")
-        dispatch_s = time.perf_counter() - t0
-        STAGES.record("device.dispatch", dispatch_s)
+            sp.set_tag("kernel", "mesh_split")
+        dispatch_s = sp.duration_s
         tag = "mesh"
         if fault_shards:
             tag = "mesh:" + ",".join(f"shard{sh}"
@@ -1602,18 +1592,19 @@ class MeshMatcher(TpuMatcher):
             dispatch_s=dispatch_s, tokenize_s=prep.tokenize_s,
             quarantine_tag=tag)
 
-    def _note_shard_ready(self, sh: int, dt: float,
+    def _note_shard_ready(self, sh: int, t0_ns: int,
                           start_hlc: int = 0) -> None:
         """One completion row (ISSUE 20): per-shard dispatch→ready timing
-        into the stage histogram + the board (deferred span like the
-        batcher's queue-wait — duration is only known at readiness); a
+        (from ``t0_ns``, a ``monotonic_ns`` stamp, to now) into the stage
+        histogram + the board (deferred span like the batcher's
+        queue-wait — duration is only known at readiness); a
         previously-hung shard that serves again clears its degraded
         attribution."""
-        STAGES.record("device.shard_ready", dt)
+        now_ns = time.monotonic_ns()
         trace.record_finished("device.shard_ready", trace.current_ctx(),
-                              start_hlc=start_hlc, duration_s=dt,
-                              tags={"shard": sh})
-        self.completion.note_ready(sh, dt)
+                              start_ns=t0_ns, end_ns=now_ns,
+                              start_hlc=start_hlc, tags={"shard": sh})
+        self.completion.note_ready(sh, (now_ns - t0_ns) * 1e-9)
         OBS.e2e.clear_degraded(f"mesh:shard{sh}")
 
     async def _await_ready_shards(self, ring, fl) -> None:
@@ -1628,14 +1619,13 @@ class MeshMatcher(TpuMatcher):
                                          device_deadline_s)
         shards = list(fl.dispatch_shards or ())
         if len(shards) <= 1:
-            t0, shlc = time.monotonic(), HLC.INST.get()
+            t0, shlc = time.monotonic_ns(), HLC.INST.get()
             await ring.wait_ready(fl.res, fault=fl.fault)
-            dt = time.monotonic() - t0
             for sh in shards:
-                self._note_shard_ready(sh, dt, shlc)
+                self._note_shard_ready(sh, t0, shlc)
             return
         deadline = device_deadline_s()
-        t0, shlc = time.monotonic(), HLC.INST.get()
+        t0, shlc = time.monotonic_ns(), HLC.INST.get()
         hung: List[int] = []
 
         async def wait_shard(sh: int) -> None:
@@ -1643,7 +1633,7 @@ class MeshMatcher(TpuMatcher):
                 await ring.wait_ready(
                     fl.res, deadline_s=deadline,
                     fault=fl.fault_shards.get(sh, fl.fault))
-                self._note_shard_ready(sh, time.monotonic() - t0, shlc)
+                self._note_shard_ready(sh, t0, shlc)
             except DeviceTimeoutError:
                 hung.append(sh)
         await asyncio.gather(*(wait_shard(sh) for sh in shards))
@@ -1671,7 +1661,7 @@ class MeshMatcher(TpuMatcher):
         from ..resilience.device import (DeviceTimeoutError,
                                          shard_deadline_s)
         deadline = shard_deadline_s()
-        t0, shlc = time.monotonic(), HLC.INST.get()
+        t0, shlc = time.monotonic_ns(), HLC.INST.get()
 
         async def wait_group(g: _SplitGroup) -> None:
             # ISSUE 20: a half-open canary probes alone under a deadline
@@ -1683,9 +1673,8 @@ class MeshMatcher(TpuMatcher):
             try:
                 await ring.wait_ready(g.res, deadline_s=gd,
                                       fault=g.fault)
-                dt = time.monotonic() - t0
                 for sh in g.shards:
-                    self._note_shard_ready(sh, dt, shlc)
+                    self._note_shard_ready(sh, t0, shlc)
             except DeviceTimeoutError:
                 g.failed = True
         await asyncio.gather(*(wait_group(g) for g in res.groups))

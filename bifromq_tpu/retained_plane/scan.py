@@ -148,8 +148,7 @@ class RetainedScanPlane:
                 if reason is not None:
                     self.degraded_total[reason] = \
                         self.degraded_total.get(reason, 0) + 1
-                    if sp is not trace.NOOP:
-                        sp.set_tag("degraded", reason)
+                    sp.set_tag("degraded", reason)
             # ISSUE 13 satellite bugfix: retained scans feed the tenant
             # RED windows like deliver.fanout does — latency per scanned
             # tenant, achieved retained fan-out into the fanout share.

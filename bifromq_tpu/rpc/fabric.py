@@ -50,7 +50,6 @@ from typing import Awaitable, Callable, Dict, List, Optional, Tuple
 from .. import trace as _trace
 from ..resilience import faults as _faults
 from ..resilience import policy as _policy
-from ..utils.metrics import STAGES as _STAGES
 
 log = logging.getLogger(__name__)
 
@@ -464,20 +463,16 @@ class RPCClient:
                    trace_tags: Optional[dict] = None) -> bytes:
         """Span-wrapped call (ISSUE 2): every attempt gets an "rpc.attempt"
         span tagged with endpoint + breaker state (``trace_tags`` lets
-        ``call_resilient`` stamp attempt/failover counts), and feeds the
-        "rpc" stage histogram whether or not the trace is sampled."""
+        ``call_resilient`` stamp attempt/failover counts); its exit feeds
+        the "rpc" stage histogram whether or not the trace is sampled."""
         sp = _trace.span("rpc.attempt", service=service, method=method,
                          endpoint=f"{self.host}:{self.port}",
                          **(trace_tags or {}))
         if self.breaker is not None:
             sp.set_tag("breaker", self.breaker.state)
-        t0 = time.perf_counter()
-        try:
-            with sp:
-                return await self._call(service, method, payload,
-                                        order_key, timeout)
-        finally:
-            _STAGES.record("rpc", time.perf_counter() - t0)
+        with sp:
+            return await self._call(service, method, payload, order_key,
+                                    timeout)
 
     async def _call(self, service: str, method: str, payload: bytes,
                     order_key: str, timeout: float) -> bytes:

@@ -22,7 +22,6 @@ from typing import (Awaitable, Callable, Dict, Generic, Hashable, List,
 from .. import trace
 from ..obs import OBS
 from ..utils.hlc import HLC
-from ..utils.metrics import STAGES
 
 CallT = TypeVar("CallT")
 ResultT = TypeVar("ResultT")
@@ -67,7 +66,7 @@ class Batcher(Generic[CallT, ResultT]):
                  stage: Optional[str] = None,
                  obs_key: Optional[str] = None,
                  shallow_decay: bool = True,
-                 clock: Callable[[], float] = time.perf_counter) -> None:
+                 clock: Callable[[], float] = time.monotonic) -> None:
         if pipeline_depth is None:
             # ISSUE 6: one knob rules the whole pipeline — the batcher's
             # in-flight batches and the matcher's dispatch ring share
@@ -86,15 +85,17 @@ class Batcher(Generic[CallT, ResultT]):
         # the latency/depth signals deterministically)
         self._clock = clock
         # ISSUE 2: a named stage turns on enqueue→emit queue-wait
-        # attribution — per-call histogram records under ``stage`` and,
-        # for sampled calls, deferred "batch.queue_wait" spans stamped
-        # with batch size + the adaptive cap AT EMIT TIME
+        # attribution — one deferred "batch.queue_wait" boundary per
+        # call, closed AT EMIT TIME on the batcher's clock (seconds of
+        # CLOCK_MONOTONIC): its exit feeds the ``stage`` histogram and,
+        # for sampled calls, a span stamped with batch size + the
+        # adaptive cap
         self._stage = stage
         # ISSUE 3: when the batcher key IS a tenant (the pub scheduler),
         # queue-wait also lands in that tenant's SLO window — the
         # noisy-neighbor detector's share-of-queue-wait signal
         self._obs_key = obs_key
-        # queue entries: (call, fut, enqueue_perf, trace_ctx, start_hlc)
+        # queue entries: (call, fut, enqueue_clock, trace_ctx, start_hlc)
         self._queue: List[Tuple[CallT, asyncio.Future, float,
                                 Optional[object], int]] = []
         self._inflight = 0
@@ -176,12 +177,13 @@ class Batcher(Generic[CallT, ResultT]):
         if self._stage is not None:
             # enqueue→emit queue-wait per call, stamped at EMIT time with
             # the batch shape the adaptive cap produced
+            start_ns = int(start * 1e9)
+            # one id per emitted batch, carried by every sampled span
+            # opened under this task (the device spans included), so a
+            # sampled publish joins its batch whoever parents the batch
+            batch_id = trace.open_batch()
+            tags = None
             for _, _, enq, tctx, shlc in batch:
-                wait = start - enq
-                STAGES.record(self._stage, wait)
-                if self._obs_key is not None:
-                    OBS.record_queue_wait(self._obs_key, wait)
-                    OBS.record_latency(self._obs_key, "queue_wait", wait)
                 if tctx is not None:
                     if rep_ctx is None:
                         rep_ctx = tctx
@@ -190,11 +192,12 @@ class Batcher(Generic[CallT, ResultT]):
                         # on the batch-emit span below, so its trace still
                         # reaches the device work it shared
                         links.append((tctx.trace_id, tctx.span_id))
-                    trace.record_finished(
-                        "batch.queue_wait", tctx, start_hlc=shlc,
-                        duration_s=wait,
-                        tags={"batch_size": len(batch), "cap": self._cap,
-                              "stage": self._stage})
+                    tags = {"batch_size": len(batch), "cap": self._cap,
+                            "stage": self._stage, "batch_id": batch_id}
+                trace.record_finished(
+                    "batch.queue_wait", tctx, start_ns=int(enq * 1e9),
+                    end_ns=start_ns, start_hlc=shlc, tenant=self._obs_key,
+                    tags=tags, stage=self._stage)
         try:
             if self._stage is not None:
                 # a batch aggregates many callers' traces; run the

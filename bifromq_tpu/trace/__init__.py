@@ -7,21 +7,27 @@ Usage at an instrumentation site::
     with trace.span("match.device", tenant=tenant_id, n=len(queries)):
         ...
 
-Spans are no-ops unless sampling is configured (per-tenant probabilistic
-via ``TRACER.sampler``, always-on-slow via ``TRACER.slow_ms``, env knobs
+A span always times its boundary (``time.monotonic_ns``) and feeds the
+name's window totals (``TRACER.totals``), stage histogram and tenant
+window as ``trace/names.py`` registers them; it materializes a ``Span``
+into the ring only when sampling says so (per-tenant probabilistic via
+``TRACER.sampler``, always-on-slow via ``TRACER.slow_ms``, env knobs
 ``BIFROMQ_TRACE_SAMPLE`` / ``BIFROMQ_TRACE_SLOW_MS``). The RPC fabric
 carries contexts across processes; the API server serves the rings at
 ``/trace`` and ``/trace/slow``.
 """
 
+from .names import BOUNDARIES, KNOWN_STAGES, Boundary
 from .recorder import SpanRing
 from .sampler import TenantSampler
 from .span import Span, SpanContext, decode_ctx, new_id
-from .tracer import (LINK_CAP, NOOP, TRACER, Tracer, activate, current_ctx,
-                     extract, inject, record_finished, span)
+from .totals import WindowTotals
+from .tracer import (LINK_CAP, TRACER, Tracer, activate, count, current_ctx,
+                     extract, inject, open_batch, record_finished, span)
 
 __all__ = [
-    "LINK_CAP", "NOOP", "TRACER", "Tracer", "Span", "SpanContext",
-    "SpanRing", "TenantSampler", "activate", "current_ctx", "decode_ctx",
-    "extract", "inject", "new_id", "record_finished", "span",
+    "BOUNDARIES", "Boundary", "KNOWN_STAGES", "LINK_CAP", "TRACER", "Tracer",
+    "Span", "SpanContext", "SpanRing", "TenantSampler", "WindowTotals",
+    "activate", "count", "current_ctx", "decode_ctx", "extract", "inject",
+    "new_id", "open_batch", "record_finished", "span",
 ]

@@ -97,6 +97,11 @@ class Span:
     # sampled caller's (trace_id, span_id) — OpenTelemetry span-link
     # semantics, bounded by the recorder
     links: tuple = ()
+    # the same boundary on CLOCK_MONOTONIC (``time.monotonic_ns``): the
+    # clock of the window totals, of the load generator and, through one
+    # annotated span seen in a kept profiler trace, of the device trace
+    start_ns: int = 0
+    end_ns: int = 0
 
     def to_dict(self) -> dict:
         out = {
@@ -111,6 +116,8 @@ class Span:
             "start_hlc": self.start_hlc,
             "end_hlc": self.end_hlc,
             "start_ms": HLC.physical(self.start_hlc),
+            "start_ns": self.start_ns,
+            "end_ns": self.end_ns,
             "duration_ms": round(self.duration_ms, 4),
             "status": self.status,
             # wire-bytes tag values (ISSUE 12 byte-plane pub path) decode

@@ -89,37 +89,11 @@ STAGES = StageLatencies()
 # ISSUE 10 (graftcheck R5): the registered stage-name set. Stage
 # histograms are stringly-typed — a typo'd name at a record site would
 # silently open an orphan series nobody dashboards — so every literal
-# fed to STAGES.record / Batcher(stage=...) / OBS.record_latency must
-# appear here, and every entry here must be emitted somewhere (the
-# analyzer checks both directions).
-KNOWN_STAGES = frozenset({
-    "ingest",           # mqtt/session publish ingest
-    "queue_wait",       # scheduler/batcher enqueue→emit
-    "rpc",              # rpc/fabric attempt wall time
-    "device",           # dist/worker per-range device match
-    "tokenize",         # ISSUE 11: byte-plane topic prep + probe upload
-    "device.dispatch",  # matcher walk enqueue cost
-    "device.ready",     # in-flight walk awaited on readiness
-    # ISSUE 20: per-shard dispatch→ready completion rows (mesh steps
-    # record one per dispatched shard — the /mesh hung-device naming)
-    "device.shard_ready",
-    "device.fetch",     # final host copy
-    "device.expand",    # ISSUE 19: fan-out expansion + peer-bucket enqueue
-    "deliver",          # dist/service fan-out
-    "repl.apply",       # ISSUE 12: standby delta-batch apply (host+flush)
-    "mesh.flush",       # ISSUE 15: per-shard mesh patch flush (scatters)
-    "retain.scan",      # ISSUE 13: retained wildcard scan batch (SUBSCRIBE)
-    "inbox.drain",      # ISSUE 13: persistent-session catch-up drain
-    "mesh.migrate",     # ISSUE 17: live-migration copy chunks + resize
-    "repl.audit",       # ISSUE 18: leader parity-fingerprint fold + emit
-    # ISSUE 18: per-rung migration-ladder timing (the aggregate
-    # mesh.migrate histogram stays — dashboards keyed on it survive)
-    "mesh.migrate.begin",
-    "mesh.migrate.copy",
-    "mesh.migrate.ready",
-    "mesh.migrate.cutover",
-    "mesh.migrate.tombstone",
-})
+# fed to STAGES.record / Batcher(stage=...) / OBS.record_latency must be
+# a stage that the ONE registry of boundary names (``trace/names.py``)
+# gives some row, and every registered stage must be emitted somewhere
+# (the analyzer checks both directions).
+from ..trace.names import KNOWN_STAGES  # noqa: E402,F401
 
 
 class TenantMetric(enum.Enum):

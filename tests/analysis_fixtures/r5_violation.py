@@ -1,4 +1,6 @@
-"""R5 fixture: unregistered stage + cache-field typo."""
+"""R5 fixture: unregistered stage + cache-field typo + a boundary name
+with no row in the registry."""
+from bifromq_tpu import trace
 from bifromq_tpu.utils.metrics import MATCH_CACHE, STAGES
 
 
@@ -10,3 +12,9 @@ def bad_stage(dt):
 def bad_cache_field():
     # R5: typo'd field not in MatchCacheMetrics._FIELDS
     MATCH_CACHE.inc("matcher", "hist", 1)
+
+
+def bad_boundary():
+    # R5: a span and a counter opened under names trace/names.py lacks
+    with trace.span("deliver.fanuot"):
+        trace.count("redy.polls", 1)

@@ -126,6 +126,43 @@ class TestRuleFixtures:
         assert "devcie.dispatch" in symbols   # typo'd stage
         assert "hist" in symbols              # typo'd cache field
 
+    def test_r5_boundary_without_a_registry_row(self):
+        # one registry of boundary names (trace/names.py): a span or a
+        # counter opened under a name it lacks is a finding
+        bad, clean = self._split(fixture_findings(RegistryDriftRule), 5)
+        assert clean == [], [f.render() for f in clean]
+        symbols = {f.symbol for f in bad}
+        assert "deliver.fanuot" in symbols    # typo'd span
+        assert "redy.polls" in symbols        # typo'd counter
+
+    def test_r5_dead_row_and_readme_drift(self, tmp_path):
+        # a row nothing opens, a stage nothing feeds and a README table
+        # that lags the registry each fail, in a tree of its own
+        pkg = tmp_path / "pkg"
+        (pkg / "trace").mkdir(parents=True)
+        (pkg / "trace" / "names.py").write_text(
+            "def _row(*a, **k): pass\n"
+            "_row('live.span', 'span', 'm.py', 'x', stage='live')\n"
+            "_row('dead.span', 'span', 'm.py', 'x', stage='dead')\n")
+        (pkg / "m.py").write_text(
+            "from . import trace\n"
+            "def f():\n"
+            "    with trace.span('live.span'):\n"
+            "        pass\n")
+        readme = tmp_path / "README.md"
+        readme.write_text("| span | where |\n|---|---|\n"
+                          "| `live.span` | m |\n| `gone.span` | m |\n")
+        report = run_analysis(root=str(pkg), readme=str(readme),
+                              suppressions=None,
+                              rules=[RegistryDriftRule])
+        found = {(f.scope, f.symbol) for f in report.findings}
+        assert ("<BOUNDARIES>", "dead.span") in found
+        assert ("<KNOWN_STAGES>", "dead") in found
+        assert ("<span-table>", "gone.span") in found
+        assert ("<span-table>", "dead.span") in found
+        assert not any(f.symbol in ("live.span", "live")
+                       for f in report.findings)
+
 
 # ---------------------------------------------------------------------------
 # suppression machinery

@@ -400,7 +400,7 @@ class TestExporter:
             with trace.span("root", tenant="acme") as root:
                 with trace.span("fastchild"):
                     pass
-                root._t0 -= 1.0        # root crosses the threshold
+                root.start_ns -= 10**9        # root crosses the threshold
             exp = TelemetryExporter(FileSink(str(path)), interval_s=60)
             await exp._flush_once()
         finally:
@@ -431,7 +431,7 @@ class TestExporter:
             with trace.span("root2", tenant="acme") as root:
                 with trace.span("child2"):
                     pass
-                root._t0 -= 1.0     # slow root: lands in BOTH rings
+                root.start_ns -= 10**9     # slow root: lands in BOTH rings
             await exp._flush_once()
         finally:
             trace.TRACER.sampler.default_rate = 0.0
@@ -594,7 +594,7 @@ class TestSlowTraceChildren:
             for i in range(3):
                 with tr.span(f"child{i}"):
                     pass                    # fast children
-            root._t0 -= 1.0                 # root crossed the threshold
+            root.start_ns -= 10**9                 # root crossed the threshold
         slow = tr.export(slow=True, limit=100)
         names = {s["name"] for s in slow}
         assert names == {"root", "child0", "child1", "child2"}
@@ -608,7 +608,7 @@ class TestSlowTraceChildren:
             for i in range(100):
                 with tr.span(f"c{i}"):
                     pass
-            root._t0 -= 1.0
+            root.start_ns -= 10**9
         slow = tr.export(slow=True, limit=1000)
         # root + at most SLOW_CHILD_CAP children
         assert 2 <= len(slow) <= Tracer.SLOW_CHILD_CAP + 1
@@ -618,8 +618,8 @@ class TestSlowTraceChildren:
         tr = Tracer(sampler=TenantSampler(1.0), slow_ms=50.0)
         with tr.span("root", tenant="t") as root:
             with tr.span("slowchild") as c:
-                c._t0 -= 1.0                # child itself slow
-            root._t0 -= 1.0
+                c.start_ns -= 10**9                # child itself slow
+            root.start_ns -= 10**9
         slow = tr.export(slow=True, limit=100)
         assert [s["name"] for s in slow].count("slowchild") == 1
 
@@ -636,7 +636,7 @@ class TestSlowTraceChildren:
             with tr.span("rpc.server") as server:
                 with tr.span("match.device"):
                     pass
-                server._t0 -= 1.0   # the server span is the slow one
+                server.start_ns -= 10**9   # the server span is the slow one
         slow = tr.export(slow=True, limit=100)
         names = {s["name"] for s in slow}
         assert names == {"rpc.server", "match.device"}, names

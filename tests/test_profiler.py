@@ -354,3 +354,91 @@ class TestOTLPFraming:
         assert exp.framing == "otlp"
         monkeypatch.setenv("BIFROMQ_OBS_FORMAT", "bogus")
         assert hub.exporter_from_env().framing == "jsonl"
+
+
+# ---------------------------------------------------------------------------
+# PR 29: the BatchRecord and the profiler's trace read the spans
+# ---------------------------------------------------------------------------
+
+class TestBoundaryRecords:
+    async def test_batch_record_durations_equal_the_spans(self):
+        """One publish on the rehearsal table: the profiler's BatchRecord
+        holds the spans' own durations, not a second pair of clock
+        reads."""
+        from bifromq_tpu.mqtt.client import MQTTClient
+        from rehearsal_broker import rehearsal_broker
+        async with rehearsal_broker() as (node, _matcher, tenant, topics):
+            p = MQTTClient("127.0.0.1", node.broker.port, client_id="bp",
+                           username=f"{tenant}/pub")
+            await p.connect()
+            await p.publish(topics[0], b"warm" * 4, qos=1)
+            trace.TRACER.reset()
+            _recs, cursor, _missed = OBS.profiler.since(0)
+            trace.TRACER.sampler.default_rate = 1.0
+            try:
+                await p.publish(topics[1], b"timed" * 4, qos=1)
+            finally:
+                trace.TRACER.sampler.default_rate = 0.0
+            await p.disconnect()
+            recs, _cursor, _missed = OBS.profiler.since(cursor)
+        spans = {s.name: s for s in trace.TRACER.ring.spans()}
+        trace.TRACER.reset()
+        assert len(recs) == 1, [r.to_dict() for r in recs]
+        rec = recs[0]
+
+        def seconds(name):
+            s = spans[name]
+            return (s.end_ns - s.start_ns) * 1e-9
+        assert rec.tokenize_s == seconds("device.tokenize")
+        assert rec.dispatch_s == seconds("device.dispatch")
+        assert rec.ready_s == seconds("device.ready")
+        assert rec.fetch_s == seconds("device.fetch")
+        assert rec.expand_s == seconds("match.expand")
+        if "device.expand" in spans:
+            assert rec.dev_expand_s == seconds("device.expand")
+        # the fetch holds its wait
+        assert seconds("device.fetch.wait") <= rec.fetch_s
+        assert abs(rec.ts - time.time()) < 60       # wall clock, as read
+
+    async def test_cpu_profiler_trace_holds_the_boundary_names(self,
+                                                               tmp_path):
+        """A ``jax.profiler.trace`` of one publish (CPU): the program's
+        own annotations sit on a ``/host:`` plane, under the spans' bare
+        names. Its own time limit, inside the harness's."""
+        import glob
+        import os
+        import jax
+        from jax.profiler import ProfileData
+        from bifromq_tpu.mqtt.client import MQTTClient
+        from rehearsal_broker import rehearsal_broker
+
+        async def traced_publish():
+            async with rehearsal_broker() as (node, _m, tenant, topics):
+                p = MQTTClient("127.0.0.1", node.broker.port,
+                               client_id="tp", username=f"{tenant}/pub")
+                await p.connect()
+                await p.publish(topics[0], b"warm" * 4, qos=1)
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0    # the program names itself
+                jax.profiler.start_trace(str(tmp_path),
+                                         profiler_options=opts)
+                try:
+                    await p.publish(topics[1], b"seen" * 4, qos=1)
+                finally:
+                    jax.profiler.stop_trace()
+                await p.disconnect()
+        await asyncio.wait_for(traced_publish(), 50)
+        (path,) = glob.glob(os.path.join(
+            str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+        names = set()
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    names.update(e.name for e in line.events)
+        # every synchronous boundary of a publish, by its bare name
+        want = {"mqtt.decode", "device.tokenize", "device.dispatch",
+                "device.fetch", "device.fetch.wait", "match.expand",
+                "deliver.group"}
+        assert want <= names, want - names
+        # spans whose bodies await are not annotated as a whole
+        assert not {"pub.ingest", "dist.pub", "device.ready"} & names
