@@ -674,17 +674,21 @@ class DistWorker:
         # each tenant's window its row share of the batch
         with trace.span("match.device", tenant=sub[0][0],
                         n_queries=len(sub)) as sp:
-            out, waited_s = await self._match_or_degrade(
+            out, waited_s, of_batch = await self._match_or_degrade(
                 coproc, sub, max_persistent_fanout, max_group_fanout,
                 deadline, sp)
-            sp.charge(shares=self._tenant_shares(sub), waited_s=waited_s)
+            sp.charge(shares=self._tenant_shares(sub, of_batch),
+                      waited_s=waited_s)
         return out
 
     async def _match_or_degrade(self, coproc, sub, max_persistent_fanout,
                                 max_group_fanout, deadline, sp):
-        """``(results, waited_s)``: the device serve, or on any fault the
-        host oracle. ``waited_s`` is the ring-admission wait the matcher
-        reported: queue time, not this batch's match cost."""
+        """``(results, waited_s, of_batch)``: the device serve, or on any
+        fault the host oracle. ``waited_s`` is the ring-admission wait
+        the matcher reported: queue time, not this batch's match cost.
+        ``of_batch`` is this call's share of the rows of the device batch
+        that served it: calls that waited at the ring together shared
+        one walk, and split its cost."""
         cache = getattr(coproc.matcher, "match_cache", None)
         c0 = cache.counts() if cache is not None else (0, 0)
         try:
@@ -734,7 +738,8 @@ class DistWorker:
             # it out of the "device" stage and the per-tenant shares —
             # the stage then measures the same thing either side of
             # BIFROMQ_PIPELINE (the sync fallback has no such wait)
-            return out, stats.get("acquire_s", 0.0)
+            return (out, stats.get("acquire_s", 0.0),
+                    stats.get("batch_share", 1.0))
         except Exception as e:  # noqa: BLE001 — degrade, don't fail
             oracle = getattr(coproc.matcher, "match_from_tries", None)
             if oracle is None:
@@ -753,22 +758,24 @@ class DistWorker:
                 out = oracle(sub,
                              max_persistent_fanout=max_persistent_fanout,
                              max_group_fanout=max_group_fanout)
-            return out, 0.0
+            return out, 0.0, 1.0
 
     @staticmethod
-    def _tenant_shares(sub) -> dict:
+    def _tenant_shares(sub, of_batch: float = 1.0) -> dict:
         """Per-row tenant attribution of a range batch's device time
         (ISSUE 4 satellite, closing the PR-3 follow-up): each tenant's SLO
         window gets its row-count share of the batch instead of the whole
         batch landing on the representative tenant — /tenants device
-        shares stay honest under mixed batches."""
+        shares stay honest under mixed batches. ``of_batch`` scales them
+        to this call's part of a device batch it shared, so the shares
+        of one device batch sum to 1 over all its callers."""
         n = len(sub)
         if n == 1:
-            return {sub[0][0]: 1.0}
+            return {sub[0][0]: of_batch}
         counts: dict = {}
         for tenant_id, _levels in sub:
             counts[tenant_id] = counts.get(tenant_id, 0) + 1
-        return {t: c / n for t, c in counts.items()}
+        return {t: c * of_batch / n for t, c in counts.items()}
 
     async def match_batch(self, queries, *, max_persistent_fanout,
                           max_group_fanout, linearized: bool = False,

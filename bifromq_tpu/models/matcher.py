@@ -983,7 +983,10 @@ class TpuMatcher:
         match cost is cache probe + dispatch+ready+fetch + host
         expansion and cache fill, the same work the sync path's wall
         clock covers, and toggling ``BIFROMQ_PIPELINE`` does not shift
-        what the "device" stage histograms measure. ``stats["degraded"]``
+        what the "device" stage histograms measure.
+        ``stats["batch_share"]`` is this call's share of the rows of the
+        device batch that served it (1.0 alone; callers waiting at ring
+        admission leave as one batch). ``stats["degraded"]``
         carries the reason when the batch was served from the host
         oracle (ISSUE 7: breaker open, watchdog timeout, device error)
         so the worker can emit MATCH_DEGRADED events without a raising
@@ -1017,9 +1020,10 @@ class TpuMatcher:
             out = [None] * len(queries)
             uniq_queries = list(queries)
         if uniq_queries:
-            res, degraded, acquire_s = await self._device_serve_async(
-                uniq_queries, batch, max_persistent_fanout,
-                max_group_fanout)
+            res, degraded, acquire_s, share = \
+                await self._device_serve_async(
+                    uniq_queries, batch, max_persistent_fanout,
+                    max_group_fanout)
             if cache is not None:
                 self._frontend_fill(out, res, uniq, miss_rows, tokens,
                                     caps)
@@ -1031,6 +1035,9 @@ class TpuMatcher:
                 # out of the "device" stage and the per-tenant
                 # attribution feeding the noisy detector
                 stats["acquire_s"] = acquire_s
+                # this caller's rows over the device batch's: callers
+                # that shared one walk split its cost between them
+                stats["batch_share"] = share
                 if degraded is not None:
                     stats["degraded"] = degraded
         if cache is not None:
@@ -1039,15 +1046,71 @@ class TpuMatcher:
 
     async def _device_serve_async(self, uniq_queries, batch,
                                   max_persistent_fanout, max_group_fanout):
+        """One caller's way through ring admission: it waits in line for
+        a prep ticket and leaves with whoever waits there with it, as ONE
+        device batch (``_serve_merged``), then takes its own rows.
+
+        Returns ``(results, degraded_reason, acquire_s, share)``:
+        ``degraded_reason`` as ``_serve_batch`` gives it; ``acquire_s``
+        is this caller's wait from entry to the batch's slot admission
+        (queue time, which the caller subtracts from its device-time
+        accounting; it starts no deadline and no watchdog); ``share`` is
+        its rows over the batch's. Cancelled while in line it just
+        leaves; cancelled after its batch left, its rows are dropped and
+        the walk goes on for the others."""
+        from .pipeline import Caller
+        ring = self._pipeline_ring()
+        me = Caller(uniq_queries,
+                    (max_persistent_fanout, max_group_fanout), batch,
+                    self._serve_merged)
+        ring.enter(me)
+        try:
+            return await me.fut
+        except asyncio.CancelledError:
+            ring.leave(me)
+            raise
+
+    async def _serve_merged(self, ring, merged) -> None:
+        """ONE device batch over the callers' rows, concatenated in entry
+        order; each caller's future gets its own slice, resolved in entry
+        order. The batch is one unit above the device too: one breaker
+        admission and settle, one ``BatchRecord``, one quarantine entry,
+        and on any fault every caller in it is served by the oracle."""
+        callers = ring.board(merged)
+        if not callers:         # everybody in line gave up meanwhile
+            return
+        queries = (callers[0].queries if len(callers) == 1
+                   else [q for c in callers for q in c.queries])
+        merged.admitted = time.monotonic()
+        try:
+            rows, reason = await self._serve_batch(
+                ring, merged, queries, callers[0].batch, *callers[0].caps)
+        except Exception as e:  # noqa: BLE001 — the callers' to see
+            for c in callers:
+                if not c.fut.done():
+                    c.fut.set_exception(e)
+            return
+        off = 0
+        for c in callers:
+            n = len(c.queries)
+            if not c.fut.done():
+                c.fut.set_result((
+                    rows[off:off + n], reason,
+                    max(0.0, merged.admitted - c.entered
+                        - merged.tokenize_s),
+                    n / len(queries)))
+            off += n
+
+    async def _serve_batch(self, ring, merged, uniq_queries, batch,
+                           max_persistent_fanout, max_group_fanout):
         """The failure-bounded device leg of the async path (ISSUE 7).
 
-        Returns ``(results, degraded_reason, acquire_s)`` —
+        Returns ``(results, degraded_reason)`` —
         ``degraded_reason`` is None when the device served, else one of
         ``breaker`` (circuit open: dispatch skipped entirely), ``timeout``
         (watchdog fired: the ring slot was reclaimed, the orphaned arrays
-        quarantined), or ``device_error`` (dispatch/fetch raised);
-        ``acquire_s`` is the ring-acquire wait the caller subtracts from
-        its device-time accounting. Every degraded serve comes from
+        quarantined), or ``device_error`` (dispatch/fetch raised).
+        Every degraded serve comes from
         ``match_from_tries`` — the authoritative host oracle, exact by
         construction — so the publish path NEVER fails on a sick device;
         it just loses the accelerator speedup until the canary re-closes
@@ -1058,15 +1121,14 @@ class TpuMatcher:
         verdict = br.admit() if br is not None else "ok"
         reason = None
         oracle_rows = None
-        timing = {"acquire_s": 0.0}
         if verdict == "rejected":
             reason = "breaker"
         else:
             settled = False
             try:
                 res = await self._device_leg_async(
-                    uniq_queries, batch, max_persistent_fanout,
-                    max_group_fanout, timing)
+                    ring, merged, uniq_queries, batch,
+                    max_persistent_fanout, max_group_fanout)
                 if br is not None:
                     if verdict == "canary":
                         ok, oracle_rows = self._canary_parity(
@@ -1086,7 +1148,7 @@ class TpuMatcher:
                         br.record_success()
                 settled = True
                 if reason is None:
-                    return res, None, timing["acquire_s"]
+                    return res, None
             except DeviceTimeoutError as e:
                 FABRIC.inc(FabricMetric.DEVICE_TIMEOUT)
                 if br is not None:
@@ -1121,99 +1183,96 @@ class TpuMatcher:
                     uniq_queries,
                     max_persistent_fanout=max_persistent_fanout,
                     max_group_fanout=max_group_fanout)
-            return oracle_rows, reason, timing["acquire_s"]
+            return oracle_rows, reason
 
-    async def _device_leg_async(self, uniq_queries, batch,
-                                max_persistent_fanout, max_group_fanout,
-                                timing=None):
-        """dispatch → fetch-on-ready → expand through the bounded ring,
-        with the ISSUE 7 watchdog armed on the readiness wait. A timeout
+    async def _device_leg_async(self, ring, merged, uniq_queries, batch,
+                                max_persistent_fanout, max_group_fanout):
+        """prepare → dispatch → fetch-on-ready → expand through the
+        bounded ring, once a device batch, with the ISSUE 7 watchdog
+        armed on the readiness wait. A timeout
         RECLAIMS the slot: the ring releases it immediately (the next
         batch keeps flowing) and the orphaned result arrays — which may
         alias donated probe buffers the device is still writing — go to
-        quarantine until actually ready. ``timing["acquire_s"]`` reports
-        the ring-acquire wait (queue time, not match cost) even when the
-        leg later raises."""
+        quarantine until actually ready. ``merged.admitted`` (the end
+        of its callers' queue time) and ``merged.tokenize_s`` are set
+        here, and stand even when the leg later raises."""
         from ..resilience.device import DeviceTimeoutError
         from .pipeline import donation_enabled
-        ring = self._pipeline_ring()
         # ISSUE 11 overlap: stage-1 prep (tokenize + probe upload) runs
-        # BEFORE ring admission — batch N+1 tokenizes while batch N is
+        # BEFORE slot admission — batch N+1 tokenizes while batch N is
         # still walking, and a full ring stalls only the enqueue, not
-        # the byte plane. Prep TICKETS (depth + 1) bound the probe
-        # batches resident on device: parked callers beyond one
-        # prep-ahead wait un-uploaded, keeping the capacity model's
-        # in-flight byte accounting honest. The dispatch half re-preps
-        # iff a compaction swapped the base during the admission wait.
-        ticket = False
+        # the byte plane. The batch holds a prep TICKET (depth + 1 of
+        # them, taken when it left the line): callers beyond one
+        # prep-ahead batch wait un-uploaded, keeping the capacity
+        # model's in-flight byte accounting honest. The dispatch half
+        # re-preps iff a compaction swapped the base during the
+        # admission wait.
+        with trace.span("device.acquire"):
+            if batch is None:
+                # queue-depth-adaptive pow2 floor: idle ring ⇒ small
+                # pad to cut time-to-first-result, busy ring ⇒ the
+                # throughput floor. Read before slot admission
+                # (planned_floor = the pre-acquire twin). Callers that
+                # share this batch ARE concurrency, whatever the ring
+                # holds: simultaneous publishes meet the throughput
+                # shape whether they merge or overlap, so what warms it
+                # does not hang on how their arrivals fall.
+                floor = (ring.base_floor if len(merged.callers) > 1
+                         else ring.planned_floor())
+                batch = _pow2_batch(len(uniq_queries), floor=floor)
+            prep = self._prepare_probes(uniq_queries, batch)
+            merged.tokenize_s = prep.tokenize_s
+            await ring.acquire()
+        merged.admitted = time.monotonic()
+        trace.count("match.merged_calls", len(merged.callers))
         try:
-            with trace.span("device.acquire") as acq:
-                await ring.acquire_prep()
-                ticket = True
-                if batch is None:
-                    # queue-depth-adaptive pow2 floor: idle ring ⇒ small
-                    # pad to cut time-to-first-result, busy ring ⇒ the
-                    # throughput floor. Read before slot admission
-                    # (planned_floor = the pre-acquire twin).
-                    batch = _pow2_batch(len(uniq_queries),
-                                        floor=ring.planned_floor())
-                prep = self._prepare_probes(uniq_queries, batch)
-                await ring.acquire()
-            if timing is not None:
-                # queue time: prep-ticket wait + slot wait, minus the
-                # prep work itself (match cost, attributed via the
-                # tokenize stage)
-                timing["acquire_s"] = max(
-                    0.0, acq.duration_s - prep.tokenize_s)
+            fl = self._dispatch_prepared(prep,
+                                         donate=donation_enabled(),
+                                         watchdogged=True)
+            ring.start_fetch(fl.res)
             try:
-                fl = self._dispatch_prepared(prep,
-                                             donate=donation_enabled(),
-                                             watchdogged=True)
-                ring.start_fetch(fl.res)
-                try:
-                    with trace.span("device.ready", batch=fl.batch,
-                                    kernel=fl.kernel) as ready:
-                        await self._await_ready(ring, fl)
-                except DeviceTimeoutError:
-                    ring.reclaim(fl.res,
-                                 tag=getattr(fl, "quarantine_tag", None))
-                    # ISSUE 15: let the subclass attribute the timeout
-                    # (the mesh feeds the implicated SHARD's breaker)
-                    self._note_device_timeout(fl)
-                    # ISSUE 20: the e2e plane's degraded map names the
-                    # component stalling deliveries (the mesh hook above
-                    # already named individual shards; this covers the
-                    # single-chip matcher)
-                    from ..obs import OBS
-                    OBS.e2e.set_degraded(
-                        getattr(fl, "quarantine_tag", None) or "device",
-                        "device_timeout")
-                    raise
-                except BaseException:
-                    # cancelled mid-wait (caller timeout, client
-                    # disconnect): the arrays may still be in flight and
-                    # may alias donated probe buffers — park them like a
-                    # timeout does, minus the timeout accounting, or
-                    # dropping the last reference here would be the
-                    # exact use-after-donate the quarantine exists to
-                    # prevent
-                    ring.quarantine.add(fl.res,
-                                        tag=getattr(fl, "quarantine_tag",
-                                                    None))
-                    raise
-                # a step that completes clears the single-chip degraded
-                # mark (per-shard marks clear on their own ready rows)
-                from ..obs import OBS as _obs
-                _obs.e2e.clear_degraded("device")
-            finally:
-                ring.release()
+                with trace.span("device.ready", batch=fl.batch,
+                                kernel=fl.kernel) as ready:
+                    await self._await_ready(ring, fl)
+            except DeviceTimeoutError:
+                ring.reclaim(fl.res,
+                             tag=getattr(fl, "quarantine_tag", None))
+                # ISSUE 15: let the subclass attribute the timeout
+                # (the mesh feeds the implicated SHARD's breaker)
+                self._note_device_timeout(fl)
+                # ISSUE 20: the e2e plane's degraded map names the
+                # component stalling deliveries (the mesh hook above
+                # already named individual shards; this covers the
+                # single-chip matcher)
+                from ..obs import OBS
+                OBS.e2e.set_degraded(
+                    getattr(fl, "quarantine_tag", None) or "device",
+                    "device_timeout")
+                raise
+            except BaseException:
+                # cancelled mid-wait (caller timeout, client
+                # disconnect): the arrays may still be in flight and
+                # may alias donated probe buffers — park them like a
+                # timeout does, minus the timeout accounting, or
+                # dropping the last reference here would be the
+                # exact use-after-donate the quarantine exists to
+                # prevent
+                ring.quarantine.add(fl.res,
+                                    tag=getattr(fl, "quarantine_tag",
+                                                None))
+                raise
+            # a step that completes clears the single-chip degraded
+            # mark (per-shard marks clear on their own ready rows)
+            from ..obs import OBS as _obs
+            _obs.e2e.clear_degraded("device")
         finally:
-            # held for the WHOLE slot tenure: tickets bound prepped +
-            # in-flight batches together at depth+1, so at most ONE
-            # uploaded-but-undispatched probe set exists when the ring
-            # is full — the exact +1 the capacity model counts
-            if ticket:
-                ring.release_prep()
+            # the ticket is held for the WHOLE slot tenure and goes back
+            # with the slot: tickets bound prepped + in-flight batches
+            # together at depth+1, so at most ONE uploaded-but-
+            # undispatched probe set exists when the ring is full — the
+            # exact +1 the capacity model counts
+            ring.release()
+            ring.release_prep(merged)
         with trace.span("device.fetch") as fetch:
             overflow, starts_a, counts_a = self._fetch_walk(fl.res)
         with trace.span("match.expand") as expand:

@@ -17,6 +17,11 @@ module is the overlap plane:
   floor drops to ``BIFROMQ_PIPELINE_MIN_BATCH`` (default 8) to cut
   time-to-first-result; a busy ring keeps the throughput floor (16).
 
+- callers that wait at admission **share one device batch**: whoever is
+  in line when a prep ticket frees leaves together, up to the busy-ring
+  pad floor, so a batch grows only out of waiting that already happens
+  (no timer, no knob; a lone caller on an idle ring leaves at once).
+
 The ring deliberately has NO asyncio primitives bound at construction
 (no Semaphore/Event): matchers outlive event loops in tests and
 multi-loop processes, so waiters are plain per-call futures created on
@@ -26,8 +31,10 @@ whatever loop is running the dispatch.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import time
-from typing import Optional
+from collections import deque
+from typing import Deque, List, Optional
 
 from .. import trace
 from ..resilience.device import (BoundedSlots, BufferQuarantine,
@@ -60,6 +67,44 @@ def donation_enabled() -> bool:
     return env_bool("BIFROMQ_DONATE_BUFFERS", True)
 
 
+class Caller:
+    """One caller of the async device leg, from entry until its rows are
+    in: what it asked for, when and in which context it entered, and the
+    future its rows arrive on. ``serve`` is the matcher's coroutine that
+    runs ONE device batch for a :class:`Merged` group."""
+
+    __slots__ = ("queries", "caps", "batch", "serve", "entered", "ctx",
+                 "fut", "merged")
+
+    def __init__(self, queries, caps, batch, serve) -> None:
+        self.queries = queries
+        self.caps = caps            # (max_persistent_fanout, max_group_fanout)
+        self.batch = batch          # an explicit pad size never merges
+        self.serve = serve
+        self.entered = time.monotonic()
+        self.ctx = contextvars.copy_context()
+        self.fut = asyncio.get_running_loop().create_future()
+        self.merged: Optional[Merged] = None
+
+
+class Merged:
+    """The callers one device batch serves, in entry order (they board
+    at the first step of ``task``, which runs it), whether the batch
+    still holds its prep ticket, and the two times its callers' queue
+    time is read from: ``admitted``, the instant it won its slot (or
+    stopped trying), and ``tokenize_s``, the prep done inside that
+    wait."""
+
+    __slots__ = ("callers", "task", "ticket", "admitted", "tokenize_s")
+
+    def __init__(self) -> None:
+        self.callers: List[Caller] = []
+        self.task: Optional[asyncio.Task] = None
+        self.ticket = True
+        self.admitted = 0.0
+        self.tokenize_s = 0.0
+
+
 class DispatchRing(BoundedSlots):
     """Bounded in-flight dispatch slots + the queue-depth signal.
 
@@ -90,12 +135,17 @@ class DispatchRing(BoundedSlots):
         # bound the probe batches resident on device. A ticket is held
         # for the whole prep + slot tenure (released WITH the slot), so
         # prepped + in-flight batches together never exceed depth + 1:
-        # with the ring full, exactly ONE caller can hold an uploaded-
+        # with the ring full, exactly ONE batch can hold an uploaded-
         # but-undispatched probe set, which is the "+1 prep-ahead" the
         # capacity model counts (obs/capacity.inflight_bytes). Without
         # the gate, K parked callers would each hold an upload the
         # model never saw.
         self._prep = BoundedSlots(self.capacity + 1)
+        # callers waiting for a prep ticket, in entry order: whoever is
+        # here when the next batch boards leaves with it (board)
+        self._line: Deque[Caller] = deque()
+        # the batch that holds a ticket and has not boarded yet
+        self._boarding: Optional[Merged] = None
 
     # ---------------- slot management --------------------------------------
 
@@ -108,18 +158,92 @@ class DispatchRing(BoundedSlots):
         self.capacity = v
         self._prep.capacity = max(1, v + 1)
 
-    async def acquire_prep(self) -> None:
-        """Admit one stage-1 prep (see ``_prep``): held across tokenize
-        + probe upload + ring admission + the walk's slot tenure,
-        released together with the slot (or when the leg dies)."""
-        await self._prep.acquire()
+    def enter(self, caller: Caller) -> None:
+        """Put a caller in line for a prep ticket. With a ticket free and
+        nobody ahead it leaves at the loop's next turn, alone or with
+        whoever entered in between; else with whoever is in line when
+        its turn comes. Its rows (or the batch's exception) arrive on
+        ``caller.fut``."""
+        self._line.append(caller)
+        self._pump()
 
-    def release_prep(self) -> None:
-        self._prep.release()
+    def leave(self, caller: Caller) -> None:
+        """A caller gave up (cancelled). Still in line: it just goes.
+        Already part of a batch: the shared walk goes on for the others
+        and is cancelled only when nobody is left to serve."""
+        merged = caller.merged
+        if merged is None:
+            self._line.remove(caller)
+        elif all(c.fut.done() for c in merged.callers):
+            merged.task.cancel()
+
+    def release_prep(self, merged: Merged) -> None:
+        """Give the batch's prep ticket back (with its slot, or when the
+        leg dies; once), and let the line move."""
+        if merged.ticket:
+            merged.ticket = False
+            self._prep.release()
+            self._pump()
+
+    def _pump(self) -> None:
+        """Start a batch for the head of the line if a prep ticket is
+        free. It boards at its task's first step and not here, so that
+        whoever enters during that turn of the loop rides along; one
+        batch boards at a time."""
+        if (self._line and self._boarding is None
+                and self._prep.try_acquire()):
+            head = self._line[0]
+            merged = self._boarding = Merged()
+            merged.task = asyncio.get_running_loop().create_task(
+                head.serve(self, merged), context=head.ctx)
+            merged.task.add_done_callback(
+                lambda _t, m=merged: self._retire(m))
+
+    def board(self, merged: Merged) -> List[Caller]:
+        """The first step of a batch's task: the head of the line and,
+        in entry order, every caller behind it that fits: same fan-out
+        caps, no pad size of its own, and ``base_floor`` rows in all —
+        the pad a busy ring gives a one-row batch anyway, so a merged
+        batch runs the programs already compiled. Stops at the first
+        that does not fit: nobody is overtaken."""
+        self._boarding = None
+        line, callers = self._line, merged.callers
+        if line:
+            head = line.popleft()
+            callers.append(head)
+            rows = len(head.queries)
+            while line and head.batch is None:
+                nxt = line[0]
+                rows += len(nxt.queries)
+                if (nxt.batch is not None or nxt.caps != head.caps
+                        or rows > self.base_floor):
+                    break
+                callers.append(line.popleft())
+            for c in callers:
+                c.merged = merged
+            self._pump()        # those that did not fit: the next ticket
+        return callers
+
+    def _retire(self, merged: Merged) -> None:
+        # the batch's task is done, however it ended (a leg that got as
+        # far as its slot gave the ticket back with it; a task cancelled
+        # before its first step never boarded): no ticket and no caller
+        # may hang on it
+        if self._boarding is merged:
+            self._boarding = None
+        self.release_prep(merged)
+        for c in merged.callers:
+            if not c.fut.done():
+                c.fut.cancel()
 
     @property
     def prepping(self) -> int:
         return self._prep.in_flight
+
+    @property
+    def parked(self) -> int:
+        """Callers in line for a prep ticket."""
+        return len(self._line)
 
     async def acquire(self) -> None:
         await super().acquire()

@@ -472,6 +472,15 @@ class BoundedSlots:
         self._inflight += 1
         self.peak_inflight = max(self.peak_inflight, self._inflight)
 
+    def try_acquire(self) -> bool:
+        """Take a slot if one is free, without parking (the caller keeps
+        its own line: the dispatch ring's prep tickets)."""
+        if self._inflight >= self.capacity:
+            return False
+        self._inflight += 1
+        self.peak_inflight = max(self.peak_inflight, self._inflight)
+        return True
+
     def _wake_one(self) -> None:
         while self._waiters:
             fut = self._waiters.popleft()

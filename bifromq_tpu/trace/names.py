@@ -131,8 +131,11 @@ _row("device.tokenize", "span", "models/matcher.py, parallel/sharded.py",
      "the device hash program) + probe upload; feeds the `tokenize` stage",
      stage="tokenize", sync=True)
 _row("device.acquire", "span", "models/matcher.py",
-     "ring admission: prep ticket + slot wait (queue time, not match "
-     "cost; the prep itself is `device.tokenize`)")
+     "once a device batch, from leaving the line at ring admission to "
+     "winning a slot: the prep (`device.tokenize`) and the slot wait")
+_row("match.merged_calls", "counter", "models/matcher.py",
+     "callers served by one device batch: those in line at ring "
+     "admission when it boarded, per `device.dispatch`")
 _row("device.dispatch", "span", "models/matcher.py, parallel/sharded.py",
      "walk enqueue cost, tagged `kernel`; feeds the stage the device "
      "breaker's deadline reads",

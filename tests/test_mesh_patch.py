@@ -146,6 +146,50 @@ class TestMeshChurnAsyncParity:
                        and hasattr(x, "receiver_url")), sh
 
 
+class TestMeshMergeAtAdmission:
+    async def test_two_callers_in_line_take_one_step(self):
+        """The merge at ring admission sits above the prepare / dispatch
+        / ready hooks the mesh overrides: two callers waiting for the
+        ring leave as ONE mesh step, each with exactly its own rows."""
+        from bifromq_tpu.models.pipeline import Merged
+        from bifromq_tpu.obs import OBS
+        async with asyncio.timeout(40):
+            mm, _sc, oracle = seed_matchers(_mesh())
+            await mm.match_batch_async([(TENANTS[0], "a/b")])   # compile
+            ring = mm._pipeline_ring()
+            # keep the ring busy: every slot and every prep ticket held
+            for _ in range(ring.depth):
+                await ring.acquire()
+            tickets = []
+            while ring._prep.try_acquire():
+                tickets.append(Merged())
+            # 9 + 7 rows: together the 16 a merged batch may hold
+            qs = [[(t, topic) for t in TENANTS[:3] for topic in TOPICS[:3]],
+                  [(t, TOPICS[3]) for t in TENANTS[3:]]]
+            tasks = [asyncio.ensure_future(mm.match_batch_async(q))
+                     for q in qs]
+            for _ in range(10):
+                await asyncio.sleep(0)
+            assert ring.parked == 2
+            n0, d0 = OBS.profiler.batches_total, ring.dispatched_total
+            for _ in range(ring.depth):
+                ring.release()
+            for held in tickets:
+                ring.release_prep(held)
+            got = await asyncio.gather(*tasks)
+            assert ring.dispatched_total == d0 + 1
+            recs = OBS.profiler.records()[-(OBS.profiler.batches_total
+                                            - n0):]
+            assert [(r.kernel, r.n_queries) for r in recs] == [("mesh", 16)]
+            for q, rows in zip(qs, got):
+                assert len(rows) == len(q)
+                for (t, topic), g in zip(q, rows):
+                    want = (canon(oracle[t].match(topic.split("/")))
+                            if t in oracle else ([], {}))
+                    assert canon(g) == want, (t, topic)
+            assert ring.in_flight == ring.prepping == ring.parked == 0
+
+
 class TestShardFaultDomains:
     async def test_hung_shard_degrades_only_its_rows(self, monkeypatch):
         """A hang injected on ONE shard's device: the watchdog reclaims

@@ -308,11 +308,11 @@ class TestMatcherAsync:
         # ISSUE 11: the 3 excess callers park behind TWO gates now —
         # prep tickets (depth+1, held for the whole slot tenure) bound
         # uploaded probe batches, so exactly ONE caller preps ahead and
-        # parks at the slot gate; the other 2 wait un-uploaded at the
-        # prep gate
+        # parks at the slot gate; the other 2 wait un-uploaded in the
+        # line for a ticket
         assert m._ring.waiting == 1
         assert m._ring.prepping == 3        # 2 in flight + 1 prep-ahead
-        assert m._ring._prep.waiting == 2
+        assert m._ring.parked == 2
         gate.open = True
         await asyncio.gather(*tasks)
         assert m._ring.in_flight == 0
@@ -365,6 +365,334 @@ class TestMatcherAsync:
         assert _ids(res[0]) == ["r1", "r2"]
         # the sync fallback never touched the ring
         assert matcher._ring is None or matcher._ring.in_flight == 0
+
+
+# ---------------- one device walk for callers in line ----------------------
+
+
+def _fleet(n_tenants: int) -> TpuMatcher:
+    m = TpuMatcher(max_levels=8, k_states=8, auto_compact=False,
+                   match_cache=False)
+    for i in range(n_tenants):
+        m.add_route(f"T{i}", mk_route("a/+", f"r{i}a"))
+        m.add_route(f"T{i}", mk_route(f"a/{i}", f"r{i}b"))
+        m.add_route(f"T{i}", mk_route("x/#", f"r{i}c"))
+    m.refresh()
+    return m
+
+
+class _Hold:
+    """Keeps a ring busy: every slot and every prep ticket taken, so
+    callers that enter wait in line until ``free``."""
+
+    def __init__(self, ring: DispatchRing) -> None:
+        self.ring = ring
+        self.tickets = []
+
+    async def take(self) -> "_Hold":
+        from bifromq_tpu.models.pipeline import Merged
+        for _ in range(self.ring.depth):
+            await self.ring.acquire()
+        while self.ring._prep.try_acquire():
+            self.tickets.append(Merged())
+        return self
+
+    def free(self) -> None:
+        for _ in range(self.ring.depth):
+            self.ring.release()
+        for held in self.tickets:
+            self.ring.release_prep(held)
+
+
+async def _turns(n: int = 10) -> None:
+    for _ in range(n):
+        await asyncio.sleep(0)
+
+
+def _new_records(n0: int):
+    from bifromq_tpu.obs import OBS
+    n = OBS.profiler.batches_total - n0
+    return OBS.profiler.records()[-n:] if n else []
+
+
+def _batches_total() -> int:
+    from bifromq_tpu.obs import OBS
+    return OBS.profiler.batches_total
+
+
+def _exact(m: TpuMatcher, queries, rows) -> bool:
+    want = m.match_from_tries(queries)
+    return len(rows) == len(want) and all(
+        _ids(a) == _ids(b) for a, b in zip(rows, want))
+
+
+@pytest.fixture
+def injector():
+    from bifromq_tpu.resilience.faults import get_injector
+    get_injector().reset(seed=7)
+    yield get_injector()
+    get_injector().reset()
+
+
+class TestMergeAtAdmission:
+    async def test_callers_in_line_share_one_batch(self):
+        async with asyncio.timeout(30):
+            m = _fleet(6)
+            ring = m._pipeline_ring()
+            ring.depth = 1
+            hold = await _Hold(ring).take()
+            # N callers of N tenants, one or two rows each
+            qs = [[(f"T{i}", ["a", str(i)])] + ([(f"T{i}", ["x", "y"])]
+                                               if i % 2 else [])
+                  for i in range(6)]
+            tasks = [asyncio.ensure_future(m.match_batch_async(q))
+                     for q in qs]
+            await _turns()
+            assert ring.parked == 6 and ring.in_flight == 1
+            n0 = _batches_total()
+            hold.free()
+            got = await asyncio.gather(*tasks)
+            recs = _new_records(n0)
+            assert [r.n_queries for r in recs] == [sum(map(len, qs))]
+            assert recs[0].kernel != "oracle" and recs[0].batch == 16
+            for q, rows in zip(qs, got):    # its own rows, nobody else's
+                assert _exact(m, q, rows)
+            assert ring.in_flight == ring.prepping == ring.parked == 0
+
+    async def test_lone_caller_on_idle_ring_leaves_at_once(self):
+        async with asyncio.timeout(30):
+            m = _fleet(1)
+            gate = _Gate()
+            gate.open = True        # the walk reads ready at first poll
+            _gate_matcher(m, gate)
+            await m.match_batch_async([("T0", ["a", "0"])])     # compile
+            loop = asyncio.get_running_loop()
+            timers = []
+            real_call_at = loop.call_at     # call_later goes through it
+
+            def call_at(when, *a, **kw):
+                timers.append(when)
+                return real_call_at(when, *a, **kw)
+            loop.call_at = call_at
+            n0 = _batches_total()
+            try:
+                stats = {}
+                rows = await m.match_batch_async([("T0", ["a", "q"])],
+                                                 stats=stats)
+            finally:
+                del loop.call_at
+            assert timers == []     # no timed wait was ever scheduled
+            assert _ids(rows[0]) == ["r0a"]
+            recs = _new_records(n0)
+            assert [(r.n_queries, r.batch) for r in recs] == [(1, 8)]
+            assert stats["batch_share"] == 1.0
+
+    async def test_forty_in_line_leave_as_16_16_8_in_entry_order(self):
+        async with asyncio.timeout(30):
+            m = _fleet(4)
+            ring = m._pipeline_ring()
+            ring.depth = 1
+            hold = await _Hold(ring).take()
+            qs = [[(f"T{i % 4}", ["a", str(i)])] for i in range(40)]
+            done = []
+            tasks = []
+            for i, q in enumerate(qs):
+                t = asyncio.ensure_future(m.match_batch_async(q))
+                t.add_done_callback(lambda _t, i=i: done.append(i))
+                tasks.append(t)
+            await _turns()
+            assert ring.parked == 40
+            n0 = _batches_total()
+            hold.free()
+            got = await asyncio.gather(*tasks)
+            assert [r.n_queries for r in _new_records(n0)] == [16, 16, 8]
+            assert done == list(range(40))
+            for q, rows in zip(qs, got):
+                assert _exact(m, q, rows)
+
+    async def test_stall_at_the_ring_is_late_not_degraded(self):
+        """Time in line starts no deadline: callers whose match deadline
+        runs out WHILE they wait for the ring are served by the device,
+        as in the parent (the check is on entry, in the worker)."""
+        from bifromq_tpu.dist.worker import DistWorker
+        from bifromq_tpu.resilience.policy import deadline_scope
+        from bifromq_tpu.utils.metrics import FABRIC, FabricMetric
+        async with asyncio.timeout(40):
+            w = DistWorker()
+            await w.start()
+            try:
+                for i in range(4):
+                    await w.add_route(f"T{i}", mk_route("s/+", f"r{i}"))
+                kw = dict(max_persistent_fanout=100, max_group_fanout=100)
+                await w.match_batch([("T0", "s/warm")], **kw)   # compile
+                ring = w.matcher._pipeline_ring()
+                hold = await _Hold(ring).take()
+                base = (FABRIC.get(FabricMetric.MATCH_DEGRADED),
+                        FABRIC.get(FabricMetric.DEVICE_TIMEOUT))
+                n0 = _batches_total()
+
+                async def one(i):
+                    with deadline_scope(0.05):
+                        return await w.match_batch([(f"T{i}", f"s/{i}")],
+                                                   **kw)
+                tasks = [asyncio.ensure_future(one(i)) for i in range(4)]
+                await asyncio.sleep(0.3)
+                assert ring.parked == 4
+                hold.free()
+                got = await asyncio.gather(*tasks)
+                for i, rows in enumerate(got):
+                    assert _ids(rows[0]) == [f"r{i}"]
+                assert (FABRIC.get(FabricMetric.MATCH_DEGRADED),
+                        FABRIC.get(FabricMetric.DEVICE_TIMEOUT)) == base
+                recs = _new_records(n0)
+                # four rows, and still the throughput pad: callers that
+                # share a batch are concurrency, however idle the ring
+                assert [(r.n_queries, r.batch) for r in recs] == [(4, 16)]
+                assert recs[0].kernel != "oracle"
+            finally:
+                await w.stop()
+
+    async def test_fault_on_merged_batch_is_one_fault(self, injector):
+        from bifromq_tpu.resilience.breaker import CircuitBreaker
+        from bifromq_tpu.utils.metrics import FABRIC, FabricMetric
+        async with asyncio.timeout(30):
+            m = _fleet(5)
+            clock = [0.0]
+            br = m.device_breaker = CircuitBreaker(
+                failure_threshold=3, recovery_time=5.0,
+                clock=lambda: clock[0])
+            seen = {"failures": 0, "admits": []}
+            real_fail, real_admit = br.record_failure, br.admit
+
+            def record_failure(*a, **kw):
+                seen["failures"] += 1
+                return real_fail(*a, **kw)
+
+            def admit():
+                seen["admits"].append(real_admit())
+                return seen["admits"][-1]
+            br.record_failure, br.admit = record_failure, admit
+            ring = m._pipeline_ring()
+            ring.depth = 1
+
+            async def merged_round(n):
+                hold = await _Hold(ring).take()
+                qs = [[(f"T{i}", ["a", str(i)])] for i in range(n)]
+                stats = [{} for _ in qs]
+                tasks = [asyncio.ensure_future(
+                    m.match_batch_async(q, stats=st))
+                    for q, st in zip(qs, stats)]
+                await _turns()
+                assert ring.parked == n
+                n0, d0 = _batches_total(), ring.dispatched_total
+                hold.free()
+                got = await asyncio.gather(*tasks)
+                for q, rows in zip(qs, got):
+                    assert _exact(m, q, rows)
+                return (stats, _new_records(n0),
+                        ring.dispatched_total - d0)
+
+            # a device error: every caller served by the oracle, the rows
+            # counted once, the breaker fed once
+            injector.add_rule(service="tpu-device", method="dispatch",
+                              action="error", max_hits=1)
+            base = FABRIC.get(FabricMetric.MATCH_DEGRADED)
+            stats, recs, dispatched = await merged_round(5)
+            assert [st["degraded"] for st in stats] == ["device_error"] * 5
+            assert FABRIC.get(FabricMetric.MATCH_DEGRADED) == base + 5
+            assert seen == {"failures": 1, "admits": ["ok"]}
+            assert dispatched == 1
+            assert [(r.kernel, r.n_queries) for r in recs] == [("oracle", 5)]
+            # half-open: the merged batch is ONE canary
+            br.force_open()
+            clock[0] = 6.0
+            seen["admits"].clear()
+            stats, recs, dispatched = await merged_round(4)
+            assert seen["admits"] == ["canary"] and dispatched == 1
+            assert br.state == "closed"
+            assert all("degraded" not in st for st in stats)
+            assert [r.n_queries for r in recs] == [4]
+            assert FABRIC.get(FabricMetric.MATCH_DEGRADED) == base + 5
+
+    async def test_cancelled_callers_harm_nobody(self):
+        async with asyncio.timeout(30):
+            m = _fleet(4)
+            gate = _Gate()
+            _gate_matcher(m, gate)
+            ring = m._pipeline_ring()
+            ring.depth = 1
+            hold = await _Hold(ring).take()
+            qs = [[(f"T{i}", ["a", str(i)])] for i in range(4)]
+            tasks = [asyncio.ensure_future(m.match_batch_async(q))
+                     for q in qs]
+            await _turns()
+            assert ring.parked == 4
+            tasks[1].cancel()               # cancelled while in line
+            await _turns()
+            assert ring.parked == 3
+            n0 = _batches_total()
+            hold.free()
+            await _turns()
+            assert ring.in_flight == 1 and ring.parked == 0
+            tasks[0].cancel()               # cancelled after dispatch
+            await _turns()
+            assert ring.in_flight == 1      # the shared walk goes on
+            gate.open = True
+            r2, r3 = await asyncio.gather(tasks[2], tasks[3])
+            assert _exact(m, qs[2], r2) and _exact(m, qs[3], r3)
+            assert [r.n_queries for r in _new_records(n0)] == [3]
+            assert tasks[0].cancelled() and tasks[1].cancelled()
+            await _turns()
+            assert ring.in_flight == ring.prepping == ring.parked == 0
+            assert len(ring.quarantine) == 0
+            # nobody left to serve: the walk is given up, its arrays
+            # quarantined like any cancelled in-flight batch
+            gate.open = False
+            last = asyncio.ensure_future(m.match_batch_async(qs[0]))
+            await _turns()
+            assert ring.in_flight == 1
+            last.cancel()
+            await _turns()
+            assert last.cancelled()
+            assert ring.in_flight == ring.prepping == ring.parked == 0
+            assert len(ring.quarantine) == 1
+            gate.open = True
+            assert _exact(m, qs[3], await m.match_batch_async(qs[3]))
+
+    async def test_shares_of_one_batch_sum_to_one(self):
+        from bifromq_tpu.dist.worker import DistWorker
+        from bifromq_tpu.utils.metrics import STAGES
+        async with asyncio.timeout(40):
+            w = DistWorker()
+            await w.start()
+            try:
+                for i in range(3):
+                    await w.add_route(f"T{i}", mk_route("s/+", f"r{i}"))
+                kw = dict(max_persistent_fanout=100, max_group_fanout=100)
+                await w.match_batch([("T0", "s/warm")], **kw)   # compile
+                shares = []
+                real = DistWorker._tenant_shares
+
+                def tenant_shares(sub, of_batch=1.0):
+                    shares.append(real(sub, of_batch))
+                    return shares[-1]
+                w._tenant_shares = tenant_shares
+                hold = await _Hold(w.matcher._pipeline_ring()).take()
+                # 1 + 2 + 1 rows of three tenants, T0 twice
+                subs = [[("T0", "s/a")], [("T1", "s/b"), ("T1", "s/c")],
+                        [("T0", "s/d")]]
+                count0 = STAGES.snapshot().get("device", {}).get("count", 0)
+                n0 = _batches_total()
+                tasks = [asyncio.ensure_future(w.match_batch(q, **kw))
+                         for q in subs]
+                await _turns()
+                hold.free()
+                await asyncio.gather(*tasks)
+                assert [r.n_queries for r in _new_records(n0)] == [4]
+                assert shares == [{"T0": 0.25}, {"T1": 0.5}, {"T0": 0.25}]
+                assert STAGES.snapshot()["device"]["count"] == count0 + 3
+            finally:
+                await w.stop()
 
 
 class TestDonationSafety:
