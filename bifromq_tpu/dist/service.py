@@ -414,6 +414,8 @@ class DistService:
                     if matched[qi] is None:
                         matched[qi] = fresh[miss_pos[c.topic]]
             results: List[PubResult] = []
+            trace.count("match.no_route", sum(
+                1 for m in matched if not m.normal and not m.groups))
             for call, m in zip(calls, matched):
                 fanout = await self._fan_out(tenant_id, call, m)
                 results.append(PubResult(ok=True, fanout=fanout))

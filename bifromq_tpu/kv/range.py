@@ -157,6 +157,7 @@ class ReplicatedKVRange:
             apply_cb=self._apply,
             snapshot_cb=self._snapshot,
             restore_cb=self._restore,
+            state_len_cb=space.__len__,
             store=raft_store,
             initial_applied=applied)
 

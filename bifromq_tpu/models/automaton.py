@@ -57,6 +57,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple, Union
 
 import numpy as np
 
+from .. import trace
 from ..types import RouteMatcherType
 from ..utils import topic as topic_util
 from ..utils.env import env_bool, env_float, env_int
@@ -701,6 +702,9 @@ class PatchableTrie(CompiledTrie):
         edges = np.fromiter(sorted(self._dirty_edges), dtype=np.int64,
                             count=len(self._dirty_edges))
         ops = self._pending_ops
+        # a 0 beside every flush keeps the name in the window totals:
+        # "no regrow" is then a reading, not an absence
+        trace.count("patch.regrow", 0)
         self._full = set()
         self._dirty_nodes = set()
         self._dirty_edges = set()
@@ -938,6 +942,7 @@ class PatchableTrie(CompiledTrie):
         self.edge_tab = _build_edge_table(
             live, self.probe_len, min_cap=2 * self.edge_tab.shape[0])
         self.edge_regrows += 1
+        trace.count("patch.regrow")
         self._full.add("edge")
         self._dirty_edges.clear()
 
@@ -966,6 +971,7 @@ class PatchableTrie(CompiledTrie):
         par[:cap] = self.parent
         self.parent = par
         self.node_grows += 1
+        trace.count("patch.regrow")
         self._full.add("node")
         self._dirty_nodes.clear()
 

@@ -45,6 +45,7 @@ from __future__ import annotations
 import time
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
+from .. import trace
 from ..utils import topic as topic_util
 
 # invalidation token: (generation, tenant epoch, tenant mutation seq)
@@ -254,6 +255,7 @@ class TenantMatchCache:
                     joined.encode("utf-8")):
             if s.entries.pop(key, None) is not None:
                 n += 1
+        trace.count("match.cache.evict_exact", n)
         if n:
             self._total -= n
             self._count_evictions(n)

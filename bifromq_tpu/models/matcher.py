@@ -926,6 +926,8 @@ class TpuMatcher:
         MATCH_CACHE.inc(self.match_cache.scope, "hits",
                         n_queries - len(miss_rows))
         MATCH_CACHE.inc(self.match_cache.scope, "misses", len(miss_rows))
+        trace.count("match.cache.lookups", n_queries)
+        trace.count("match.cache.hits", n_queries - len(miss_rows))
         if uniq_queries:
             MATCH_CACHE.record_dedup(len(uniq_queries),
                                      len(miss_rows) - len(uniq_queries))

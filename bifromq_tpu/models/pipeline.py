@@ -345,15 +345,22 @@ class DispatchRing(BoundedSlots):
         :class:`DeviceTimeoutError` fires so one hung dispatch cannot
         wedge a ring slot forever. The deadline check is one monotonic
         read per poll — the sub-ms spin phase stays spin (no timed sleep
-        is ever added to it). ``fault`` is a fired device FaultRule
-        (models/matcher threads it from the dispatch hook): ``hang``
+        is ever added to it). The deadline runs on OBSERVED time: a gap
+        between two polls counts for at most a quarter of it, so a host
+        that was frozen (the serving thread blocked, the machine stolen)
+        does not charge its own absence to the device — the walk gets
+        its polls after the thaw, and a device that hangs under a live
+        loop still times out at ``deadline_s``. ``fault`` is a fired
+        device FaultRule (models/matcher threads it from the dispatch
+        hook): ``hang``
         withholds readiness while the rule stays installed, ``slow``
         withholds it for the rule's delay, ``flaky_ready`` makes each
         poll lie with the rule's probability.
         """
         if deadline_s is None:
             deadline_s = device_deadline_s()
-        t0 = time.monotonic()
+        t0 = last = time.monotonic()
+        observed = 0.0
         ready = getattr(res, "ready_leaves", None)
         leaves = list(ready()) if ready is not None \
             else [res.start, res.count, res.overflow]
@@ -384,9 +391,12 @@ class DispatchRing(BoundedSlots):
                             return
                     except AttributeError:
                         return
-                if (deadline_s is not None
-                        and time.monotonic() - t0 >= deadline_s):
-                    raise DeviceTimeoutError(deadline_s)
+                if deadline_s is not None:
+                    now = time.monotonic()
+                    observed += min(now - last, deadline_s / 4)
+                    last = now
+                    if observed >= deadline_s:
+                        raise DeviceTimeoutError(deadline_s)
                 await asyncio.sleep(0 if polls < spin_polls else poll_s)
                 polls += 1
         finally:

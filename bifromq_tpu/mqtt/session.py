@@ -1050,27 +1050,30 @@ class Session:
             await self.conn.protocol_error(
                 "too many filters", ReasonCode.QUOTA_EXCEEDED)
             return
-        codes: List[int] = []
-        for tf in u.topic_filters:
-            # unsub permission check (≈ MQTTSessionHandler checkAndUnsub →
-            # UnsubActionDisallow event)
-            if not await self._check_permission(MQTTAction.UNSUB, tf):
-                self.events.report(Event(
-                    EventType.UNSUB_ACTION_DISALLOW,
-                    self.client_info.tenant_id, {"filter": tf}))
-                codes.append(ReasonCode.NOT_AUTHORIZED if v5 else 0x80)
-                continue
-            sub = self.subscriptions.pop(tf, None)
-            if sub is None:
-                codes.append(ReasonCode.NO_SUBSCRIPTION_EXISTED if v5 else 0)
-                continue
-            await self._unroute(sub)
-            codes.append(ReasonCode.SUCCESS)
-        await self.conn.send(pk.UnsubAck(packet_id=u.packet_id,
-                                         reason_codes=codes))
+        with trace.span("unsub.route", tenant=self.client_info.tenant_id,
+                        filters=len(u.topic_filters)):
+            codes: List[int] = []
+            for tf in u.topic_filters:
+                codes.append(await self._unsubscribe_one(tf, v5))
+            await self.conn.send(pk.UnsubAck(packet_id=u.packet_id,
+                                             reason_codes=codes))
         self.events.report(Event(EventType.UNSUB_ACKED,
                                  self.client_info.tenant_id,
                                  {"filters": u.topic_filters}))
+
+    async def _unsubscribe_one(self, tf: str, v5: bool) -> int:
+        # unsub permission check (≈ MQTTSessionHandler checkAndUnsub →
+        # UnsubActionDisallow event)
+        if not await self._check_permission(MQTTAction.UNSUB, tf):
+            self.events.report(Event(
+                EventType.UNSUB_ACTION_DISALLOW,
+                self.client_info.tenant_id, {"filter": tf}))
+            return ReasonCode.NOT_AUTHORIZED if v5 else 0x80
+        sub = self.subscriptions.pop(tf, None)
+        if sub is None:
+            return ReasonCode.NO_SUBSCRIPTION_EXISTED if v5 else 0
+        await self._unroute(sub)
+        return ReasonCode.SUCCESS
 
     async def _route(self, sub: Subscription) -> None:
         """Register the dist route for a new subscription (a consensus write

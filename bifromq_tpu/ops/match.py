@@ -710,8 +710,15 @@ def walk_routes_donated(trie, probes, **kw):
 # compaction swap); with ``donate=True`` XLA aliases the update in place
 # (O(rows) device work, no table copy), which is only legal when the
 # caller proves no in-flight batch references the old tables.
+#
+# Every scatter carries exactly ``_PATCH_CHUNK`` rows: a table has ONE
+# scatter shape (per donation variant), compiled by the install-time warm,
+# so no flush — however many mutations it coalesces — traces a new program
+# on the serving path. One exact-filter op dirties 4 node rows and 3 edge
+# buckets; a pow2-snapped vector met a new class (and a 0.1-0.4 s compile
+# per table) the first time three of them shared a flush.
 
-_PATCH_PAD_FLOOR = 8
+_PATCH_CHUNK = 64
 
 
 @jax.jit
@@ -726,16 +733,16 @@ def _scatter_rows_donated(tab, idx, vals):
         return tab.at[idx].set(vals)
 
 
-def _pad_patch_idx(idx: np.ndarray) -> np.ndarray:
-    """pow2-snap a dirty-row index vector (every distinct scatter shape
-    costs an XLA trace) by repeating the last index — duplicate indices
-    write identical values, so the result is deterministic."""
-    from ..models.automaton import _next_pow2
-    p = _next_pow2(idx.shape[0], floor=_PATCH_PAD_FLOOR)
-    if p == idx.shape[0]:
-        return idx
-    return np.concatenate(
-        [idx, np.full(p - idx.shape[0], idx[-1], idx.dtype)])
+def _patch_chunks(idx: np.ndarray):
+    """A dirty-row index vector in pieces of exactly ``_PATCH_CHUNK``
+    rows, the last one filled by repeating its last index — duplicate
+    indices write identical values, so the result is deterministic."""
+    for lo in range(0, idx.shape[0], _PATCH_CHUNK):
+        part = idx[lo:lo + _PATCH_CHUNK]
+        if part.shape[0] < _PATCH_CHUNK:
+            part = np.concatenate([part, np.full(
+                _PATCH_CHUNK - part.shape[0], part[-1], part.dtype)])
+        yield part
 
 
 def patch_device_trie(dev: DeviceTrie, pt, *, device=None,
@@ -780,17 +787,17 @@ def _patch_device_trie(dev, pt, full, node_rows, edge_rows, ops, *,
         # into the jit'd scatter was an IMPLICIT h2d transfer per flush —
         # legal but invisible; the transfer-guard sanitizer now proves
         # the steady-churn path makes only declared transfers
-        idx_np = _pad_patch_idx(node_rows.astype(np.int32))
-        rows_np = pt.node_tab[idx_np]
-        idx = put(idx_np)
-        node_tab = scatter(node_tab, idx, put(rows_np))
-        count_tab = scatter(count_tab, idx,
-                            put(count_cols_from_node_tab(rows_np)))
-        route_tab = scatter(route_tab, idx,
-                            put(route_cols_from_node_tab(rows_np)))
+        for idx_np in _patch_chunks(node_rows.astype(np.int32)):
+            rows_np = pt.node_tab[idx_np]
+            idx = put(idx_np)
+            node_tab = scatter(node_tab, idx, put(rows_np))
+            count_tab = scatter(count_tab, idx,
+                                put(count_cols_from_node_tab(rows_np)))
+            route_tab = scatter(route_tab, idx,
+                                put(route_cols_from_node_tab(rows_np)))
+            stats["bytes"] += int(idx_np.nbytes) * 3 + int(rows_np.nbytes) \
+                + idx_np.shape[0] * (CT_COLS + RT_COLS) * 4
         stats["rows"] += int(node_rows.size)
-        stats["bytes"] += int(idx_np.nbytes) * 3 + int(rows_np.nbytes) \
-            + idx_np.shape[0] * (CT_COLS + RT_COLS) * 4
     if "edge" in full:
         stats["reshaped"] |= tuple(pt.edge_tab.shape) \
             != tuple(dev.edge_tab.shape)
@@ -798,11 +805,11 @@ def _patch_device_trie(dev, pt, full, node_rows, edge_rows, ops, *,
         stats["rows"] += int(pt.edge_tab.shape[0])
         stats["bytes"] += int(pt.edge_tab.nbytes)
     elif edge_rows.size:
-        idx_np = _pad_patch_idx(edge_rows.astype(np.int32))
-        rows_np = pt.edge_tab[idx_np]
-        edge_tab = scatter(edge_tab, put(idx_np), put(rows_np))
+        for idx_np in _patch_chunks(edge_rows.astype(np.int32)):
+            rows_np = pt.edge_tab[idx_np]
+            edge_tab = scatter(edge_tab, put(idx_np), put(rows_np))
+            stats["bytes"] += int(idx_np.nbytes) + int(rows_np.nbytes)
         stats["rows"] += int(edge_rows.size)
-        stats["bytes"] += int(idx_np.nbytes) + int(rows_np.nbytes)
     return DeviceTrie(node_tab=node_tab, edge_tab=edge_tab,
                       child_list=dev.child_list, count_tab=count_tab,
                       route_tab=route_tab), stats
@@ -842,10 +849,10 @@ def warm_patch_scatter(shapes: tuple, *, device=None,
     ROADMAP PR 9 follow-up (c)).
 
     The first churn flush otherwise pays a ~100ms one-off XLA trace per
-    (table shape, idx-pad) class — on the serving path, inside
+    table shape — on the serving path, inside
     ``_dispatch_device``. ``shapes`` is ``scatter_warm_shapes(dev)``;
-    warming compiles the ``_PATCH_PAD_FLOOR``-row scatter (the
-    steady-churn shape; bigger dirty sets re-trace pow2-amortized) per
+    warming compiles the ``_PATCH_CHUNK``-row scatter (the only shape a
+    flush uses, see ``_patch_chunks``) per
     class, functional AND donated variants — both against throwaway
     device zeros tables (the jit cache keys on avals, not identity, and
     a live table captured across the warm delay could already be
@@ -862,12 +869,12 @@ def warm_patch_scatter(shapes: tuple, *, device=None,
         if key in _WARMED_SCATTER_KEYS:
             return
         _WARMED_SCATTER_KEYS.add(key)
-    idx = jax.device_put(np.zeros(_PATCH_PAD_FLOOR, np.int32),
+    idx = jax.device_put(np.zeros(_PATCH_CHUNK, np.int32),
                          device=device)
     for shape, dtype in shapes:
         try:
             rows = jax.device_put(
-                np.zeros((_PATCH_PAD_FLOOR,) + tuple(shape[1:]), dtype),
+                np.zeros((_PATCH_CHUNK,) + tuple(shape[1:]), dtype),
                 device=device)
             dummy = jax.device_put(jnp.zeros(shape, dtype),
                                    device=device)

@@ -359,7 +359,7 @@ def patch_retained_tables(dev: RetainedDeviceTables, rt, *, device=None,
 
 def _patch_retained(dev, rt, full, node_rows, edge_rows, ext_rows,
                     child_idx, extra_idx, ops, *, device, donate):
-    from .match import _pad_patch_idx, _scatter_rows, _scatter_rows_donated
+    from .match import _patch_chunks, _scatter_rows, _scatter_rows_donated
     put = functools.partial(jax.device_put, device=device)
     scatter = _scatter_rows_donated if donate else _scatter_rows
     stats = {"rows": 0, "bytes": 0, "ops": ops, "reshaped": False,
@@ -373,11 +373,11 @@ def _patch_retained(dev, rt, full, node_rows, edge_rows, ext_rows,
             stats["bytes"] += int(host.nbytes)
             return put(host)
         if rows.size:
-            idx_np = _pad_patch_idx(rows.astype(np.int32))
-            vals_np = host[idx_np]
             stats["rows"] += int(rows.size)
-            stats["bytes"] += int(idx_np.nbytes) + int(vals_np.nbytes)
-            return scatter(dev_tab, put(idx_np), put(vals_np))
+            for idx_np in _patch_chunks(rows.astype(np.int32)):
+                vals_np = host[idx_np]
+                stats["bytes"] += int(idx_np.nbytes) + int(vals_np.nbytes)
+                dev_tab = scatter(dev_tab, put(idx_np), put(vals_np))
         return dev_tab
 
     node_tab = _table("node", rt.node_tab, dev.node_tab, node_rows)

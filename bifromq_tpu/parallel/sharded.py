@@ -63,7 +63,7 @@ from ..models.matcher import TpuMatcher, _HostPairs, _parse_levels, \
 from ..models.oracle import UNCAPPED_FANOUT, MatchedRoutes, SubscriptionTrie
 from ..ops.match import (
     RT_COLS, DeviceTrie, Probes, _bucket_pairs, _expand_pairs,
-    _pad_patch_idx, _route_walk, device_expand_enabled, expand_cap_lanes,
+    _patch_chunks, _route_walk, device_expand_enabled, expand_cap_lanes,
     expand_intervals, route_cols_from_node_tab,
 )
 from ..obs import OBS
@@ -1024,13 +1024,14 @@ class MeshMatcher(TpuMatcher):
                         bytes_total += int(rows.nbytes)
                         full_tags.add(f"s{sh}:node")
                     elif nodes.size:
-                        idx_np = _pad_patch_idx(nodes.astype(np.int32))
-                        rows_np = route_cols_from_node_tab(
-                            pt.node_tab[idx_np])
-                        dev_route = scatter(dev_route, put(idx_np),
-                                            put(rows_np), shard=sh)
+                        for idx_np in _patch_chunks(nodes.astype(np.int32)):
+                            rows_np = route_cols_from_node_tab(
+                                pt.node_tab[idx_np])
+                            dev_route = scatter(dev_route, put(idx_np),
+                                                put(rows_np), shard=sh)
+                            bytes_total += int(idx_np.nbytes
+                                               + rows_np.nbytes)
                         rows_total += int(nodes.size)
-                        bytes_total += int(idx_np.nbytes + rows_np.nbytes)
                     if "edge" in full:
                         dev_edge = slice_set(dev_edge, put(pt.edge_tab),
                                              shard=sh)
@@ -1038,12 +1039,13 @@ class MeshMatcher(TpuMatcher):
                         bytes_total += int(pt.edge_tab.nbytes)
                         full_tags.add(f"s{sh}:edge")
                     elif edges.size:
-                        idx_np = _pad_patch_idx(edges.astype(np.int32))
-                        rows_np = pt.edge_tab[idx_np]
-                        dev_edge = scatter(dev_edge, put(idx_np),
-                                           put(rows_np), shard=sh)
+                        for idx_np in _patch_chunks(edges.astype(np.int32)):
+                            rows_np = pt.edge_tab[idx_np]
+                            dev_edge = scatter(dev_edge, put(idx_np),
+                                               put(rows_np), shard=sh)
+                            bytes_total += int(idx_np.nbytes
+                                               + rows_np.nbytes)
                         rows_total += int(edges.size)
-                        bytes_total += int(idx_np.nbytes + rows_np.nbytes)
         except BaseException:
             # a flush that dies mid-update must not lose the drained row
             # ids (donation may even have consumed a table): mark every

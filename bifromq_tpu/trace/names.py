@@ -62,6 +62,9 @@ _row("pub.ack", "span", "mqtt/session.py",
 _row("sub.route", "span", "mqtt/session.py",
      "SUBSCRIBE parsed -> SUBACK queued: permission checks, the route's "
      "consensus write, retained replay")
+_row("unsub.route", "span", "mqtt/session.py",
+     "UNSUBSCRIBE parsed -> UNSUBACK queued: permission checks and the "
+     "route's consensus delete (`sub.route` covers SUBSCRIBE alone)")
 _row("sub.dist", "span", "dist/service.py",
      "one route add through the dist service: worker mutation + match-"
      "cache invalidation")
@@ -78,6 +81,9 @@ _row("batch.queue_wait", "span", "scheduler/batcher.py",
 _row("batch.emit", "span", "scheduler/batcher.py",
      "a batch that holds several sampled callers: parented under the "
      "first, linking the others (bounded at 16)")
+_row("match.no_route", "counter", "dist/service.py",
+     "publishes whose match came back empty: acknowledged, delivered to "
+     "nobody")
 _row("deliver.fanout", "span", "dist/service.py",
      "one publish's fan-out to its sub-brokers, tagged with the achieved "
      "count; feeds the `deliver` stage and the tenant's window",
@@ -117,15 +123,29 @@ _row("raft.apply", "span", "kv/range.py",
      "one committed entry applied to the range: KV batch or coproc "
      "mutation (route add/remove patches the matcher here)", sync=True)
 _row("kv.resort", "span", "kv/engine.py",
-     "the in-memory KV's ordered key list extended and re-sorted after "
-     "puts (a 1M-key sort per live SUBSCRIBE)", sync=True)
+     "the in-memory KV's pending puts merged into its ordered key list "
+     "by the next ordered read or delete: placed key by key when few, "
+     "appended and re-sorted when many", sync=True)
 _row("patch.host", "span", "models/matcher.py",
      "one route op folded into the host arenas (`patch_host_s`)",
      sync=True)
 _row("patch.flush", "span", "models/matcher.py",
      "accumulated host patches shipped to the device as row scatters "
      "(`patch_device_s`)", sync=True)
+_row("patch.regrow", "counter", "models/automaton.py",
+     "node-arena doublings and edge-table regrows of a patched base: each "
+     "re-ships a whole table and re-traces the walk")
 # ---- matcher ---------------------------------------------------------------
+_row("match.cache.lookups", "counter", "models/matcher.py",
+     "rows probed in the matcher's result cache (`models/matchcache.py`), "
+     "once a match call")
+_row("match.cache.hits", "counter", "models/matcher.py",
+     "of those, the rows the cache answered: they reach neither tokenizer "
+     "nor device")
+_row("match.cache.evict_exact", "counter", "models/matchcache.py",
+     "topic keys evicted one at a time by an exact filter's route "
+     "mutation (either cache scope); a wildcard filter bumps the tenant's "
+     "epoch instead")
 _row("device.tokenize", "span", "models/matcher.py, parallel/sharded.py",
      "stage-1 byte-plane prep: TopicBytes pack + level hashing (host or "
      "the device hash program) + probe upload; feeds the `tokenize` stage",

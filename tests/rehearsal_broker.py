@@ -20,14 +20,19 @@ def _harness():
     return sut, traffic
 
 
+def rehearsal_config(name: str = "rehearsal_20k", **changes) -> dict:
+    """A configuration file of the benchmark, with keys replaced."""
+    with open(os.path.join(BENCH, "configs", name + ".json")) as f:
+        return dict(json.load(f), **changes)
+
+
 @contextlib.asynccontextmanager
-async def rehearsal_broker():
+async def rehearsal_broker(cfg: dict = None):
     """Yields ``(node, matcher, tenant, topics)``: ``topics`` are publish
     topics of the configuration's own population that match rows."""
     sut, traffic = _harness()
     from bifromq_tpu.starter import Standalone
-    with open(os.path.join(BENCH, "configs", "rehearsal_20k.json")) as f:
-        cfg = json.load(f)
+    cfg = cfg or rehearsal_config()
     gen = traffic.generator_of(cfg)
     rows = list(gen.subscriptions(cfg))
     tries, _n = sut.build_tries(rows)

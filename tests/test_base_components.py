@@ -465,6 +465,29 @@ class TestSortedBytesMap:
             ref.items(), reverse=True)
         assert len(m) == len(ref)
 
+    @pytest.mark.parametrize("tail", [1, 64, 65, 300])
+    def test_short_and_long_tails_merge_alike(self, tail):
+        """A pending tail is placed key by key up to ``INSORT_MAX`` and
+        re-sorted with the list beyond it (a live UNSUBSCRIBE at 1M keys is
+        the first, a bulk load the second): same order either way."""
+        import random
+        from bifromq_tpu.kv.engine import _SortedBytesMap
+        assert _SortedBytesMap.INSORT_MAX == 64
+        rng = random.Random(tail)
+        m = _SortedBytesMap()
+        base = [b"%08d" % rng.randrange(10**8) for _ in range(2000)]
+        for k in base:
+            m.put(k, b"b")
+        m.delete(base[0])                       # merges the bulk tail
+        new = [b"%08d" % rng.randrange(10**8) for _ in range(tail)]
+        for k in new:
+            m.put(k, b"n")
+        assert len(m._pending) == len(set(new) - set(base[1:]))
+        m.delete(base[1])                       # merges this tail
+        want = sorted((set(base) | set(new)) - {base[0], base[1]})
+        assert m._keys == want and not m._pending
+        assert [k for k, _ in m.scan(None, None)] == want
+
     def test_bulk_puts_defer_the_sort_and_copy_sees_them(self):
         from bifromq_tpu.kv.engine import _SortedBytesMap
         m = _SortedBytesMap()

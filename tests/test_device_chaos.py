@@ -202,6 +202,54 @@ class TestWatchdog:
         gate.open = True
         await asyncio.wait_for(task, 2)
 
+    @pytest.mark.parametrize("frozen_s, expect_timeout", [
+        (10.0, False),   # the host froze past the deadline: not the device's fault
+        (0.0, True),     # a live loop and a device that never answers
+    ])
+    async def test_wait_ready_deadline_runs_on_observed_time(
+            self, monkeypatch, frozen_s, expect_timeout):
+        """PR 32: a 4 s stall of the serving thread read ``device_timeout``
+        6 on the chip. The first poll after a freeze finds the walk
+        unfinished (the runtime thaws with the loop); it must get its
+        polls, and a hang under a live loop must still time out."""
+        from bifromq_tpu.models import pipeline
+        now = [100.0]
+
+        class Clock:
+            monotonic = staticmethod(lambda: now[0])
+        monkeypatch.setattr(pipeline, "time", Clock)
+        gate = _Gate()
+        polls = [0]
+
+        class Leaf(_GatedLeaf):
+            def is_ready(self):
+                polls[0] += 1
+                if polls[0] == 1:
+                    now[0] += frozen_s       # the freeze, between two polls
+                elif frozen_s and polls[0] == 3:
+                    gate.open = True         # ready two polls after the thaw
+                else:
+                    now[0] += 0.01
+                return gate.open
+        leaf = Leaf(np.zeros(1), gate)
+
+        class R:
+            start = count = overflow = None
+
+            @staticmethod
+            def ready_leaves():
+                return [leaf]
+        wait = DispatchRing.wait_ready(R(), deadline_s=0.25)
+        if expect_timeout:
+            with pytest.raises(DeviceTimeoutError):
+                await asyncio.wait_for(wait, 5)
+            # 0.01 s a poll under a 0.25 s deadline: at the deadline, not
+            # four polls in (the cap bounds a GAP, not the deadline)
+            assert 20 <= polls[0] <= 30
+        else:
+            await asyncio.wait_for(wait, 5)
+            assert polls[0] == 3
+
 
 class TestQuarantine:
     def test_expiry_bounds_a_permanently_wedged_device(self):

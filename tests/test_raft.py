@@ -222,6 +222,26 @@ class TestSnapshot:
         assert c.applied[straggler][-1] == c.applied[c.leader().id][-1]
 
 
+    @pytest.mark.parametrize("records", [0, 16 * 400, 16 * 4000])
+    async def test_compaction_is_paced_by_the_fsm_size(self, records):
+        """Cutting a snapshot costs O(FSM state) on the serving thread, so
+        the log may hold one entry per ``STATE_RECORDS_PER_LOG_ENTRY``
+        records before it is compacted (never fewer than
+        ``SNAPSHOT_THRESHOLD``): a 1M-key range is not serialised every
+        256 SUBSCRIBEs."""
+        c = Cluster(1)
+        leader = c.elect()
+        leader.state_len_cb = lambda: records
+        allowed = max(RaftNode.SNAPSHOT_THRESHOLD,
+                      records // RaftNode.STATE_RECORDS_PER_LOG_ENTRY)
+        n = RaftNode.SNAPSHOT_THRESHOLD + 300
+        for i in range(n):
+            await c.propose(f"p{i}".encode())
+        assert (leader.snap.last_index > 0) == (n > allowed)
+        assert len(leader.log) <= allowed
+        assert [d for _i, d in c.applied[leader.id]][-1] == f"p{n - 1}".encode()
+
+
 class TestConfigChange:
     async def test_add_voter(self):
         c = Cluster(3)
