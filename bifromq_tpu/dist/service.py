@@ -190,10 +190,15 @@ class DistService:
         # rides the PR 5 gossip digest so a failover target pre-warms
         # before taking traffic
         OBS.register_pub_cache(self._match_cache)
+        # a pub batch hands the matcher at most one warmed device batch:
+        # 17 unique topics would pad to 32 rows, a shape nothing warms,
+        # and compile on the serving path
+        from ..models.pipeline import BASE_FLOOR
         self._pub_scheduler: BatchCallScheduler[PubCall, PubResult] = \
             BatchCallScheduler(lambda tenant: self._make_pub_batch(tenant),
                                pipeline_depth=None,  # BIFROMQ_PIPELINE_DEPTH
                                max_burst_latency=max_burst_latency,
+                               max_batch_size=BASE_FLOOR,
                                stage="queue_wait",
                                obs_tenant_key=True)
 

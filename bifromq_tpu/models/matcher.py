@@ -591,7 +591,8 @@ class TpuMatcher:
         right before it. Warming here (mutation-triggered background
         compile path) keeps the publish path jit-warm."""
         from ..ops.match import Probes, walk_routes, walk_routes_donated
-        from .pipeline import donation_enabled, pipeline_min_floor
+        from .pipeline import (BASE_FLOOR, donation_enabled,
+                               pipeline_min_floor)
         kw = dict(probe_len=ct.probe_len, k_states=self.k_states,
                   max_intervals=self.max_intervals)
         # warm exactly the (batch, walk) pairs _walk_primary will
@@ -610,9 +611,10 @@ class TpuMatcher:
                 return walk_routes_donated(d, p, esc_k=0, **kw)
         else:
             pipe_fn = sync_fn
-        warm = [(16, sync_fn)]
+        warm = [(BASE_FLOOR, sync_fn)]
         if self._ring is not None:
-            warm += [(16, pipe_fn), (pipeline_min_floor(), pipe_fn)]
+            warm += [(BASE_FLOOR, pipe_fn),
+                     (pipeline_min_floor(), pipe_fn)]
         seen = set()
         try:
             for b, fn in warm:

@@ -42,6 +42,13 @@ from ..resilience.device import (BoundedSlots, BufferQuarantine,
 from ..utils.env import env_bool, env_int
 
 
+#: rows of the throughput pad: the widest device batch the serving path
+#: warms (``TpuMatcher._warm``), the most rows callers share at the ring
+#: (``DispatchRing.board``) and the most calls one pub batch hands the
+#: matcher (``dist/service.py``). A wider batch is a new XLA shape class.
+BASE_FLOOR = 16
+
+
 def pipeline_enabled() -> bool:
     """Kill-switch for the async dispatch path (``BIFROMQ_PIPELINE=0``
     degrades ``match_batch_async`` to the sync serving path)."""
@@ -118,7 +125,7 @@ class DispatchRing(BoundedSlots):
 
     def __init__(self, depth: Optional[int] = None,
                  min_floor: Optional[int] = None,
-                 base_floor: int = 16) -> None:
+                 base_floor: int = BASE_FLOOR) -> None:
         super().__init__(depth if depth is not None else pipeline_depth())
         self.min_floor = (min_floor if min_floor is not None
                           else pipeline_min_floor())

@@ -46,14 +46,25 @@ class Batcher(Generic[CallT, ResultT]):
     """One batching pipeline (≈ Batcher.java:46).
 
     - bounded in-flight pipeline (``pipeline_depth``)
-    - queue-depth-adaptive batch cap (ISSUE 6, replacing the
-      latency-EWMA-only heuristic): the cap grows toward the
-      throughput-optimal max while the queue stays SATURATED (depth at
-      emit ≥ cap) within the latency budget, and decays back toward the
-      idle cap while the queue runs SHALLOW — so after a burst drains,
-      the next trickle of calls emits small batches (time-to-first-result)
-      instead of padding to a stale burst-sized cap. A latency overrun
-      still halves the cap (the ``maxBurstLatency`` guard).
+    - a batch is what is queued when a pipeline slot frees, up to an
+      adaptive cap; nobody is held for a batch to fill (no timer)
+    - the cap (``_adapt``) follows what the batcher itself observes:
+      STARVED (a staged batcher's calls waited longer in the queue than
+      their batch ran, both smoothed) and SATURATED (the queue held a
+      full cap at emit) doubles it toward ``max_batch_size`` whatever
+      the budget says: a batch whose cost is a fixed round trip is not
+      shortened by halving it, only its queue is lengthened. An OVERRUN
+      (a batch longer than ``max_burst_latency``) whose calls waited
+      under a quarter of its run halves it: the ``maxBurstLatency``
+      guard, for a cost that grows with the batch on a shallow queue.
+      Between the two the cap holds: a closed loop settles where the
+      wait is about the run, and one threshold there would flip the cap
+      every few batches. Within the budget the cap grows while the
+      queue stays saturated and decays back toward the idle cap while
+      it runs shallow, so after a burst drains the next trickle is not
+      measured against a stale burst-sized cap.
+    - an un-staged batcher records no enqueue time, is never starved,
+      and so keeps the overrun guard unconditionally.
     """
 
     #: cap a freshly-built (or drained-idle) batcher starts from
@@ -100,6 +111,9 @@ class Batcher(Generic[CallT, ResultT]):
                                 Optional[object], int]] = []
         self._inflight = 0
         self._latency = EMA(init=0.0)
+        # mean enqueue→emit wait of a batch's calls, smoothed like the
+        # batch time it is compared with (staged batchers only)
+        self._wait = EMA(init=0.0)
         # queue depth observed at emit (EMA smooths one-batch spikes so a
         # single burst doesn't whipsaw the cap)
         self._depth_ema = EMA(alpha=0.3, init=0.0)
@@ -174,6 +188,7 @@ class Batcher(Generic[CallT, ResultT]):
         start = self._clock()
         rep_ctx = None
         links: List[Tuple[int, int]] = []
+        waited = 0.0
         if self._stage is not None:
             # enqueue→emit queue-wait per call, stamped at EMIT time with
             # the batch shape the adaptive cap produced
@@ -184,6 +199,7 @@ class Batcher(Generic[CallT, ResultT]):
             batch_id = trace.open_batch()
             tags = None
             for _, _, enq, tctx, shlc in batch:
+                waited += start - enq
                 if tctx is not None:
                     if rep_ctx is None:
                         rep_ctx = tctx
@@ -198,6 +214,8 @@ class Batcher(Generic[CallT, ResultT]):
                     "batch.queue_wait", tctx, start_ns=int(enq * 1e9),
                     end_ns=start_ns, start_hlc=shlc, tenant=self._obs_key,
                     tags=tags, stage=self._stage)
+            trace.count("batch.emitted")
+            trace.count("batch.calls", len(batch))
         try:
             if self._stage is not None:
                 # a batch aggregates many callers' traces; run the
@@ -220,7 +238,8 @@ class Batcher(Generic[CallT, ResultT]):
             else:
                 results = await self._process(calls)
             elapsed = self._clock() - start
-            self._adapt(len(calls), elapsed, depth_at_emit)
+            self._adapt(len(calls), elapsed, depth_at_emit,
+                        waited / len(batch))
             if self._stage is not None:
                 # ISSUE 8: emit occupancy for the continuous profiler
                 # (the scheduler-side half of padding waste: a batch far
@@ -242,28 +261,48 @@ class Batcher(Generic[CallT, ResultT]):
             self._trigger()
 
     def _adapt(self, batch_size: int, elapsed: float,
-               depth_at_emit: int = 0) -> None:
-        """Queue-depth-adaptive cap (ISSUE 6). Three regimes:
+               depth_at_emit: int = 0, waited: float = 0.0) -> None:
+        """The cap after one batch, from its run time ``elapsed``, the
+        queue depth when it emitted and its calls' mean queue wait
+        ``waited`` (0 for an un-staged batcher):
 
-        - latency overrun ⇒ halve (unchanged ``maxBurstLatency`` guard);
-        - saturated (the queue held ≥ a full cap when this batch emitted)
-          within budget ⇒ double toward the throughput-optimal cap;
+        - starved and saturated ⇒ double, whatever the budget says: the
+          batch is too SMALL, its calls waited longer than it ran and a
+          full cap more was waiting behind them;
+        - overrun, and the calls waited under a quarter of the run ⇒
+          halve (the ``maxBurstLatency`` guard: a shallow queue, a cost
+          that grows with the batch); an overrun whose calls waited
+          longer holds: halving would move their time from the batch
+          into the queue;
+        - saturated within budget ⇒ double toward the throughput-optimal
+          cap;
         - shallow (smoothed depth under a quarter cap) ⇒ decay halfway
           toward the idle cap, so the cap tracks the LIVE queue instead
           of whatever the last burst grew it to.
         """
         self._latency.update(elapsed)
         self._depth_ema.update(depth_at_emit)
-        if elapsed > self._budget:
-            self._cap = max(self._min_cap, self._cap // 2)
-            return
-        if (depth_at_emit >= self._cap
-                and self._latency.value < self._budget / 2):
-            self._cap = min(self._max_cap, self._cap * 2)
+        self._wait.update(waited)
+        cap = self._cap
+        starved = self._wait.value > self._latency.value
+        saturated = depth_at_emit >= cap
+        if starved and saturated:
+            cap = min(self._max_cap, cap * 2)
+        elif elapsed > self._budget:
+            if self._wait.value < self._latency.value / 4:
+                cap = max(self._min_cap, cap // 2)
+        elif saturated and self._latency.value < self._budget / 2:
+            cap = min(self._max_cap, cap * 2)
         elif (self._shallow_decay
-                and self._depth_ema.value < self._cap / 4
-                and self._cap > self._idle_cap):
-            self._cap = max(self._idle_cap, self._cap // 2)
+                and self._depth_ema.value < cap / 4
+                and cap > self._idle_cap):
+            cap = max(self._idle_cap, cap // 2)
+        if cap != self._cap and self._stage is not None:
+            if cap > self._cap:
+                trace.count("batch.cap_grow")
+            else:
+                trace.count("batch.cap_shrink")
+        self._cap = cap
 
 
 class BatchCallScheduler(Generic[CallT, ResultT]):

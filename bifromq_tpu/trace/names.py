@@ -76,8 +76,23 @@ _row("batch.queue_wait", "span", "scheduler/batcher.py",
      "enqueue -> emit wait per call (deferred span), tagged `batch_size`, "
      "the adaptive `cap` at emit time and the `batch_id` every sampled "
      "span of that batch carries; feeds the `queue_wait` stage and the "
-     "tenant's window",
+     "tenant's window. A batch's mean of it, against the batch's run "
+     "time, is what moves the cap",
      stage="queue_wait", window="queue_wait")
+_row("batch.emitted", "counter", "scheduler/batcher.py",
+     "batches a staged batcher emitted (the pub scheduler's: one "
+     "`process`, at most one match call)")
+_row("batch.calls", "counter", "scheduler/batcher.py",
+     "calls in those batches (= n of `batch.queue_wait`): over "
+     "`batch.emitted` it reads calls a pub batch")
+_row("batch.cap_grow", "counter", "scheduler/batcher.py",
+     "times a staged batcher's cap doubled: its calls waited longer than "
+     "their batch ran with a full cap more queued behind them (whatever "
+     "the budget), or the queue was saturated well inside the budget")
+_row("batch.cap_shrink", "counter", "scheduler/batcher.py",
+     "times a staged batcher's cap halved: a batch over "
+     "`max_burst_latency` whose calls had waited under a quarter of its "
+     "run (the overrun guard), or the shallow-queue decay toward idle")
 _row("batch.emit", "span", "scheduler/batcher.py",
      "a batch that holds several sampled callers: parented under the "
      "first, linking the others (bounded at 16)")

@@ -50,14 +50,21 @@ class TestBatcher:
         assert peak <= 2
 
     async def test_cap_shrinks_on_overrun(self):
+        # the guard's own case: a cost that grows with the batch, and
+        # bursts that leave nobody queued behind them (a fixed cost under
+        # a deep queue is test_pipeline's TestAdaptiveSizing)
         async def slow(calls):
-            await asyncio.sleep(0.02)
+            await asyncio.sleep(0.0005 * len(calls))
             return list(calls)
 
-        b = Batcher(slow, max_burst_latency=0.001)
+        b = Batcher(slow, max_burst_latency=0.001, pipeline_depth=1,
+                    stage="queue_wait")
         start_cap = b.batch_cap
-        futs = [b.submit(i) for i in range(200)]
-        await asyncio.gather(*futs)
+        for _ in range(3):
+            # the first leaves alone and at once, the rest as ONE batch
+            # when it returns: they waited 0.5 ms and run cap x 0.5 ms
+            await asyncio.gather(*[b.submit(i)
+                                   for i in range(b.batch_cap + 1)])
         assert b.batch_cap < start_cap
 
     async def test_cap_grows_when_fast(self):

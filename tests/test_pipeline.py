@@ -4,6 +4,7 @@ safety, and the _InFlight snapshot discipline under mid-flight mutations
 and compaction swaps."""
 
 import asyncio
+import heapq
 
 import numpy as np
 import pytest
@@ -30,6 +31,74 @@ class FakeClock:
 
     def advance(self, dt: float) -> None:
         self.t += dt
+
+
+class SimClock(FakeClock):
+    """Fake time that several tasks share: ``sleep`` parks its caller
+    until the clock reaches the due time; ``run`` lets every task that
+    can run at the current instant run, then steps to the next due time
+    (batches of a depth-2 pipeline overlap as they do on a real loop)."""
+
+    def __init__(self) -> None:
+        super().__init__()
+        self._due = []
+        self._seq = 0
+
+    def sleep(self, dt: float) -> "asyncio.Future":
+        fut = asyncio.get_running_loop().create_future()
+        self._seq += 1      # equal due times fire in the order they parked
+        heapq.heappush(self._due, (self.t + dt, self._seq, fut))
+        return fut
+
+    async def run(self, *coros):
+        tasks = [asyncio.ensure_future(c) for c in coros]
+        while True:
+            for _ in range(16):     # a wake-up chain is 4 turns long
+                await asyncio.sleep(0)
+            if all(t.done() for t in tasks):
+                return [t.result() for t in tasks]
+            self.t, _, fut = heapq.heappop(self._due)
+            fut.set_result(None)
+
+
+def sim_batcher(clk: SimClock, *, fixed: float = 0.0, per_call: float = 0.0,
+                staged: bool = True, **kw):
+    """A batcher on ``clk`` whose batches take ``fixed`` + ``per_call`` a
+    call, as the pub scheduler builds its own (5 ms budget, depth 2, at
+    most 16 calls) unless ``kw`` says otherwise; ``sizes`` collects the
+    batches it emitted. Un-staged it records no enqueue time: the
+    parent's rule."""
+    sizes = []
+
+    async def process(calls):
+        sizes.append(len(calls))
+        await clk.sleep(fixed + per_call * len(calls))
+        return list(calls)
+
+    kw.setdefault("max_burst_latency", 0.005)
+    kw.setdefault("pipeline_depth", 2)
+    kw.setdefault("max_batch_size", 16)
+    b = Batcher(process, stage="queue_wait" if staged else None,
+                clock=clk, **kw)
+    return b, sizes
+
+
+async def closed_loop(clk: SimClock, b: Batcher, lanes: int, each: int,
+                      think: float = 0.0):
+    """``lanes`` submitters, each sending its next call ``think`` (the
+    client's turn-around) after the last returned; returns the cap each
+    saw as a call returned."""
+    caps = []
+
+    async def lane(i):
+        for _ in range(each):
+            await b.submit(i)
+            caps.append(b.batch_cap)
+            if think:
+                await clk.sleep(think)
+
+    await clk.run(*[lane(i) for i in range(lanes)])
+    return caps
 
 
 # ---------------- adaptive batch sizing (fake clock) ------------------------
@@ -109,8 +178,9 @@ class TestAdaptiveSizing:
             await b.submit(i)
         assert b.batch_cap == grown          # no decay
         # the latency-overrun guard still applies to opted-out batchers
+        # (a cost that grows with the batch)
         async def slow(calls):
-            clk.advance(1.0)
+            clk.advance(0.01 * len(calls))
             return list(calls)
 
         b._process = slow
@@ -119,17 +189,159 @@ class TestAdaptiveSizing:
         assert b.batch_cap < grown
 
     async def test_latency_overrun_still_halves(self):
-        clk = FakeClock()
+        # (b) the guard's own case: 1 ms a call, bursts that leave
+        # nobody queued behind them: over budget, and the calls waited
+        # (one call's millisecond) under a quarter of their batch's run
+        clk = SimClock()
+        b, sizes = sim_batcher(clk, per_call=0.001, pipeline_depth=1)
+        assert b.batch_cap == 16
 
-        async def slow(calls):
-            clk.advance(0.2)        # blows the budget every time
-            return list(calls)
+        async def bursts():
+            for _ in range(4):
+                # the first leaves alone and at once, the rest as ONE
+                # batch of a whole cap when it returns
+                await asyncio.gather(*[b.submit(i)
+                                       for i in range(b.batch_cap + 1)])
 
-        b = Batcher(slow, max_burst_latency=0.01, clock=clk)
-        start = b.batch_cap
-        futs = [b.submit(i) for i in range(200)]
-        await asyncio.gather(*futs)
-        assert b.batch_cap < start
+        await clk.run(bursts())
+        assert sizes == [1, 16, 1, 8, 1, 4, 1, 4]
+        assert b.batch_cap == 4         # 4 ms a batch: inside the budget
+
+    async def test_fixed_cost_overrun_does_not_collapse(self):
+        # (a) THE COLLAPSE: a 9 ms round trip whatever the batch, a 5 ms
+        # budget, 64 closed-loop lanes behind depth 2. Halving cannot
+        # shorten such a batch: the parent's rule ends at one call a
+        # batch, for good
+        clk = SimClock()
+        b, sizes = sim_batcher(clk, fixed=0.009)
+        caps = await closed_loop(clk, b, lanes=64, each=40)
+        settled = caps[len(caps) // 4:]
+        assert b.batch_cap >= 8 and min(settled) >= 8
+        assert sum(sizes) == 64 * 40 and max(sizes) <= 16
+        took = clk.t
+
+        clk = SimClock()
+        parent, _ = sim_batcher(clk, fixed=0.009, staged=False)
+        await closed_loop(clk, parent, lanes=64, each=40)
+        assert parent.batch_cap == 1
+        assert b.batches_emitted * 4 <= parent.batches_emitted
+        assert took * 4 <= clk.t        # and the same calls in a quarter
+
+    async def test_open_trickle_keeps_single_call_batches(self):
+        # (c) wildcard_1m.fanout_r25's shape: a 47 ms batch against a
+        # 5 ms budget, arrivals one at a time on an empty queue: nobody
+        # waits, so the guard takes the cap to 1 and nothing takes it
+        # back; no call is held for company
+        clk = SimClock()
+        b, sizes = sim_batcher(clk, fixed=0.047)
+        done_at = []
+
+        async def trickle():
+            for i in range(12):
+                t0 = clk.t
+                await b.submit(i)
+                done_at.append(clk.t - t0)
+                await clk.sleep(0.1)
+
+        await clk.run(trickle())
+        assert sizes == [1] * 12
+        assert done_at == pytest.approx([0.047] * 12)
+        assert b.batch_cap == 1
+
+    async def test_collapsed_cap_recovers_when_queue_deepens(self):
+        # (d) a cap the trickle took to 1 comes back once calls wait
+        # longer than their batches run; the parent's never does
+        for staged in (True, False):
+            clk = SimClock()
+            b, sizes = sim_batcher(clk, fixed=0.009, staged=staged)
+
+            async def trickle():
+                for i in range(8):
+                    await b.submit(i)
+
+            await clk.run(trickle())
+            assert b.batch_cap == 1
+            await closed_loop(clk, b, lanes=64, each=20)
+            if staged:
+                assert b.batch_cap >= 8
+                assert sizes[-12:-2] == [16] * 10   # the tail drains
+            else:
+                assert b.batch_cap == 1 and set(sizes) == {1}
+
+    @pytest.mark.parametrize("max_batch", [4, 16])
+    async def test_cap_never_passes_max_batch_size(self, max_batch):
+        # (e) one device batch is all a pub batch may hand the matcher:
+        # a fresh batcher's first burst of 64 leaves in batches of at
+        # most max_batch_size (IDLE_CAP clamps to it), and no depth of
+        # queue grows the cap past it
+        clk = SimClock()
+        b, sizes = sim_batcher(clk, fixed=0.009, max_batch_size=max_batch)
+        assert b.batch_cap == max_batch < Batcher.IDLE_CAP
+
+        async def burst():
+            await asyncio.gather(*[b.submit(i) for i in range(64)])
+
+        await clk.run(burst())
+        assert sum(sizes) == 64 and max(sizes) <= max_batch
+        caps = await closed_loop(clk, b, lanes=64, each=20)
+        assert max(caps) <= max_batch and max(sizes) == max_batch
+
+    async def test_unstaged_batcher_keeps_the_unconditional_guard(self):
+        # (f) the worker's mutation coalescer records no enqueue time:
+        # it is never starved, and an overrun halves its cap whatever
+        # the queue holds, as at the parent
+        clk = SimClock()
+        b, sizes = sim_batcher(clk, fixed=0.009, staged=False,
+                               max_batch_size=8192, shallow_decay=False)
+        assert b.batch_cap == Batcher.IDLE_CAP
+
+        async def burst():
+            await asyncio.gather(*[b.submit(i) for i in range(400)])
+
+        await clk.run(burst())
+        # 1, 1 (the two free slots), then a half less as each returns
+        assert sizes[:8] == [1, 1, 32, 16, 8, 4, 2, 1]
+        assert b.batch_cap == 1 and b._wait.value == 0.0
+
+    @pytest.mark.parametrize("lanes,think,cap", [
+        (12, 0.001, 4), (16, 0.002, 4), (24, 0.001, 16), (64, 0.003, 16)])
+    async def test_cap_holds_between_the_two_thresholds(self, lanes, think,
+                                                        cap):
+        # halving asks for a wait under a quarter of the run, doubling
+        # for a wait over the whole of it: a closed loop sits between
+        # the two at some cap and stays there (with one threshold the
+        # cap flips every few batches, and the EMAs' lag takes it to 1)
+        clk = SimClock()
+        b, _ = sim_batcher(clk, fixed=0.009)
+        caps = await closed_loop(clk, b, lanes, each=80, think=think)
+        # past the start, and before the lanes run out one by one
+        assert set(caps[len(caps) // 2:len(caps) * 3 // 4]) == {cap}
+
+    async def test_staged_batches_count_calls_and_cap_moves(self):
+        import time
+
+        from bifromq_tpu import trace
+
+        def totals():
+            got = trace.TRACER.totals.between(0, time.monotonic_ns() + 10**9)
+            return [got.get(n, (0, 0.0))[0] for n in
+                    ("batch.calls", "batch.cap_grow", "batch.cap_shrink",
+                     "batch.emitted")]
+
+        before = totals()
+        clk = SimClock()
+        b, sizes = sim_batcher(clk, fixed=0.009)
+        await closed_loop(clk, b, lanes=64, each=10)
+        calls, grew, shrank, batches = (a - b4 for a, b4
+                                        in zip(totals(), before))
+        assert calls == 640 and batches == b.batches_emitted == len(sizes)
+        # 16 -> 8 -> 4 while the first calls had not waited, then back
+        assert shrank == grew == 2 and b.batch_cap == 16
+        # an un-staged batcher counts nothing
+        before = totals()
+        b, sizes = sim_batcher(SimClock(), fixed=0.009, staged=False)
+        await closed_loop(b._clock, b, lanes=8, each=4)
+        assert totals() == before
 
     async def test_queue_depth_property(self):
         started = asyncio.Event()
