@@ -124,8 +124,8 @@ class GroupFanoutBalancer:
         return elected
 
     def spread(self, tenant_id: str, mqtt_filter: str) -> dict:
-        """Per-group balance introspection (bench config 10's
-        share-balance leg and the fairness tests read it)."""
+        """Per-group balance introspection (the fairness tests read
+        it)."""
         counts = self._counts.get((tenant_id, mqtt_filter), {})
         if not counts:
             return {"members": 0, "max": 0, "min": 0}
@@ -169,17 +169,12 @@ class DistService:
         # is remote; with a local worker the coproc apply-stream hook
         # below makes invalidation exact (replayed mutations included).
         from ..models.matchcache import TenantMatchCache
-        from ..models.matcher import _match_cache_default
         self._match_cache = TenantMatchCache(
             scope="pub", ttl_s=self._MATCH_CACHE_TTL_DEFAULT,
             max_topics_per_tenant=self.MATCH_CACHE_MAX,
             max_entries=self.MATCH_CACHE_MAX)   # same TOTAL bound as the
         # hand-rolled predecessor: TTL expiry is lazy, the bound is the
         # memory wall
-        # BIFROMQ_MATCH_CACHE=0 is the kill-switch for the WHOLE cache
-        # plane: this pub layer bypasses lookups/stores too (the cache
-        # object stays constructed so invalidation plumbing is inert-safe)
-        self._pub_cache_enabled = _match_cache_default()
         if hasattr(worker, "on_route_mutation"):
             worker.on_route_mutation = self._on_route_mutation
         # ISSUE 12: a REMOTE worker has no local apply stream — the
@@ -193,10 +188,10 @@ class DistService:
         # a pub batch hands the matcher at most one warmed device batch:
         # 17 unique topics would pad to 32 rows, a shape nothing warms,
         # and compile on the serving path
-        from ..models.pipeline import BASE_FLOOR
+        from ..models.pipeline import BASE_FLOOR, PIPELINE_DEPTH
         self._pub_scheduler: BatchCallScheduler[PubCall, PubResult] = \
             BatchCallScheduler(lambda tenant: self._make_pub_batch(tenant),
-                               pipeline_depth=None,  # BIFROMQ_PIPELINE_DEPTH
+                               pipeline_depth=PIPELINE_DEPTH,
                                max_burst_latency=max_burst_latency,
                                max_batch_size=BASE_FLOOR,
                                stage="queue_wait",
@@ -378,25 +373,21 @@ class DistService:
             matched: List[Optional[MatchedRoutes]] = []
             miss_topics: List[str] = []     # deduped (hot-topic bursts
             miss_pos: Dict[str, int] = {}   # must not fan into N queries)
-            cache_on = self._pub_cache_enabled
             n_miss_calls = 0
             for qi, c in enumerate(calls):
-                m = (self._match_cache.get(tenant_id, c.topic, caps)
-                     if cache_on else None)
+                m = self._match_cache.get(tenant_id, c.topic, caps)
                 matched.append(m)
                 if m is None:
                     n_miss_calls += 1
                     if c.topic not in miss_pos:
                         miss_pos[c.topic] = len(miss_topics)
                         miss_topics.append(c.topic)
-            if cache_on:
-                OBS.record_match_cache(tenant_id,
-                                       len(calls) - n_miss_calls,
-                                       n_miss_calls)
-                # global section totals: one locked inc per pub batch
-                from ..utils.metrics import MATCH_CACHE
-                MATCH_CACHE.inc("pub", "hits", len(calls) - n_miss_calls)
-                MATCH_CACHE.inc("pub", "misses", n_miss_calls)
+            OBS.record_match_cache(tenant_id, len(calls) - n_miss_calls,
+                                   n_miss_calls)
+            # global section totals: one locked inc per pub batch
+            from ..utils.metrics import MATCH_CACHE
+            MATCH_CACHE.inc("pub", "hits", len(calls) - n_miss_calls)
+            MATCH_CACHE.inc("pub", "misses", n_miss_calls)
             if miss_topics:
                 # snapshot BEFORE the (awaited) match: a mutation landing
                 # mid-flight must make the stored entry instantly stale
@@ -411,10 +402,8 @@ class DistService:
                         EventType.DIST_ERROR, tenant_id,
                         {"topics": len(miss_topics)}))
                     raise
-                if cache_on:
-                    for t, m in zip(miss_topics, fresh):
-                        self._match_cache.put(tenant_id, t, caps, m,
-                                              token)
+                for t, m in zip(miss_topics, fresh):
+                    self._match_cache.put(tenant_id, t, caps, m, token)
                 for qi, c in enumerate(calls):
                     if matched[qi] is None:
                         matched[qi] = fresh[miss_pos[c.topic]]

@@ -689,22 +689,16 @@ class DistWorker:
         ``of_batch`` is this call's share of the rows of the device batch
         that served it: calls that waited at the ring together shared
         one walk, and split its cost."""
-        cache = getattr(coproc.matcher, "match_cache", None)
+        cache = coproc.matcher.match_cache
         c0 = cache.counts() if cache is not None else (0, 0)
         try:
             get_injector().check_raise("matcher", "tpu-matcher", "match")
             if deadline is not None and _time.monotonic() >= deadline:
                 raise TimeoutError("match deadline budget exhausted")
             stats: dict = {}
-            amatch = getattr(coproc.matcher, "match_batch_async", None)
-            if amatch is not None:
-                out = await amatch(
-                    sub, max_persistent_fanout=max_persistent_fanout,
-                    max_group_fanout=max_group_fanout, stats=stats)
-            else:
-                out = coproc.matcher.match_batch(
-                    sub, max_persistent_fanout=max_persistent_fanout,
-                    max_group_fanout=max_group_fanout)
+            out = await coproc.matcher.match_batch_async(
+                sub, max_persistent_fanout=max_persistent_fanout,
+                max_group_fanout=max_group_fanout, stats=stats)
             if cache is not None and sp.sampled:
                 # ISSUE 4: cache disposition on the device span —
                 # "hit" = the whole batch skipped the device,
@@ -735,15 +729,10 @@ class DistWorker:
             # overlapped pipeline: the span also covers the ring-acquire
             # wait (queue time under a saturated pipeline, not match
             # cost), so the matcher reports it and the span's exit leaves
-            # it out of the "device" stage and the per-tenant shares —
-            # the stage then measures the same thing either side of
-            # BIFROMQ_PIPELINE (the sync fallback has no such wait)
+            # it out of the "device" stage and the per-tenant shares
             return (out, stats.get("acquire_s", 0.0),
                     stats.get("batch_share", 1.0))
         except Exception as e:  # noqa: BLE001 — degrade, don't fail
-            oracle = getattr(coproc.matcher, "match_from_tries", None)
-            if oracle is None:
-                raise       # no authoritative host state: nothing to serve
             FABRIC.inc(FabricMetric.MATCH_DEGRADED, len(sub))
             logging.getLogger(__name__).warning(
                 "match degraded to host oracle (%d queries): %r",
@@ -755,9 +744,9 @@ class DistWorker:
             # separate host-oracle serves from true device time
             with trace.span("match.degraded", tenant=sub[0][0],
                             n_queries=len(sub), reason=repr(e)[:120]):
-                out = oracle(sub,
-                             max_persistent_fanout=max_persistent_fanout,
-                             max_group_fanout=max_group_fanout)
+                out = coproc.matcher.match_from_tries(
+                    sub, max_persistent_fanout=max_persistent_fanout,
+                    max_group_fanout=max_group_fanout)
             return out, 0.0, 1.0
 
     @staticmethod

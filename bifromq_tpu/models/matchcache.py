@@ -108,7 +108,7 @@ class TenantMatchCache:
             from ..utils.metrics import MATCH_CACHE
             metrics = MATCH_CACHE
         self._metrics = metrics
-        # instance counters (bench A/B + per-range span tags); the global
+        # instance counters (per-range span tags); the global
         # section aggregates across instances
         self.hits = 0
         self.misses = 0

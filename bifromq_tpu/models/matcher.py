@@ -143,11 +143,6 @@ class _HostPairs:
             setattr(self, k, v)
 
 
-def _match_cache_default() -> bool:
-    from ..utils.env import env_bool
-    return env_bool("BIFROMQ_MATCH_CACHE", True)
-
-
 def apply_log_op(tries: Dict[str, SubscriptionTrie], op: Tuple) -> None:
     """Apply ONE matcher log op to a tries dict — THE single definition
     of the op → trie semantics, shared by the shadow replay and the
@@ -178,25 +173,12 @@ def _safe_hook(cb, what: str, *args) -> None:
 
 
 class TpuMatcher:
-    # the async pipeline path (match_batch_async) drives _dispatch_device
-    # directly; subclasses replacing the whole device plane (MeshMatcher)
-    # flip this off and the async entry degrades to their sync path
-    supports_async = True
-    # ISSUE 9: single-chip bases are PatchableTrie and mutations fold into
-    # the arenas in place (delta patches + narrow device updates) instead
-    # of accumulating in the overlay until a full rebuild. Subclasses
-    # whose compile target isn't the single-chip CompiledTrie (MeshMatcher
-    # ships per-shard stacks to a mesh) flip this off and keep the
-    # overlay+compaction path — per-shard patching is the ROADMAP
-    # follow-up this PR unlocks.
-    supports_patching = True
-
     def __init__(self, *, max_levels: int = 16, k_states: int = 32,
                  probe_len: int = 16, device=None,
                  auto_compact: bool = True,
                  compact_threshold: int = 2048,
                  max_intervals: int = 32,
-                 match_cache: Optional[bool] = None) -> None:
+                 match_cache: bool = True) -> None:
         self.max_levels = max_levels
         self.k_states = k_states
         self.probe_len = probe_len
@@ -222,13 +204,11 @@ class TpuMatcher:
         # walk — a repeated (tenant, topic) is a dict probe, not a
         # dispatch. Filter-aware invalidation lives in add/remove_route;
         # base rebuilds bump the generation (_install_base).
-        if match_cache is None:
-            match_cache = _match_cache_default()
         from .matchcache import TenantMatchCache
         self.match_cache = (TenantMatchCache(scope="matcher")
                             if match_cache else None)
         # ISSUE 6: async dispatch ring (lazy — sync-only deployments never
-        # pay for it); see models/pipeline.py for the knobs
+        # pay for it); see models/pipeline.py for its sizes
         self._ring = None
         # ISSUE 7: per-device circuit breaker fed by device timeouts and
         # errors — open serves the exact host-oracle degraded path with
@@ -301,7 +281,7 @@ class TpuMatcher:
     def from_tries(cls, tries: Dict[str, SubscriptionTrie],
                    **kwargs) -> "TpuMatcher":
         """Seed a matcher from pre-built tries WITHOUT replaying every
-        route through the mutation log/overlay (bench + tier-2 gate bulk
+        route through the mutation log/overlay (benchmark + tier-2 gate bulk
         loads). The trie objects are SHARED between authoritative and
         shadow state: later add/remove_route traffic stays correct (the
         shadow replay re-applies each op idempotently), but the compile
@@ -388,7 +368,7 @@ class TpuMatcher:
                        filter_levels, op, plan, fallback)
 
     def _patching_enabled(self) -> bool:
-        return self.supports_patching and patch_enabled()
+        return patch_enabled()
 
     def _group_members(self, tenant_id: str, matcher) -> dict:
         """The authoritative surviving member set for a shared-group op —
@@ -580,8 +560,8 @@ class TpuMatcher:
 
     def _warm_walk(self, ct: CompiledTrie, dev) -> None:
         """Pre-compile the serving walk for this table's shapes at the
-        smallest serving batches: 16 (the _pow2_batch floor) and, when
-        the async pipeline is on, the shallow-queue latency floor too —
+        smallest serving batches: 16 (the _pow2_batch floor) and, once
+        the async ring has served, the shallow-queue latency floor too —
         the idle-broker single-publish shape must not pay a first-use
         compile on the serving path.
 
@@ -591,36 +571,29 @@ class TpuMatcher:
         right before it. Warming here (mutation-triggered background
         compile path) keeps the publish path jit-warm."""
         from ..ops.match import Probes, walk_routes, walk_routes_donated
-        from .pipeline import (BASE_FLOOR, donation_enabled,
-                               pipeline_min_floor)
+        from .pipeline import BASE_FLOOR, MIN_FLOOR
         kw = dict(probe_len=ct.probe_len, k_states=self.k_states,
                   max_intervals=self.max_intervals)
         # warm exactly the (batch, walk) pairs _walk_primary will
         # select: the sync floor always; once the async ring has
         # actually served (self._ring exists), ALSO the shallow-queue
         # latency floor and the busy-ring throughput floor on the
-        # pipeline's walk (donated or not) — a live pipeline must stay
+        # pipeline's donated walk — a live pipeline must stay
         # jit-warm across recompiles, but sync-only deployments (and the
         # test suite) never pay for shapes they don't serve. The very
         # first shallow publish of a process compiles its floor lazily
         # instead.
         def sync_fn(d, p):
             return walk_routes(d, p, esc_k=0, **kw)
-        if donation_enabled():
-            def pipe_fn(d, p):
-                return walk_routes_donated(d, p, esc_k=0, **kw)
-        else:
-            pipe_fn = sync_fn
+
+        def pipe_fn(d, p):
+            return walk_routes_donated(d, p, esc_k=0, **kw)
         warm = [(BASE_FLOOR, sync_fn)]
         if self._ring is not None:
             warm += [(BASE_FLOOR, pipe_fn),
-                     (pipeline_min_floor(), pipe_fn)]
-        seen = set()
+                     (MIN_FLOOR, pipe_fn)]
         try:
             for b, fn in warm:
-                if (b, fn) in seen:
-                    continue
-                seen.add((b, fn))
                 tok = tokenize([["warm"]], [-1], max_levels=ct.max_levels,
                                salt=ct.salt, batch=b)
                 res = fn(dev, Probes.from_tokenized(tok,
@@ -970,8 +943,8 @@ class TpuMatcher:
                                 max_persistent_fanout: int = UNCAPPED_FANOUT,
                                 max_group_fanout: int = UNCAPPED_FANOUT,
                                 batch: Optional[int] = None,
-                                stats: Optional[dict] = None,
-                                **device_kw) -> List[MatchedRoutes]:
+                                stats: Optional[dict] = None
+                                ) -> List[MatchedRoutes]:
         """Pipelined serving path: same results as ``match_batch``, but
         the device walk is dispatched through the bounded in-flight ring
         and awaited on READINESS — batch N+1 tokenizes and enqueues while
@@ -986,8 +959,7 @@ class TpuMatcher:
         overlapped pipeline also counts that wait — so THIS batch's
         match cost is cache probe + dispatch+ready+fetch + host
         expansion and cache fill, the same work the sync path's wall
-        clock covers, and toggling ``BIFROMQ_PIPELINE`` does not shift
-        what the "device" stage histograms measure.
+        clock covers: what the "device" stage histograms measure.
         ``stats["batch_share"]`` is this call's share of the rows of the
         device batch that served it (1.0 alone; callers waiting at ring
         admission leave as one batch). ``stats["degraded"]``
@@ -995,25 +967,9 @@ class TpuMatcher:
         oracle (ISSUE 7: breaker open, watchdog timeout, device error)
         so the worker can emit MATCH_DEGRADED events without a raising
         boundary.
-
-        Degrades to the sync path when the pipeline is disabled
-        (``BIFROMQ_PIPELINE=0``) or the subclass replaced the device plane
-        (``supports_async = False``).
         """
-        from .pipeline import pipeline_enabled
         if not queries:
             return []
-        if not (self.supports_async and pipeline_enabled()):
-            return self.match_batch(
-                queries, max_persistent_fanout=max_persistent_fanout,
-                max_group_fanout=max_group_fanout, batch=batch,
-                stats=stats, **device_kw)
-        if device_kw:
-            # the sync path would TypeError on unknown kwargs inside
-            # _match_batch_device; an env flag must not turn that into a
-            # silent drop
-            raise TypeError("match_batch_async got unsupported kwargs: "
-                            f"{sorted(device_kw)}")
         caps = (max_persistent_fanout, max_group_fanout)
         cache = self.match_cache
         if cache is not None:
@@ -1201,7 +1157,6 @@ class TpuMatcher:
         of its callers' queue time) and ``merged.tokenize_s`` are set
         here, and stand even when the leg later raises."""
         from ..resilience.device import DeviceTimeoutError
-        from .pipeline import donation_enabled
         # ISSUE 11 overlap: stage-1 prep (tokenize + probe upload) runs
         # BEFORE slot admission — batch N+1 tokenizes while batch N is
         # still walking, and a full ring stalls only the enqueue, not
@@ -1230,8 +1185,7 @@ class TpuMatcher:
         merged.admitted = time.monotonic()
         trace.count("match.merged_calls", len(merged.callers))
         try:
-            fl = self._dispatch_prepared(prep,
-                                         donate=donation_enabled(),
+            fl = self._dispatch_prepared(prep, donate=True,
                                          watchdogged=True)
             ring.start_fetch(fl.res)
             try:

@@ -5,17 +5,17 @@ device dispatch → BLOCKING device_get` with nothing overlapped. This
 module is the overlap plane:
 
 - a **dispatch ring** bounds the number of in-flight device batches
-  (``BIFROMQ_PIPELINE_DEPTH``, default 2 = double-buffered; 3 = triple):
-  batch N+1 tokenizes and enqueues on device while batch N is still
-  walking, because the await happens on *readiness*, not inside dispatch;
+  (``PIPELINE_DEPTH``: 2, double-buffered): batch N+1 tokenizes and
+  enqueues on device while batch N is still walking, because the await
+  happens on *readiness*, not inside dispatch;
 - results come back via **fetch-on-ready**: the dispatch starts a
   ``copy_to_host_async`` immediately, the serving coroutine polls
   ``jax.Array.is_ready`` (yielding the event loop between polls — other
   batches dispatch in those gaps) and only then pays the final host copy;
 - the ring's occupancy is the **queue-depth signal** for adaptive batch
   shaping: an idle ring means a shallow dispatch queue, so the pow2 pad
-  floor drops to ``BIFROMQ_PIPELINE_MIN_BATCH`` (default 8) to cut
-  time-to-first-result; a busy ring keeps the throughput floor (16).
+  floor drops to ``MIN_FLOOR`` (8) to cut time-to-first-result; a busy
+  ring keeps the throughput floor (``BASE_FLOOR``, 16).
 
 - callers that wait at admission **share one device batch**: whoever is
   in line when a prep ticket frees leaves together, up to the busy-ring
@@ -39,7 +39,6 @@ from typing import Deque, List, Optional
 from .. import trace
 from ..resilience.device import (BoundedSlots, BufferQuarantine,
                                  DeviceTimeoutError, device_deadline_s)
-from ..utils.env import env_bool, env_int
 
 
 #: rows of the throughput pad: the widest device batch the serving path
@@ -48,30 +47,13 @@ from ..utils.env import env_bool, env_int
 #: matcher (``dist/service.py``). A wider batch is a new XLA shape class.
 BASE_FLOOR = 16
 
+#: rows of the latency pad: what a lone caller on an idle ring is padded
+#: to. Each floor is one more XLA shape class to warm, so there are two.
+MIN_FLOOR = 8
 
-def pipeline_enabled() -> bool:
-    """Kill-switch for the async dispatch path (``BIFROMQ_PIPELINE=0``
-    degrades ``match_batch_async`` to the sync serving path)."""
-    return env_bool("BIFROMQ_PIPELINE", True)
-
-
-def pipeline_depth() -> int:
-    """In-flight device batches (2 = double-buffered, 3 = triple)."""
-    return max(1, min(env_int("BIFROMQ_PIPELINE_DEPTH", 2), 8))
-
-
-def pipeline_min_floor() -> int:
-    """Shallow-queue pow2 pad floor (the latency floor; 16 stays the
-    throughput floor). Each extra floor is one more XLA shape class, so
-    it is a single knob, not a free sweep."""
-    return max(1, min(env_int("BIFROMQ_PIPELINE_MIN_BATCH", 8), 16))
-
-
-def donation_enabled() -> bool:
-    """Donate in-flight probe buffers to XLA (``walk_routes_donated``).
-    Default on — the ring never re-reads a dispatched Probes object (the
-    escalation/readback paths only touch the host TokenizedTopics copy)."""
-    return env_bool("BIFROMQ_DONATE_BUFFERS", True)
+#: device batches in flight at once (double-buffered): the ring's slots,
+#: the pub batcher's batches, and what the capacity model multiplies by.
+PIPELINE_DEPTH = 2
 
 
 class Caller:
@@ -123,12 +105,11 @@ class DispatchRing(BoundedSlots):
     machinery — the same core that gates QoS>0 ingest.
     """
 
-    def __init__(self, depth: Optional[int] = None,
-                 min_floor: Optional[int] = None,
+    def __init__(self, depth: int = PIPELINE_DEPTH,
+                 min_floor: int = MIN_FLOOR,
                  base_floor: int = BASE_FLOOR) -> None:
-        super().__init__(depth if depth is not None else pipeline_depth())
-        self.min_floor = (min_floor if min_floor is not None
-                          else pipeline_min_floor())
+        super().__init__(depth)
+        self.min_floor = min_floor
         self.base_floor = base_floor
         # observability (tests assert overlap through these)
         self.dispatched_total = 0

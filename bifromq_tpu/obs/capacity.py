@@ -127,28 +127,23 @@ def result_bytes(batch: int, max_intervals: int = 32) -> int:
 
 
 def inflight_bytes(batch: int, *, max_levels: int = 16,
-                   max_intervals: int = 32, ring_depth: Optional[int] = None,
-                   donated: Optional[bool] = None) -> Dict[str, int]:
+                   max_intervals: int = 32,
+                   ring_depth: Optional[int] = None) -> Dict[str, int]:
     """Device bytes pinned by the async dispatch ring: ``ring_depth``
     in-flight slots, each holding a probe batch and its result arrays,
     plus ONE prep-ahead probe batch (ISSUE 11: stage-1 prep uploads
     before ring admission; the ring's prep tickets bound it to depth+1,
-    so exactly one extra probe set can be resident). With buffer
-    donation XLA may alias the results into the donated probe buffers,
-    so a slot costs max(probes, results) instead of the sum — the
-    "donated-aliasing double" the non-donated path pays."""
+    so exactly one extra probe set can be resident). The ring's walk
+    donates its probe buffers and XLA may alias the results into them,
+    so a slot costs max(probes, results), not the sum."""
     if ring_depth is None:
-        from ..models.pipeline import pipeline_depth
-        ring_depth = pipeline_depth()
-    if donated is None:
-        from ..models.pipeline import donation_enabled
-        donated = donation_enabled()
+        from ..models.pipeline import PIPELINE_DEPTH
+        ring_depth = PIPELINE_DEPTH
     pb = probe_bytes(batch, max_levels)
     rb = result_bytes(batch, max_intervals)
-    per_slot = max(pb, rb) if donated else pb + rb
+    per_slot = max(pb, rb)
     return {"ring_depth": int(ring_depth), "batch": int(batch),
-            "donated": bool(donated), "probe_bytes": pb,
-            "result_bytes": rb, "per_slot": per_slot,
+            "probe_bytes": pb, "result_bytes": rb, "per_slot": per_slot,
             "prep_ahead_bytes": pb,
             "total": per_slot * int(ring_depth) + pb}
 
@@ -291,7 +286,6 @@ class CapacityPlanner:
              *, batch: int = 16,
              max_levels: int = 16, probe_len: int = 16,
              max_intervals: int = 32, ring_depth: Optional[int] = None,
-             donated: Optional[bool] = None,
              hbm_limit_bytes: Optional[int] = None) -> Dict[str, object]:
         """The planner verdict: would ``n_subs`` subscriptions fit this
         device (or each shard of ``mesh``) — WITHOUT building or
@@ -322,7 +316,7 @@ class CapacityPlanner:
                                      mesh_placed=n_shards > 1)
         flight = inflight_bytes(batch, max_levels=max_levels,
                                 max_intervals=max_intervals,
-                                ring_depth=ring_depth, donated=donated)
+                                ring_depth=ring_depth)
         # a background compaction holds TWO bases alive across the swap
         # (in-flight dispatches pin the old tables) — plan for the peak
         transient = tables["total"]

@@ -6,7 +6,7 @@ serving for seconds), the dispatch queue in front of the device (the
 batcher's backlog is the first thing to grow when the device slows), and
 device memory watermarks. Producers register weakly — a test-scoped
 matcher or scheduler must not be pinned by telemetry — and the snapshot
-is assembled on demand for ``/metrics`` ``"device"`` and ``bench.py``.
+is assembled on demand for ``/metrics`` ``"device"``.
 
 jax is only touched inside a guarded, TTL-cached probe: the gauges must
 stay readable (reporting zeros / unavailability) when the device is

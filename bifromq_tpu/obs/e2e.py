@@ -266,8 +266,8 @@ class E2EPlane:
                 "write_buffer": self.watermark_gauges()}
 
     def qos_rollup(self) -> dict:
-        """Per-qos p50/p99 + violation totals across every tenant/path —
-        the compact shape bench.py stamps into broker-bench records."""
+        """Per-qos p50/p99 + violation totals across every tenant/path,
+        in one compact shape."""
         from .window import N_BUCKETS, percentile_ms_from
         merged: Dict[int, List[int]] = {}
         violations = 0.0

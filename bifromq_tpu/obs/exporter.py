@@ -42,8 +42,7 @@ SCHEMA_VERSION = "bifromq-tpu.telemetry/1"
 # anything else into resourceLogs — so a stock OTLP collector ingests the
 # exporter's stream without a custom shim. The resource envelope
 # (node_id / cluster_id / schema_version) maps onto OTLP resource
-# attributes; scripts/otlp_schema.json pins the emitted shape and the
-# profile_check.sh gate validates against it.
+# attributes; scripts/otlp_schema.json pins the emitted shape.
 # ---------------------------------------------------------------------------
 
 _OTLP_SCOPE = {"name": "bifromq_tpu", "version": SCHEMA_VERSION}

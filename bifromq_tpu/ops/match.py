@@ -256,8 +256,7 @@ def _advance(trie: DeviceTrie, probes: Probes, probe_len: int, b: int,
     statically impossible. Returns (new_act [B, min(2*cap, K)],
     overflowed [B]).
 
-    ``compaction`` picks the compaction strategy (A/B-able on real
-    hardware via the bench's BENCH_COMPACTION knob):
+    ``compaction`` picks the compaction strategy:
     - "sort": per-row descending sort of 2K lanes via a static bitonic
       compare-exchange network (vectorizes on the TPU VPU).
     - "scatter": mask + cumsum + one scatter per row — fewer total ops
@@ -374,7 +373,7 @@ def count_routes(trie: DeviceTrie, result: WalkResult) -> jax.Array:
 def walk_and_count(trie: DeviceTrie, probes: Probes, *, probe_len: int,
                    k_states: int = 32, compaction: str = "sort"
                    ) -> Tuple[WalkResult, jax.Array]:
-    """Fused walk + per-topic fan-out count (bench entry point)."""
+    """Fused walk + per-topic fan-out count."""
     res = walk(trie, probes, probe_len=probe_len, k_states=k_states,
                compaction=compaction)
     return res, count_routes(trie, res)
@@ -1207,8 +1206,8 @@ def expand_routes(ivl: RouteIntervals, slot_peer, *, cap: int,
 
 def bucket_pairs_host(slots: np.ndarray, rows: np.ndarray,
                       slot_peer: np.ndarray, n_peers: int):
-    """Host reference of :func:`_bucket_pairs` (parity oracle + the
-    bench's host-A/B leg): same bucket layout, numpy stable argsort."""
+    """Host reference of :func:`_bucket_pairs` (parity oracle): same
+    bucket layout, numpy stable argsort."""
     slots = np.asarray(slots)
     rows = np.asarray(rows)
     slot_peer = np.asarray(slot_peer)

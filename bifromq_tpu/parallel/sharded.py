@@ -29,10 +29,9 @@ ISSUE 15 makes this a first-class serving plane:
   (donated when the dispatch ring is idle). A churn storm at mesh scale
   runs zero rebuilds and zero match-cache generation bumps; only an
   arena reshape (node growth / edge regrow, pow2-amortized) restacks.
-- **Async serving** — ``supports_async`` is on: the mesh leg rides the
-  shared dispatch-ring/watchdog/profiler machinery (prep-before-
-  admission, fetch-on-ready, tokenize/dispatch/ready/fetch stages
-  stamped per mesh step).
+- **Async serving** — the mesh leg rides the shared dispatch-ring/
+  watchdog/profiler machinery (prep-before-admission, fetch-on-ready,
+  tokenize/dispatch/ready/fetch stages stamped per mesh step).
 - **Per-shard fault domains** — one device breaker per shard on the
   shared board: an open shard's rows serve from the host oracle while
   healthy shards stay on device; half-open re-closes on canary row
@@ -778,21 +777,12 @@ class MeshMatcher(TpuMatcher):
     every TpuMatcher seat (DistWorkerCoProc, DistWorker) and serves live
     add_route/remove_route traffic."""
 
-    # ISSUE 15: the mesh leg now implements the staged serving contract
-    # (_prepare_probes/_dispatch_prepared/_expand_walk), so the shared
-    # async ring + watchdog drive it like the single-chip path
-    supports_async = True
-    # ISSUE 15: per-shard PatchableTrie arenas — mutations fold into the
-    # owning shard(s) in place; BIFROMQ_MESH_PATCH=0 kills back to the
-    # overlay+compaction path
-    supports_patching = True
-
     def __init__(self, tries: Optional[Dict[str, SubscriptionTrie]] = None,
                  mesh: Optional[Mesh] = None, *,
                  max_levels: int = 16, probe_len: int = 16,
                  k_states: int = 32, auto_compact: bool = True,
                  compact_threshold: int = 2048,
-                 match_cache: Optional[bool] = None,
+                 match_cache: bool = True,
                  replicate: Optional[Set[str]] = None) -> None:
         assert mesh is not None, "MeshMatcher requires a mesh"
         super().__init__(max_levels=max_levels, k_states=k_states,

@@ -71,19 +71,13 @@ class Batcher(Generic[CallT, ResultT]):
     IDLE_CAP = 64
 
     def __init__(self, process_batch: BatchFn, *,
-                 pipeline_depth: Optional[int] = 2,
+                 pipeline_depth: int = 2,
                  max_burst_latency: float = 0.010, max_batch_size: int = 8192,
                  min_batch_size: int = 1,
                  stage: Optional[str] = None,
                  obs_key: Optional[str] = None,
                  shallow_decay: bool = True,
                  clock: Callable[[], float] = time.monotonic) -> None:
-        if pipeline_depth is None:
-            # ISSUE 6: one knob rules the whole pipeline — the batcher's
-            # in-flight batches and the matcher's dispatch ring share
-            # BIFROMQ_PIPELINE_DEPTH (double/triple buffering)
-            from ..models.pipeline import pipeline_depth as _env_depth
-            pipeline_depth = _env_depth()
         self._process = process_batch
         self._depth = pipeline_depth
         self._budget = max_burst_latency
@@ -313,7 +307,7 @@ class BatchCallScheduler(Generic[CallT, ResultT]):
     """
 
     def __init__(self, process_batch_for_key: Callable[
-            [Hashable], BatchFn], *, pipeline_depth: Optional[int] = 2,
+            [Hashable], BatchFn], *, pipeline_depth: int = 2,
             max_burst_latency: float = 0.010,
             max_batch_size: int = 8192,
             stage: Optional[str] = None,

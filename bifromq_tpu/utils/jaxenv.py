@@ -2,7 +2,8 @@
 
 Every entry point that compiles device programs (``python -m
 bifromq_tpu``, ``dist.worker_main``, ``kv.store_main``, ``chip_smoke.py``,
-``bench.py``) calls :func:`setup_compile_cache` before its first jit. The
+``benchmarks/sut.py``) calls :func:`setup_compile_cache` before its first
+jit. The
 cache path is part of JAX's cache key, so it must not move between runs:
 it is either wherever the operator points ``JAX_COMPILATION_CACHE_DIR``
 (JAX reads that variable itself — nothing is set in code then) or the

@@ -60,7 +60,7 @@ class StageLatencies:
     """Named per-stage histograms for the publish→match→deliver hot path
     (queue_wait / device / rpc / deliver / ingest + ad-hoc stages). Always
     on — recording is cheap enough to run untraced — so ``/metrics`` and
-    ``bench.py`` get stage breakdowns without sampling."""
+    the benchmark's readers get stage breakdowns without sampling."""
 
     def __init__(self) -> None:
         self._hists: Dict[str, LatencyHistogram] = {}
@@ -211,9 +211,8 @@ class MatchCacheMetrics:
     hits/misses/evictions/epoch-bumps per scope (``"matcher"`` = the
     per-range TpuMatcher caches, ``"pub"`` = the dist service's frontend
     cache) plus the in-batch dedup tally. Served under ``/metrics``
-    ``"match_cache"`` and printed by ``bench.py`` next to the stage
-    breakdown. Thread-safe: range matchers may serve from coproc appliers
-    while the pub cache runs on the loop."""
+    ``"match_cache"``. Thread-safe: range matchers may serve from coproc
+    appliers while the pub cache runs on the loop."""
 
     _FIELDS = ("hits", "misses", "evictions", "epoch_bumps")
 

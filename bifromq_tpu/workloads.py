@@ -217,8 +217,7 @@ def diverse_topics(n: int, *, seed: int = 0,
                    profiles: dict = None) -> List[str]:
     """``n`` topic STRINGS drawn from the tenant profiles above (byte
     plane: the serving path ships strings/bytes, so the generator does
-    too). Deterministic per seed; used by bench config 9 and the
-    ingest tier-2 gate."""
+    too). Deterministic per seed; used by the ingest tier-2 gate."""
     rng = random.Random(seed)
     profs = profiles or TENANT_TOPIC_PROFILES
     names = list(profs)
@@ -257,8 +256,7 @@ def diverse_topics(n: int, *, seed: int = 0,
 # $share worker pools, retained floods, churny connections, reconnect
 # drain storms — and the SLO / noisy-neighbor / shed / cache planes only
 # mean anything under that diversity. `config_mixed` generates one
-# deterministic plan covering all of it; bench config 10 executes the
-# plan leg by leg and reports the per-plane breakdown.
+# deterministic plan covering all of it.
 
 def config_mixed(n_clients: int = 1_000_000, *, seed: int = 0,
                  n_tenants: int = 100, persistent_ratio: float = 0.3,

@@ -23,7 +23,7 @@ import numpy as np
 
 from bifromq_tpu import workloads
 from bifromq_tpu.models.matcher import TpuMatcher
-from bifromq_tpu.models.pipeline import pipeline_depth
+from bifromq_tpu.models.pipeline import PIPELINE_DEPTH
 
 N_SUBS = 20_000
 BIG = 2048
@@ -71,7 +71,7 @@ async def run_pipe():
             lats.append(time.perf_counter() - s0)
 
     await m.match_batch_async(sm[0])    # warm the small shape
-    await asyncio.gather(*[worker() for _ in range(pipeline_depth())])
+    await asyncio.gather(*[worker() for _ in range(PIPELINE_DEPTH)])
     return lats
 
 pipe_lat = asyncio.run(run_pipe())

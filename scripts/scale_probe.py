@@ -3,7 +3,7 @@
 configs HOST-SIDE and record whether the compiled tables fit v5e HBM.
 
 Pure host work — no jax import, no device needed.
-Emits bench_results/r5_scale_probe.json and saves the packed arrays to
+Emits chiprun_out/r5_scale_probe.json and saves the packed arrays to
 /tmp/scale_tables_<cfg>.npz so a later device run (scale_device_run.py)
 can upload without rebuilding (the 10M-sub Python trie build is the slow
 part).
@@ -103,7 +103,8 @@ def probe_c2_10m(n_subs=10_000_000):
 def main():
     which = sys.argv[1:] or ["c5", "c4"]
     out_path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench_results", "r5_scale_probe.json")
+        os.path.abspath(__file__))), "chiprun_out", "r5_scale_probe.json")
+    os.makedirs(os.path.dirname(out_path), exist_ok=True)
     results = {}
     if os.path.exists(out_path):
         with open(out_path) as f:

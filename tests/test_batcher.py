@@ -53,12 +53,17 @@ class TestBatcher:
         # the guard's own case: a cost that grows with the batch, and
         # bursts that leave nobody queued behind them (a fixed cost under
         # a deep queue is test_pipeline's TestAdaptiveSizing)
+        # on the batcher's own clock: under a loaded machine a wall-clock
+        # 0.5 ms sleep stretches until the calls HAVE waited long enough
+        now = [0.0]
+
         async def slow(calls):
-            await asyncio.sleep(0.0005 * len(calls))
+            now[0] += 0.0005 * len(calls)
+            await asyncio.sleep(0)
             return list(calls)
 
         b = Batcher(slow, max_burst_latency=0.001, pipeline_depth=1,
-                    stage="queue_wait")
+                    stage="queue_wait", clock=lambda: now[0])
         start_cap = b.batch_cap
         for _ in range(3):
             # the first leaves alone and at once, the rest as ONE batch

@@ -1,9 +1,8 @@
 """Mesh scale proof (ISSUE 15 tentpole part 4, slow tier).
 
 Builds MESH_SCALE_SUBS logical subscriptions (default 2M here; the full
-10M acceptance run is ``MESH_SCALE_SUBS=10000000`` or ``BENCH_CONFIGS=11
-BENCH_MESH_SUBS=10000000 python bench.py`` — see
-bench_results/mesh_scale record) across the 8-way host mesh, asserts
+10M acceptance run is ``MESH_SCALE_SUBS=10000000``) across the 8-way
+host mesh, asserts
 per-shard ``device_bytes()`` stays under the ``CapacityPlanner.fits``
 per-shard prediction, and serves + patches through the async plane with
 zero rebuilds.

@@ -11,7 +11,8 @@ import pytest
 
 from bifromq_tpu.models.matcher import TpuMatcher
 from bifromq_tpu.models.oracle import Route
-from bifromq_tpu.models.pipeline import DispatchRing
+from bifromq_tpu.models.pipeline import (BASE_FLOOR, MIN_FLOOR,
+                                         PIPELINE_DEPTH, DispatchRing)
 from bifromq_tpu.scheduler.batcher import Batcher
 from bifromq_tpu.types import RouteMatcher
 
@@ -410,6 +411,35 @@ class TestDispatchRing:
         ring.release()
 
 
+def _pub_scheduler_depth():
+    from bifromq_tpu.dist.service import DistService
+    from bifromq_tpu.plugin.events import CollectingEventCollector
+    from bifromq_tpu.plugin.settings import DefaultSettingProvider
+    from bifromq_tpu.plugin.subbroker import SubBrokerRegistry
+    svc = DistService(SubBrokerRegistry(), CollectingEventCollector(),
+                      DefaultSettingProvider(), worker=object())
+    return svc._pub_scheduler.batcher("T")._depth
+
+
+def _capacity_ring_depth():
+    from bifromq_tpu.obs.capacity import inflight_bytes
+    return inflight_bytes(BASE_FLOOR)["ring_depth"]
+
+
+@pytest.mark.parametrize("read, want", [
+    (lambda: DispatchRing().depth, PIPELINE_DEPTH),
+    (_pub_scheduler_depth, PIPELINE_DEPTH),
+    (_capacity_ring_depth, PIPELINE_DEPTH),
+    (lambda: DispatchRing().min_floor, MIN_FLOOR),
+    (lambda: DispatchRing().base_floor, BASE_FLOOR),
+], ids=["ring_depth", "pub_scheduler_depth", "capacity_ring_depth",
+        "idle_pad", "busy_pad"])
+def test_every_reader_of_a_pipeline_size_reads_the_constant(read, want):
+    """The ring, the pub scheduler and the capacity model each hold the
+    pipeline's sizes: one number each, from ``models/pipeline.py``."""
+    assert read() == want
+
+
 # ---------------- matcher async pipeline -----------------------------------
 
 
@@ -570,13 +600,10 @@ class TestMatcherAsync:
         res = await task
         assert _ids(res[0]) == ["r1", "r2"]
 
-    async def test_pipeline_kill_switch(self, matcher, monkeypatch):
-        monkeypatch.setenv("BIFROMQ_PIPELINE", "0")
-        matcher.match_cache.clear()
-        res = await matcher.match_batch_async([("T", ["a", "b"])])
-        assert _ids(res[0]) == ["r1", "r2"]
-        # the sync fallback never touched the ring
-        assert matcher._ring is None or matcher._ring.in_flight == 0
+    async def test_unknown_keyword_is_refused(self, matcher):
+        with pytest.raises(TypeError):
+            await matcher.match_batch_async([("T", ["a", "b"])],
+                                            compaction="scatter")
 
 
 # ---------------- one device walk for callers in line ----------------------

@@ -25,7 +25,7 @@ def _route(filt: str, url: str = "r1") -> Route:
 
 
 def _mk_matcher(n: int = 8, **kw) -> TpuMatcher:
-    m = TpuMatcher(auto_compact=False, match_cache=None, **kw)
+    m = TpuMatcher(auto_compact=False, **kw)
     for i in range(n):
         m.add_route("tenant", _route(f"s/{i}/t"))
     m.add_route("tenant", _route("s/+/t", url="wild"))

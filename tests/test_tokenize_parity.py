@@ -187,7 +187,7 @@ def _canon(rows):
 
 class TestMatcherByteQueries:
     def _matcher(self, **kw):
-        m = TpuMatcher(auto_compact=False, match_cache=None, **kw)
+        m = TpuMatcher(auto_compact=False, **kw)
         for i in range(8):
             m.add_route("tenant", _route(f"s/{i}/t"))
         m.add_route("tenant", _route("s/+/t", url="wild"))
@@ -241,7 +241,7 @@ class TestMatcherByteQueries:
         # force tiny state budget so a wildcard fanout overflows and the
         # escalation re-walk runs against a device-tokenized mirror
         monkeypatch.setenv("BIFROMQ_DEVICE_TOKENIZE", "1")
-        m = TpuMatcher(auto_compact=False, match_cache=None, k_states=2,
+        m = TpuMatcher(auto_compact=False, k_states=2,
                        max_intervals=2)
         for i in range(12):
             m.add_route("tenant", _route(f"f/{i}/+/x", url=f"u{i}"))
@@ -272,8 +272,8 @@ class TestSyncWatchdog:
         the SYNC leg must degrade to the exact oracle within the
         deadline instead of blocking forever."""
         from bifromq_tpu.utils.metrics import FABRIC, FabricMetric
-        # match_cache FALSE (None means default-on): a cache hit would
-        # serve the repeat query without ever dispatching
+        # match_cache FALSE: a cache hit would serve the repeat query
+        # without ever dispatching
         m = TpuMatcher(auto_compact=False, match_cache=False)
         m.add_route("tenant", _route("a/b"))
         m.refresh()
@@ -305,7 +305,7 @@ class TestSyncWatchdog:
         assert _canon(rows) == _canon(m.match_from_tries(qs))
 
     def test_sync_fetch_normal_path_unaffected(self):
-        m = TpuMatcher(auto_compact=False, match_cache=None)
+        m = TpuMatcher(auto_compact=False)
         m.add_route("tenant", _route("a/+"))
         m.refresh()
         qs = [("tenant", "a/z")]
@@ -322,7 +322,7 @@ class TestTransferGuard:
         from bifromq_tpu.analysis import sanitize
         sanitize.assert_guard_arms()
         monkeypatch.setenv("BIFROMQ_DEVICE_TOKENIZE", "1")
-        m = TpuMatcher(auto_compact=False, match_cache=None)
+        m = TpuMatcher(auto_compact=False)
         for i in range(8):
             m.add_route("tenant", _route(f"s/{i}/t"))
         m.refresh()
@@ -366,7 +366,7 @@ class TestValidationParity:
 class TestCalibrate:
     def test_calibrate_report_from_live_base(self):
         from bifromq_tpu.obs.capacity import calibrate_report
-        m = TpuMatcher(auto_compact=False, match_cache=None)
+        m = TpuMatcher(auto_compact=False)
         for i in range(200):
             m.add_route("cal-tenant", _route(f"cal/{i}/+", url=f"r{i}"))
         m.refresh()
