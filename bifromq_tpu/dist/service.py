@@ -22,7 +22,10 @@ from __future__ import annotations
 
 import hashlib
 import random
+from collections import defaultdict
 from dataclasses import dataclass
+from itertools import chain
+from operator import attrgetter
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from ..models.matcher import TpuMatcher
@@ -131,6 +134,77 @@ class GroupFanoutBalancer:
             return {"members": 0, "max": 0, "min": 0}
         vals = list(counts.values())
         return {"members": len(vals), "max": max(vals), "min": min(vals)}
+
+
+_MATCH_INFO = attrgetter("match_info")
+
+
+def _match_infos(routes) -> Tuple[MatchInfo, ...]:
+    """Each route's own ``MatchInfo``; those built here, on a route's
+    first delivery, are booked."""
+    built = Route.match_info.built
+    infos = tuple(map(_MATCH_INFO, routes))
+    if Route.match_info.built != built:
+        trace.count("deliver.match_info.built",
+                    Route.match_info.built - built)
+    return infos
+
+
+def _by_call(routes) -> Dict[Tuple[int, str], List[Route]]:
+    """``routes`` per (broker, deliverer key) ≈ BatchDeliveryCall
+    grouping: calls in order of first appearance, routes in their own
+    order within a call."""
+    by: Dict[Tuple[int, str], List[Route]] = defaultdict(list)
+    for r in routes:
+        by[r.broker_id, r.deliverer_key].append(r)
+    return by
+
+
+class _FanoutPlan:
+    """One target set as its sub-broker calls: ``match_infos`` holds every
+    route's in call order and ``calls`` is one ``(broker_id,
+    deliverer_key, start, end)`` a call into it.
+
+    Flat and small on purpose: a plan is kept beside every cached match
+    result, and whatever it holds of objects, and of pointers to objects,
+    every whole-heap collection walks. The routes in call order are needed
+    only where a call's results are not all ``OK`` and are grouped again
+    from ``source`` there."""
+    __slots__ = ("source", "match_infos", "calls", "n_persistent",
+                 "_routes")
+
+    def __init__(self, source: List[Route], by_infos=None) -> None:
+        if by_infos is None:
+            by = _by_call(source)
+            self.match_infos = _match_infos(chain.from_iterable(by.values()))
+        else:       # call key -> match infos, from ``joined``
+            by = by_infos
+            self.match_infos = tuple(chain.from_iterable(by.values()))
+        self.source = source
+        self._routes: Optional[List[Route]] = None
+        self.calls: List[Tuple[int, str, int, int]] = []
+        start = self.n_persistent = 0
+        for (broker_id, dkey), members in by.items():
+            end = start + len(members)
+            self.calls.append((broker_id, dkey, start, end))
+            if broker_id == PERSISTENT_SUB_BROKER_ID:
+                self.n_persistent += end - start
+            start = end
+
+    def joined(self, extra: List[Route]) -> "_FanoutPlan":
+        """A new plan of ``source + extra``: each extra route at the end
+        of its call, new calls last. This plan stays as it is."""
+        by = {(b, d): list(self.match_infos[s:e])
+              for b, d, s, e in self.calls}
+        for r, mi in zip(extra, _match_infos(extra)):
+            by.setdefault((r.broker_id, r.deliverer_key), []).append(mi)
+        return _FanoutPlan(self.source + extra, by)
+
+    def routes(self, start: int, end: int) -> List[Route]:
+        if self._routes is None:
+            self._routes = list(chain.from_iterable(
+                _by_call(self.source).values()))
+        return self._routes[start:end]
 
 
 class DistService:
@@ -262,8 +336,7 @@ class DistService:
         removed = 0
         for (broker_id, tenant_id), routes in groups.items():
             broker = self.sub_brokers.get(broker_id)
-            mis = [MatchInfo(matcher=r.matcher, receiver_id=r.receiver_id,
-                             incarnation=r.incarnation) for r in routes]
+            mis = [r.match_info for r in routes]
             try:
                 alive = await broker.check_subscriptions(tenant_id, mis)
             except Exception:  # noqa: BLE001
@@ -475,25 +548,23 @@ class DistService:
         settled at once. The grouping and each call are boundaries of
         their own (the calls are the finest grain timed, never one span
         per route); what is left of ``deliver.fanout`` is the fan-out's
-        own time: match infos built, results read back. One group's
-        match infos and results die before the next group's are made: a
-        publish that keeps all 9k of them alive to its end pays a third
-        more, in collections."""
+        own time: results read back. No object is built per route: a
+        route's ``MatchInfo`` lives on the route, and a call whose
+        results are all ``OK`` is settled by counting them."""
         with trace.span("deliver.group"):
-            pack, by_deliverer = self._group_targets(tenant_id, call,
-                                                     matched, topic_s)
-        if not by_deliverer:
+            pack, plan = self._group_targets(tenant_id, call, matched,
+                                             topic_s)
+        if plan is None:
             return 0
         fanout = n_routes = 0
         # cross-broker delivery (≈ mqtt-broker-client deliver RPC): a
         # deliverer key owned by ANOTHER server makes one RPC hop to that
         # broker node, whose local sub-brokers finish it
         remote = self.deliverer_registry is not None and self.server_id
-        for (broker_id, dkey), routes in by_deliverer.items():
-            n_routes += len(routes)
-            match_infos = tuple(
-                MatchInfo(matcher=r.matcher, receiver_id=r.receiver_id,
-                          incarnation=r.incarnation) for r in routes)
+        ok = DeliveryResult.OK
+        for broker_id, dkey, start, end in plan.calls:
+            n_routes += end - start
+            match_infos = plan.match_infos[start:end]
             owner = None
             if remote:
                 from .deliverer import server_of
@@ -519,12 +590,17 @@ class DistService:
                 OBS.record_delivery_violation(tenant_id, 0,
                                               "deliver_error")
                 continue
-            for route, mi in zip(routes, match_infos):
-                outcome = res.get(mi, DeliveryResult.ERROR)
-                if outcome == DeliveryResult.OK:
-                    fanout += 1
-                elif outcome in (DeliveryResult.NO_SUB,
-                                 DeliveryResult.NO_RECEIVER):
+            # a result missing from the reply reads None: an ERROR,
+            # neither counted nor reaped
+            outcomes = list(map(res.get, match_infos))
+            n_ok = outcomes.count(ok)
+            fanout += n_ok
+            if n_ok == len(outcomes):
+                continue
+            trace.count("deliver.settle.slow")
+            for route, outcome in zip(plan.routes(start, end), outcomes):
+                if outcome in (DeliveryResult.NO_SUB,
+                               DeliveryResult.NO_RECEIVER):
                     # dead route cleanup (≈ BatchDeliveryCall NO_SUB handling)
                     await self.worker.remove_route(
                         tenant_id, route.matcher, route.receiver_url,
@@ -537,19 +613,37 @@ class DistService:
     def _group_targets(self, tenant_id: str, call: PubCall,
                        matched: MatchedRoutes, topic_s: str):
         """Election, byte cap and grouping: the message pack and the
-        routes of each sub-broker call, by ``(broker_id, deliverer_key)``.
-        Never yields to the loop."""
+        plan of the sub-broker calls (``None``, ``None`` with nobody to
+        deliver to). Never yields to the loop.
+
+        The plan of ``matched.normal`` is kept on ``matched`` and reused
+        while ``normal`` is still the very list it was built from
+        (whoever REASSIGNS ``normal`` gets a new plan); elected group
+        members and the byte cap are applied per publish, beside it."""
         if matched.max_persistent_fanout_exceeded:
             self.events.report(Event(EventType.PERSISTENT_FANOUT_THROTTLED,
                                      tenant_id, {"topic": topic_s}))
         if matched.max_group_fanout_exceeded:
             self.events.report(Event(EventType.GROUP_FANOUT_THROTTLED,
                                      tenant_id, {"topic": topic_s}))
-        targets: List[Route] = list(matched.normal)
+        normal = matched.normal
+        if not normal and not matched.groups:
+            return None, None
+        plan = matched.fanout_plan
+        if (plan is not None and plan.source is normal
+                and len(plan.match_infos) == len(normal)):
+            trace.count("deliver.plan.reused")
+        else:
+            plan = matched.fanout_plan = _FanoutPlan(normal)
+            trace.count("deliver.plan.built")
+        n_persistent = plan.n_persistent
+        elected: List[Route] = []
         for mqtt_filter, members in matched.groups.items():
-            elected = self._elect(tenant_id, mqtt_filter, members, topic_s)
-            if elected is not None:
-                targets.append(elected)
+            member = self._elect(tenant_id, mqtt_filter, members, topic_s)
+            if member is not None:
+                elected.append(member)
+                if member.broker_id == PERSISTENT_SUB_BROKER_ID:
+                    n_persistent += 1
         # byte-based persistent fan-out cap (≈ MaxPersistentFanoutBytes in
         # DeliverExecutorGroup.java:132), applied over the FULL target set
         # (normal + elected shared-group members — an elected persistent
@@ -559,34 +653,29 @@ class DistService:
         if max_pf_bytes is None:
             max_pf_bytes = Setting.MaxPersistentFanoutBytes.default
         payload_len = len(call.message.payload)
-        n_persistent = sum(1 for r in targets
-                           if r.broker_id == PERSISTENT_SUB_BROKER_ID)
         if payload_len and n_persistent * payload_len > max_pf_bytes:
             allowed = int(max_pf_bytes // payload_len)
             kept: List[Route] = []
             used = 0
-            for r in targets:
+            for r in chain(normal, elected):
                 if r.broker_id != PERSISTENT_SUB_BROKER_ID:
                     kept.append(r)
                 elif used < allowed:
                     kept.append(r)
                     used += 1
-            targets = kept
+            plan = _FanoutPlan(kept)
             self.events.report(Event(
                 EventType.PERSISTENT_FANOUT_BYTES_THROTTLED, tenant_id,
                 {"topic": topic_s, "allowed": allowed}))
-        if not targets:
-            return None, {}
-        # group per (broker, deliverer_key) ≈ BatchDeliveryCall grouping
-        by_deliverer: Dict[Tuple[int, str], List[Route]] = {}
-        for r in targets:
-            by_deliverer.setdefault((r.broker_id, r.deliverer_key),
-                                    []).append(r)
+        elif elected:
+            plan = plan.joined(elected)
+        if not plan.calls:
+            return None, None
         pack = TopicMessagePack(
             topic=topic_s,
             packs=(PublisherMessagePack(publisher=call.publisher,
                                         messages=(call.message,)),))
-        return pack, by_deliverer
+        return pack, plan
 
     def _elect(self, tenant_id: str, mqtt_filter: str,
                members: List[Route], topic: str) -> Optional[Route]:

@@ -19,10 +19,37 @@ Roles:
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, Iterable, List, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
-from ..types import RouteMatcher, RouteMatcherType
+from ..types import MatchInfo, RouteMatcher, RouteMatcherType
 from ..utils import topic as topic_util
+
+
+class _kept:
+    """``functools.cached_property`` for a million frozen instances: the
+    value is stored with ``object.__setattr__`` and so stays among the
+    instance's inline attribute values, where ``cached_property`` would
+    materialise a ``__dict__`` (one more object for every collection to
+    walk) on each of them. CPython 3.12 has room there for ONE attribute
+    beyond those ``__init__`` set; a second one materialises the dict
+    all the same. Not a data descriptor: once stored, the instance's own
+    attribute is what a read finds. ``built`` counts the values built."""
+
+    def __init__(self, build) -> None:
+        self.build = build
+        self.built = 0
+        self.__doc__ = build.__doc__
+
+    def __set_name__(self, owner, name: str) -> None:
+        self.name = name
+
+    def __get__(self, obj, owner=None):
+        if obj is None:
+            return self
+        value = self.build(obj)
+        object.__setattr__(obj, self.name, value)
+        self.built += 1
+        return value
 
 
 @dataclass(frozen=True)
@@ -43,6 +70,15 @@ class Route:
     @property
     def receiver_url(self) -> Tuple[int, str, str]:
         return (self.broker_id, self.receiver_id, self.deliverer_key)
+
+    @_kept
+    def match_info(self) -> MatchInfo:
+        """The ONE ``MatchInfo`` sub-brokers see for this route: it never
+        changes while the route lives, so it is built on the first
+        delivery and kept on the (frozen) instance. A replaced route (a
+        new incarnation) is a new object with its own."""
+        return MatchInfo(matcher=self.matcher, receiver_id=self.receiver_id,
+                         incarnation=self.incarnation)
 
 
 class _TrieNode:
@@ -83,6 +119,10 @@ class MatchedRoutes:
     persistent_fanout: int = 0
     max_persistent_fanout_exceeded: bool = False
     max_group_fanout_exceeded: bool = False
+    # the fan-out's grouped form of ``normal`` (dist/service.py keeps it
+    # here so a cached result that comes back is not grouped again)
+    fanout_plan: Optional[object] = field(default=None, repr=False,
+                                          compare=False)
 
     def all_routes(self) -> List[Route]:
         out = list(self.normal)

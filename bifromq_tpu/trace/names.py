@@ -112,6 +112,20 @@ _row("deliver.call", "span", "dist/service.py",
      "fan-out's own time (grouping, match infos built, results read back)")
 _row("deliver.routes", "counter", "dist/service.py",
      "routes handed to sub-brokers (beside `deliver.fanout`)")
+_row("deliver.match_info.built", "counter", "dist/service.py",
+     "routes delivered to for the FIRST time: their `MatchInfo` was built "
+     "and is kept on the route. Against `deliver.routes` it is the share "
+     "that reused it")
+_row("deliver.plan.built", "counter", "dist/service.py",
+     "publishes whose routes were grouped anew (a fresh match, or "
+     "`normal` reassigned)")
+_row("deliver.plan.reused", "counter", "dist/service.py",
+     "publishes that found the grouped plan kept on their "
+     "`MatchedRoutes` (pub-cache or matcher-cache hit, a topic twice in "
+     "one pub batch)")
+_row("deliver.settle.slow", "counter", "dist/service.py",
+     "sub-broker calls whose results were not all `OK` and were settled "
+     "route by route (dead routes reaped there)")
 _row("deliver.local_fanout", "span", "mqtt/localrouter.py",
      "local-router re-fan-out of one shared route to its sessions")
 _row("deliver.transient", "span", "mqtt/session.py",

@@ -131,6 +131,24 @@ class MatchInfo:
     receiver_id: str
     incarnation: int = 0
 
+    def __hash__(self) -> int:
+        # the dataclass's own hash, computed once: a route's MatchInfo is
+        # hashed by the sub-broker's result dict and again when the
+        # fan-out reads it back, on every publish. Equality is the
+        # dataclass's, so one decoded off the wire equals and hashes alike.
+        try:
+            return self._hash
+        except AttributeError:
+            h = hash((self.matcher, self.receiver_id, self.incarnation))
+            object.__setattr__(self, "_hash", h)
+            return h
+
+    def __getstate__(self) -> dict:
+        # str hashes are salted per process: the kept hash stays here
+        state = dict(self.__dict__)
+        state.pop("_hash", None)
+        return state
+
 
 @dataclass(frozen=True)
 class TopicFilterOption:

@@ -441,6 +441,12 @@ class TestOneTimingPerBoundary:
             twins["deliver"].sum_s)
         assert got["pub.ingest"][1] == pytest.approx(twins["ingest"].sum_s)
         assert got["deliver.routes"][0] == n_pubs     # one subscriber each
+        # its one route's MatchInfo was built on the first delivery and
+        # reused by the six after; seven topics, seven grouped plans
+        assert got["deliver.match_info.built"][0] == 1
+        assert got["deliver.plan.built"][0] == n_pubs
+        assert "deliver.plan.reused" not in got
+        assert "deliver.settle.slow" not in got
         assert got["loop.lag"][0] >= 1
 
     def test_sampling_off_builds_no_span(self, monkeypatch):
