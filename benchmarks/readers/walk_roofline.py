@@ -4,8 +4,11 @@ import roofline
 import trace_reduce
 
 # jitted names as the v5e trace shows them (PR 28): ``_walk_routes_fn``,
-# ``_expand_routes_fn``; a later walk keeps a name that starts alike
-PROGRAMS = ("_walk_routes", "walk_routes", "_expand_routes", "expand_routes")
+# ``_expand_routes_fn``; a later walk keeps a name that starts alike. The
+# mesh's step and expand are the shard_map'd ``local_step`` / ``local_expand``
+# (PR 38); their device time is summed over every chip's plane.
+PROGRAMS = ("_walk_routes", "walk_routes", "_expand_routes", "expand_routes",
+            "local_step", "local_expand")
 
 
 def read(ctx):

@@ -10,6 +10,13 @@ topics, so it reads the same whatever program implements the walk:
         + walked topics x one probe row
 
 A table's row widths are read off the resident device arrays.
+
+On a mesh the least bytes are the same: a topic is walked on the one chip
+that holds its tenant. The share is chip-seconds needed over chip-seconds
+spent: the bytes over ONE chip's bandwidth, against the programs' device
+time summed over every chip's plane. A chip whose shard had no row in a
+batch still runs the step and its time counts, so the share reaches the
+roof of all the chips only where the rows spread evenly over them.
 """
 
 from __future__ import annotations

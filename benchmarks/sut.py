@@ -200,15 +200,32 @@ def build_tries(rows):
 def seed_worker(worker, tries) -> dict:
     """Give the started worker the state a restarted one holds: the
     route keyspace in its range's KV space, and the matcher derived from
-    it, built in bulk by ``TpuMatcher.from_tries``. (``matcher_factory``
+    it, built in bulk by ``from_tries``. (``matcher_factory``
     cannot carry it: the range's ``reset`` on open replaces whatever
-    matcher the factory made with ``clone_empty()``.)"""
+    matcher the factory made with ``clone_empty()``.)
+
+    What is seeded is what the broker STARTED: where the co-processor's
+    matcher runs on a mesh (``"dist": {"mesh": true}`` in the
+    configuration's ``broker``), a matcher of that class on that very
+    mesh with the started one's parameters; otherwise one chip's
+    ``TpuMatcher``. Read off the started object, not off a key."""
     import jax
     from bifromq_tpu.kv import schema
     from bifromq_tpu.models.matcher import TpuMatcher
     (rid, coproc), = worker.store.coprocs.items()
+    started = coproc.matcher
+    mesh = getattr(started, "mesh", None)
     t0 = time.perf_counter()
-    matcher = TpuMatcher.from_tries(tries, device=jax.devices()[0])
+    if mesh is None:
+        matcher = TpuMatcher.from_tries(tries, device=jax.devices()[0])
+    else:
+        matcher = type(started).from_tries(
+            tries, mesh=mesh, max_levels=started.max_levels,
+            probe_len=started.probe_len, k_states=started.k_states,
+            auto_compact=started.auto_compact,
+            compact_threshold=started.compact_threshold,
+            match_cache=started.match_cache is not None,
+            replicate=set(started._replicas))
     t_build = time.perf_counter() - t0
     coproc.matcher = matcher
     coproc._wire_repl_hooks()
@@ -225,6 +242,39 @@ def seed_worker(worker, tries) -> dict:
     coproc._fact_dirty = True
     return {"from_tries_s": t_build, "kv_fill_s": time.perf_counter() - t0,
             "matcher": matcher}
+
+
+def warm_patch_programs(matcher) -> int:
+    """A mesh builds one scatter program a shard, a table and a donation
+    mode (``parallel/sharded.py``: ``shard`` is a static argument), each on
+    its first use, and warms none of them itself; the live churn would
+    meet up to 16 compiles inside the window. Run each once now, as the
+    flush does, on row 0 of every shard with the row's own content: the
+    tables come out as they went in. Nothing to do on one chip, whose
+    matcher warms its own. Only with nothing in flight (it donates)."""
+    shards = _shards(matcher._base_ct)
+    if shards is None:
+        return 0
+    import functools
+
+    import jax
+    import numpy as np
+    from bifromq_tpu.ops.match import _PATCH_CHUNK, route_cols_from_node_tab
+    from bifromq_tpu.parallel import sharded
+    put = functools.partial(jax.device_put, device=matcher._repl_sharding)
+    rows_np = np.zeros(_PATCH_CHUNK, np.int32)
+    edge, child, route = matcher._device_trie
+    for sh, pt in enumerate(shards):
+        idx = put(rows_np)
+        vals = put(route_cols_from_node_tab(pt.node_tab[rows_np]))
+        sharded._shard_scatter(route, idx, vals, shard=sh)   # a copy, dropped
+        route = sharded._shard_scatter_donated(route, idx, vals, shard=sh)
+        vals = put(pt.edge_tab[rows_np])
+        sharded._shard_scatter(edge, idx, vals, shard=sh)
+        edge = sharded._shard_scatter_donated(edge, idx, vals, shard=sh)
+    jax.block_until_ready((edge, route))
+    matcher._device_trie = (edge, child, route)
+    return 4 * len(shards)
 
 
 # -------------------------------------------------------------- counters
@@ -281,6 +331,13 @@ def counters(matcher, stand_in) -> dict:
     out["fleet.total"] = stand_in.total
     out["fleet.calls"] = stand_in.calls
     out["fleet.spent_s"] = stand_in.spent_s
+    shards = _shards(matcher._base_ct)
+    if shards is not None:       # a mesh: rows routed to each shard so far
+        rows = [0] * len(shards)
+        shard_of = matcher._base_ct.shard_of
+        for tenant_id, n in matcher.query_heat.items():
+            rows[shard_of(tenant_id)] += n
+        out["mesh.rows_each"] = rows
     return out
 
 
@@ -316,40 +373,84 @@ class BatchDrain:
             self.stamps.append((r.ts, r.n_queries))
 
 
+MESH_TABLES = ("edge_tab", "child_list", "route_tab")
+
+
 def device_state(matcher, platform: str) -> dict:
-    """Where the resident tables are, and their record widths."""
+    """Where the resident tables are, and their record widths. One chip
+    holds a ``DeviceTrie``; a mesh holds ``MESH_TABLES`` stacked, the
+    leading axis the shard. ``resident_bytes`` is the fullest device's;
+    ``each_on`` the fewest devices any one table lies on."""
     import jax
     dev = matcher._device_trie
+    stacked = not hasattr(dev, "edge_tab")
+    named = dict(zip(MESH_TABLES, dev)) if stacked else \
+        {n: getattr(dev, n, None) for n in MESH_TABLES + ("node_tab",)}
     leaves = [a for a in jax.tree_util.tree_leaves(dev) if a is not None]
-    on = set()
+    on, per_device = set(), {}
     for a in leaves:
         on |= set(a.devices())
+        for sh in a.addressable_shards:
+            per_device[sh.device] = per_device.get(sh.device, 0) \
+                + int(sh.data.nbytes)
     widths = {}
     for name in ("edge_tab", "child_list", "route_tab", "node_tab"):
-        a = getattr(dev, name, None)
+        a = named.get(name)
         if a is not None and getattr(a, "ndim", 0) >= 1:
             row = int(a.dtype.itemsize)
-            for d in a.shape[1:]:
+            for d in a.shape[2 if stacked else 1:]:
                 row *= int(d)
             widths[name] = row
-    return {"resident_bytes": sum(int(a.nbytes) for a in leaves),
+    return {"resident_bytes": max(per_device.values(), default=0),
+            "bytes_each": [per_device[d] for d in
+                           sorted(per_device, key=lambda d: d.id)],
             "on": sorted(str(d) for d in on),
             "all_on_platform": all(d.platform == platform for d in on),
-            "n_devices": len(on), "record_bytes": widths}
+            "n_devices": len(on),
+            "each_on": min((len(a.devices()) for a in leaves), default=0),
+            "record_bytes": widths}
+
+
+def _shards(base):
+    """The per-shard host arenas of a mesh's base, else ``None``."""
+    return getattr(base, "compiled", None)
 
 
 def table_shapes(matcher) -> tuple:
     """Shapes of the host arenas the next flush ships: a change means the
-    device tables reshape and the walk re-traces."""
+    device tables reshape and the walk re-traces. On a mesh, the shapes
+    the shards' arenas stack to (every shard padded to the largest)."""
     base = matcher._base_ct
-    return tuple(tuple(getattr(base, n).shape)
-                 for n in ("node_tab", "edge_tab", "child_list"))
+    names = ("node_tab", "edge_tab", "child_list")
+    shards = _shards(base)
+    if shards is None:
+        return tuple(tuple(getattr(base, n).shape) for n in names)
+    return tuple((len(shards),) + tuple(
+        max(dims) for dims in zip(*(getattr(pt, n).shape for pt in shards)))
+        for n in names)
 
 
 def table_fill(matcher) -> dict:
     base = matcher._base_ct
-    return {k: int(getattr(base, k, -1)) for k in
-            ("n_live", "child_used", "edge_regrows", "node_grows")}
+    keys = ("n_live", "child_used", "edge_regrows", "node_grows")
+    shards = _shards(base)
+    if shards is None:
+        return {k: int(getattr(base, k, -1)) for k in keys}
+    return {k: [int(getattr(pt, k, -1)) for pt in shards] for k in keys}
+
+
+def host_rss_bytes() -> dict:
+    """This process's resident set now (``VmRSS``) and at its highest
+    (``VmHWM``), from ``/proc/self/status``; ``{}`` where there is none."""
+    out = {}
+    try:
+        with open("/proc/self/status") as f:
+            for line in f:
+                if line.startswith(("VmRSS:", "VmHWM:")):
+                    out[line[:5]] = int(line.split()[1]) * 1024
+    except OSError:
+        pass
+    return out
 
 
 def memory_peak_bytes() -> int:

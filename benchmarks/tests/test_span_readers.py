@@ -83,8 +83,12 @@ def test_every_new_metric_has_its_file_and_entry():
             with open(os.path.join(BENCH_DIR, "layer_metrics",
                                    f"{name}.{spelling}.json")) as f:
                 spec = json.load(f)
-            entry = entries[f"{name}.{spelling}"]
+            entry = dict(entries[f"{name}.{spelling}"])
             assert spec.pop("reader") == name
+            # a later PR that adds a cell lists it in BENCHMARK.json and
+            # may not edit the metric's own file: the file names the
+            # cells the metric came with, the entry those and the later
+            assert set(spec.pop("workloads")) <= set(entry.pop("workloads"))
             assert spec == entry and entry["moves"] == moves
 
 

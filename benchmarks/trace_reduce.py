@@ -11,8 +11,10 @@ same clock.
 
 Busy is the union of the intervals of the ``XLA Ops`` line (of ``XLA
 Modules`` where a plane has no ops line), per device plane, averaged over
-the device planes. A reader that finds no device plane returns ``None``:
-a CPU trace never yields a device number.
+the device planes (``busy_each`` keeps each plane's). ``collective_s`` is
+the summed device time, over all planes, of the operations that cross
+chips. A reader that finds no device plane returns ``None``: a CPU trace
+never yields a device number.
 """
 
 from __future__ import annotations
@@ -26,6 +28,9 @@ DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+$")
 OPS_LINES = ("XLA Ops",)
 MODULE_LINES = ("XLA Modules",)
 _FINGERPRINT = re.compile(r"\(\d+\)$")
+# HLO names of the operations that cross chips, as an ops line shows them
+COLLECTIVE = re.compile(r"^%?(collective-permute|all-reduce|all-gather|"
+                        r"all-to-all|reduce-scatter)")
 
 
 def program_name(event_name: str) -> str:
@@ -125,7 +130,9 @@ def reduce_trace(path: str, window_s: float) -> Optional[dict]:
     top_ops = sorted(ops.items(), key=lambda kv: -kv[1])[:10]
     top_ops = [(n[:120], s) for n, s in top_ops]
     return {"busy_s": busy_s, "window_s": window_s,
-            "device_planes": len(busy_each),
+            "device_planes": len(busy_each), "busy_each": busy_each,
+            "collective_s": sum(s for n, s in ops.items()
+                                if COLLECTIVE.match(n)),
             "programs": {p: {"seconds": c[0], "calls": c[1]}
                          for p, c in programs.items()},
             "device_ops": [[n, s] for n, s in top_ops],
