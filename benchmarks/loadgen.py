@@ -62,6 +62,7 @@ class Run:
         self.warm_seq = WARM_FLAG
         self.t0 = self.t1 = 0
         self.errors = []
+        self.notes = []              # the first missing deliveries, for the log
 
     # ---------------- connections -------------------------------------
     def _on_publish(self, client, topic, payload, qos, t_ns) -> None:
@@ -246,7 +247,10 @@ class Run:
             batch = filters[r * n:(r + 1) * n]
 
             async def one(k, flt):
-                c = self.sub_clients[self.plan["n_taps"] + k % n_gen]
+                idx = self.plan["n_taps"] + k % n_gen
+                if flt == self.plan["subs"][idx][1]:
+                    return      # not fresh: the client's own filter, which
+                c = self.sub_clients[idx]    # the UNSUBSCRIBE would end
                 await c.subscribe(flt, 0)
                 await c.unsubscribe(flt)
             await self._gather_limited(one(k, f) for k, f in enumerate(batch))
@@ -274,17 +278,64 @@ class Run:
                 last[conn] = rec[7]
             rec.append(rec[7] if rec[3] == 1 else last.get(conn, 0))
 
-    def expectations(self, fence_ns: dict):
-        """Per (client, seq): (must, may) counts of matching live
-        subscriptions, by the guarantees of the configuration."""
-        by_tenant = {}
+    def lifetimes(self) -> list:
+        """One record a LIFETIME of a (client, filter) subscription. A
+        SUBSCRIBE of a filter the client already holds replaces that
+        subscription ([MQTT-3.8.4-3]: it goes on, perhaps at another QoS)
+        and the first UNSUBSCRIBE ends it, whichever record asked: the
+        churn may draw a client's own filter. ``grants`` holds every
+        (request, SUBACK, QoS) of the lifetime."""
+        events = []
         for s in self.subscriptions:
+            events.append((s["sub_req"], 0, s))
+            if s["unsub_req"]:
+                events.append((s["unsub_req"], 1, s))
+        events.sort(key=lambda e: e[:2])
+        held, out = {}, []
+        for _t, kind, s in events:
+            key = (s["client"], s["filter"])
+            cur = held.get(key)
+            if kind == 0 and cur is None:
+                cur = held[key] = dict(s, unsub_req=0, unsuback=0, grants=[])
+                out.append(cur)
+            if kind == 0:
+                cur["grants"].append((s["sub_req"], s["suback"], s["qos"]))
+            elif cur is not None:
+                cur["unsub_req"], cur["unsuback"] = (s["unsub_req"],
+                                                     s["unsuback"])
+                del held[key]
+        return out
+
+    @staticmethod
+    def _granted(grants: list, qos: int, sent: int, done: int) -> set:
+        """The QoS a delivery of this publish may come at: the grant that
+        stood, or either one around a replacing SUBSCRIBE."""
+        if len(grants) == 1:
+            return {min(qos, grants[0][2])}
+        out = set()
+        for i, (req, _ack, q) in enumerate(grants):
+            nxt = grants[i + 1] if i + 1 < len(grants) else None
+            if (done and done < req) or (nxt and nxt[1] and sent > nxt[1]):
+                continue
+            out.add(min(qos, q))
+        return out
+
+    def expectations(self, fence_ns: dict):
+        """Per (client, seq): [must, may, QoS set, groups]: counts of
+        matching live subscriptions by the guarantees of the configuration.
+        A SHARED subscription (``$share`` / ``$oshare``) is never a must:
+        its group elects one member a publish, so it may bring 0 or 1
+        (counted under ``may``; never after its UNSUBACK was followed by
+        the send, never before its request) and is listed under ``groups``
+        as (group filter, firm), firm where it certainly stood."""
+        by_tenant = {}
+        for s in self.lifetimes():
             by_tenant.setdefault(s["tenant"], []).append(s)
         levels_of = [t.decode().split("/") for t in self.topics]
         expect = {}
         for rec in self.pubs:
             seq, tenant, topic, qos, conn, _due, sent, _ack, done = rec
-            done = done or fence_ns.get(conn, 0)
+            done = rec[8] = done or fence_ns.get(conn, 0)
             for s in by_tenant.get(tenant, ()):
                 if not reference.filter_matches(s["levels"], levels_of[topic]):
                     continue
@@ -296,9 +347,13 @@ class Run:
                     continue          # must not
                 else:
                     kind = 1          # in flight around the (un)subscribe
-                e = expect.setdefault((s["client"], seq), [0, 0, set()])
-                e[kind] += 1
-                e[2].add(min(qos, s["qos"]))
+                e = expect.setdefault((s["client"], seq), [0, 0, set(), []])
+                if s["group"] is None:
+                    e[kind] += 1
+                else:
+                    e[1] += 1
+                    e[3].append([s["filter"], kind == 0])
+                e[2] |= self._granted(s["grants"], qos, sent, done)
         return expect
 
     async def finish(self) -> dict:
@@ -334,6 +389,18 @@ class Run:
         drain_end = now_ns()
         return self.verdict(expect, drain_end)
 
+    def _describe_missing(self, key) -> str:
+        client, seq = key
+        rec = self.pubs[seq]
+        subs = [(s["filter"], s["sub_req"] - self.t0, s["suback"] - self.t0,
+                 s["unsub_req"] and s["unsub_req"] - self.t0,
+                 s["unsuback"] and s["unsuback"] - self.t0)
+                for s in self.subscriptions if s["client"] == client]
+        return (f"missing: client {client} seq {seq} topic "
+                f"{self.topics[rec[2]].decode()} sent {rec[6] - self.t0} "
+                f"done {rec[8] - self.t0}; its subscriptions (filter, "
+                f"sub_req, suback, unsub_req, unsuback; ns from t0): {subs}")
+
     def verdict(self, expect: dict, drain_end: int) -> dict:
         pubs = self.pubs
         # Latency samples are the deliveries to the TAPS: one "#" subscriber
@@ -366,13 +433,19 @@ class Run:
             last_seq[okey] = max(seq, last_seq.get(okey, -1))
         missing = unexpected = surplus = 0
         lost_qos0 = 0
-        for key, (must, may, _q) in expect.items():
+        receipts = []         # what the shared subscriptions may have got
+        for key, (must, may, _q, groups) in expect.items():
             got = counts.get(key, 0)
+            if groups:
+                receipts.append([key[0], key[1], got, must,
+                                 may - len(groups), groups])
             if must and not got:
                 if pubs[key[1]][3] == 0:
                     lost_qos0 += 1        # at most once: a loss, not a fault
                 else:
                     missing += 1
+                    if len(self.notes) < 8:
+                        self.notes.append(self._describe_missing(key))
                 if key[0] < n_taps:
                     lat.append((drain_end - pubs[key[1]][5]) / 1e6)
             if got > must + may:
@@ -385,7 +458,7 @@ class Run:
         sub_ms = [(s["suback"] - s["sub_req"]) / 1e6
                   for s in self.subscriptions
                   if s.get("churn") and s["suback"]]
-        return {
+        report = {
             "event": "report", "t0_ns": self.t0, "t1_ns": self.t1,
             "publishes": [r[:8] for r in pubs],
             "latencies_ms": lat, "gen_late_ms": late,
@@ -396,12 +469,22 @@ class Run:
             "live_surplus": surplus, "live_lost_qos0": lost_qos0,
             "order_violations": order_viol, "qos_violations": qos_viol,
             "unacked_qos1": unacked, "errors": self.errors[:20],
+            "notes": self.notes,
             "n_errors": len(self.errors),
             "connections": len(self.sub_clients) + len(self.conn_index),
             "churn_subs": len(sub_ms),
             "churn_unsubs": sum(1 for s in self.subscriptions
                                 if s["unsuback"]),
         }
+        shared = [s for s in self.lifetimes() if s["group"] is not None]
+        if shared:            # the live half of "one member a group": run.py
+            report["shared"] = {    # closes the sum with the stand-in's half
+                "subs": [[s["client"], s["tenant"], s["filter"], s["sub_req"],
+                          s["suback"], s["unsub_req"], s["unsuback"]]
+                         for s in shared],
+                "receipts": receipts,
+                "done_ns": [r[8] for r in pubs]}
+        return report
 
     # ---------------- one window --------------------------------------
     async def window(self) -> dict:
@@ -421,7 +504,7 @@ class Run:
     def reset(self, plan: dict) -> None:
         """A further window on the same connections (sweep, many seeds)."""
         self.plan = plan
-        self.received, self.pubs = [], []
+        self.received, self.pubs, self.notes = [], [], []
         self.subscriptions = [s for s in self.subscriptions
                               if not s["unsuback"]]
 

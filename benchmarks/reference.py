@@ -12,6 +12,11 @@ forms of the same semantics ([MQTT-4.7.1], [MQTT-4.7.2-1], [MQTT-4.8.2]):
 
 ``$share/<group>/<filter>`` and ``$oshare/...``: the filter behind the
 prefix matches as usual and ONE member of each matching group receives.
+A group is its whole filter string: ``$share/g/a/+`` and ``$oshare/g/a/+``
+are two groups, and so are ``$share/g/a/+`` and ``$share/g/a/#``.
+``Table.add`` files a row whose levels begin with a share prefix under
+its group; ``Table.match`` gives the plain rows, ``Table.match_groups``
+every matching group with its member rows.
 """
 
 from __future__ import annotations
@@ -64,14 +69,50 @@ def generalisations(topic_levels: Sequence[str]) -> Iterator[Tuple[str, ...]]:
     yield from product(*options)
 
 
+def is_shared(levels: Sequence[str]) -> bool:
+    """Do these filter levels begin with a share prefix and a group?"""
+    return len(levels) >= 3 and levels[0] in SHARE_PREFIXES
+
+
 class Table:
-    """Rows per tenant, keyed by filter levels."""
+    """Rows per tenant, keyed by filter levels; a shared row (its levels
+    begin ``$share`` / ``$oshare``, group name) by the levels behind the
+    prefix, under its group."""
 
     def __init__(self) -> None:
         self._rows: Dict[str, Dict[Tuple[str, ...], List[tuple]]] = {}
+        # tenant -> levels behind the prefix -> "<prefix>/<name>" -> rows
+        self._groups: Dict[str, Dict[Tuple[str, ...],
+                                     Dict[str, List[tuple]]]] = {}
+        self.n_groups = 0
 
     def add(self, tenant: str, levels: Tuple[str, ...], row: tuple) -> None:
+        if is_shared(levels):
+            group, rest = split_filter("/".join(levels))
+            members = self._groups.setdefault(tenant, {}).setdefault(
+                rest, {}).setdefault(group, [])
+            self.n_groups += not members
+            members.append(row)
+            return
         self._rows.setdefault(tenant, {}).setdefault(levels, []).append(row)
+
+    def members(self, tenant: str, group: str,
+                levels: Tuple[str, ...]) -> List[tuple]:
+        """The member rows of one group (``"<prefix>/<name>"``, levels)."""
+        return self._groups.get(tenant, {}).get(levels, {}).get(group, [])
+
+    def match_groups(self, tenant: str,
+                     topic: str) -> List[Tuple[str, List[tuple]]]:
+        """Every shared group whose filter matches ``topic``: (the group's
+        whole filter string, its member rows)."""
+        groups = self._groups.get(tenant)
+        if not groups:
+            return []
+        out = []
+        for g in generalisations(topic.split("/")):
+            for group, members in groups.get(g, {}).items():
+                out.append((f"{group}/{'/'.join(g)}", members))
+        return out
 
     def match(self, tenant: str, topic: str) -> List[tuple]:
         """All rows whose filter matches ``topic`` (one per matching row)."""

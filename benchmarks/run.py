@@ -17,6 +17,12 @@ metric); ``--sweep r1,r2,..`` and ``--seeds s1,s2,..`` run several
 windows on one set-up and print a line for each (``--trace 0`` only);
 ``--control <name>`` puts a broken guarantee in the matcher's place (see
 ``sut.CONTROLS``).
+
+The comparison: plain routes by count and digest a publish
+(``fleet_verdict``); deliveries a shared group ELECTED, which are the
+program's choice, by the guarantee "exactly one member a matching group",
+summed over the stand-in and the live sessions (``group_verdict``; only in
+cells whose table or traffic holds a ``$share`` / ``$oshare`` group).
 """
 
 from __future__ import annotations
@@ -30,6 +36,7 @@ import asyncio  # noqa: E402
 import gc  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
+import logging  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import statistics  # noqa: E402
@@ -90,6 +97,20 @@ class LoadGen:
                 await self.proc.wait()
 
 
+class Warnings(logging.Handler):
+    """The program's warnings (a degraded match names its exception only
+    there), kept for the run's log: the first few, and a count."""
+
+    def __init__(self, keep: int = 8) -> None:
+        super().__init__(logging.WARNING)
+        self.keep, self.n, self.first = keep, 0, []
+
+    def emit(self, record) -> None:
+        self.n += 1
+        if len(self.first) < self.keep:
+            self.first.append(f"{record.name}: {record.getMessage()}"[:600])
+
+
 # ------------------------------------------------------------ the reference
 
 class FleetReference:
@@ -102,10 +123,25 @@ class FleetReference:
         for tenant, levels, rid, dkey in rows:
             self.table.add(tenant, levels, (rid, dkey))
             if with_prefixes:
+                if reference.is_shared(levels):     # the trie holds what is
+                    levels = levels[2:]             # behind the prefix
                 seen = self.prefixes.setdefault(tenant, set())
                 for k in range(len(levels) + 1):
                     seen.add(levels[:k])
+        self.shared = self.table.n_groups > 0
         self._cache = {}
+        self._groups = {}
+
+    def groups(self, tenant: str, topic: str) -> dict:
+        """Every seeded group that matches: {group filter: its members as a
+        set of (receiver id, deliverer key)}."""
+        key = (tenant, topic)
+        hit = self._groups.get(key)
+        if hit is None:
+            hit = self._groups[key] = {
+                flt: frozenset(members) for flt, members
+                in self.table.match_groups(tenant, topic)}
+        return hit
 
     def expect(self, tenant: str, topic: str):
         key = (tenant, topic)
@@ -134,6 +170,155 @@ class FleetReference:
         return n + len(frontier)
 
 
+def live_groups(report) -> dict:
+    """The load generator's half of "one member a group": for every
+    (publish, group) a live session's shared subscription may have been
+    elected for, ``[lo, hi, firm, who, band]``: the fewest and the most
+    live deliveries that can be the group's (a client's receipts beyond
+    its plain subscriptions' musts, less what its in-flight plain ones may
+    explain), whether a live member certainly stood, the clients that
+    certainly got one, and whether a live member was in flight around its
+    SUBACK or UNSUBACK."""
+    out = {}
+    for client, seq, got, must, may_plain, groups in \
+            report.get("shared", {}).get("receipts", ()):
+        extra = max(0, got - must)
+        hi = min(len(groups), extra)
+        lo = min(len(groups), max(0, extra - may_plain))
+        for flt, firm in groups:
+            e = out.setdefault((seq, flt), [0, 0, False, [], False])
+            e[0] += max(0, lo - (len(groups) - 1))
+            e[1] += min(1, hi)
+            e[2] = e[2] or firm
+            e[4] = e[4] or not firm
+            if lo == len(groups):
+                e[3].append(client)
+    return out
+
+
+def membership_bands(report, tenants) -> dict:
+    """(tenant, group filter) -> the (request, ack) bands in which a live
+    session joined or left the group, in time order."""
+    bands = {}
+    for _c, t, flt, sub_req, suback, unsub_req, unsuback in \
+            report.get("shared", {}).get("subs", ()):
+        b = bands.setdefault((tenants[t], flt), [])
+        b.append((sub_req, suback or 1 << 62))
+        if unsub_req:
+            b.append((unsub_req, unsuback or 1 << 62))
+    for b in bands.values():
+        b.sort()
+    return bands
+
+
+def group_verdict(stand_in, report, plan, ref: FleetReference) -> dict:
+    """Elected deliveries against the plain reference: for every window
+    publish and every group the reference (seeded members) or the load
+    generator (live members) says matches, EXACTLY ONE delivery over both
+    processes, the stand-in's to a member of that group under the member's
+    own deliverer key; none under any other group. A publish in flight
+    around a live member's SUBACK or UNSUBACK may have elected that member
+    and fallen with it (the configuration's fourth guarantee: either way):
+    counted, not a fault. ``$oshare``: between two membership changes of a
+    group, one topic's publishes go to one member."""
+    tenants, pop = plan["tenants"], plan["population"]
+    pubs = report["publishes"]
+    live = live_groups(report)
+    live_at = {}                        # seq -> the groups live members hold
+    for seq, flt in live:
+        live_at.setdefault(seq, []).append(flt)
+    bands = membership_bands(report, tenants)
+    done_ns = report.get("shared", {}).get("done_ns")
+    missing = surplus = foreign = lost_qos0 = live_elected = matched = 0
+    fell_in_flight = 0
+    first_bad = None
+    ordered = {}      # (tenant, filter, topic, epoch) -> the members elected
+    for seq, t, k, qos, _conn, _due, sent, ack in pubs:
+        tenant, topic = tenants[t], pop[k]
+        want = ref.groups(tenant, topic)
+        got = {}
+        for flt, rid, dkey in stand_in.elected.get(seq, ()):
+            got.setdefault(flt, []).append((rid, dkey))
+        for flt in list(want) + [f for f in live_at.get(seq, ())
+                                 if f not in want]:
+            members = want.get(flt, ())
+            picks = got.pop(flt, ())
+            lo, hi, firm, who, band = live.get((seq, flt),
+                                               (0, 0, False, (), False))
+            matched += bool(members or firm)
+            bad = sum(1 for p in picks if p not in members)
+            foreign += bad
+            n = len(picks)
+            live_elected += 1 if lo and not n else 0
+            if n + hi < 1 and (members or firm):
+                if band:
+                    fell_in_flight += 1
+                    continue
+                if qos == 0:
+                    lost_qos0 += 1      # at most once: a loss, not a fault
+                    continue
+                missing += 1
+                bad = True
+            elif n + lo > 1:
+                surplus += 1
+                bad = True
+            if bad and first_bad is None:
+                first_bad = (seq, tenant, topic, flt, list(picks), lo, hi)
+            if flt.startswith("$oshare/") and n + lo == 1 and hi == lo:
+                epoch = 0
+                done = (done_ns[seq] if done_ns else ack) or 1 << 62
+                for req, acked in bands.get((tenant, flt), ()):
+                    if acked < sent:
+                        epoch += 1
+                    elif req <= done:
+                        epoch = None    # in flight around a join or a leave
+                        break
+                    else:
+                        break
+                if epoch is not None:
+                    ordered.setdefault((tenant, flt, topic, epoch),
+                                       set()).add(picks[0] if n else who[0])
+        for flt, picks in got.items():      # under a group that does not match
+            foreign += len(picks)
+            if first_bad is None:
+                first_bad = (seq, tenant, topic, flt, picks, 0, 0)
+    foreign += sum(len(v) for seq, v in stand_in.elected.items()
+                   if seq >= len(pubs))
+    split = sum(1 for who in ordered.values() if len(who) > 1)
+    subs = report.get("shared", {}).get("subs", ())
+    t0, t1 = report["t0_ns"], report["t1_ns"]
+    return {"group_missing": missing, "group_surplus": surplus,
+            "group_foreign": foreign, "oshare_split": split,
+            "group_lost_qos0": lost_qos0, "group_matched": matched,
+            "live_elected": live_elected, "ordered_keys": len(ordered),
+            "fell_in_flight": fell_in_flight,
+            "joins_in_window": sum(1 for s in subs if t0 <= s[4] < t1),
+            "leaves_in_window": sum(1 for s in subs if t0 <= s[6] < t1),
+            "first_bad": first_bad}
+
+
+SKEW_MIN_DELIVERIES = 100
+
+
+def share_skew(stand_in, ref: FleetReference) -> dict:
+    """Per kind of group (``$share``, ``$oshare``), over the groups the
+    stand-in was handed at least ``SKEW_MIN_DELIVERIES`` deliveries for
+    inside the window: the largest member's deliveries over the group's
+    mean a SEEDED member (1.0 is even), the worst group's; and how many
+    groups that was. The stand-in's own record."""
+    out = {}
+    for (tenant, flt), load in stand_in.member_load.items():
+        total = sum(load.values())
+        group, rest = reference.split_filter(flt)
+        size = len(ref.table.members(tenant, group, rest))
+        if total < SKEW_MIN_DELIVERIES or not size:
+            continue
+        kind = out.setdefault(flt.split("/", 1)[0], {"max": 0.0, "groups": 0})
+        kind["groups"] += 1
+        kind["max"] = max(kind["max"], max(load.values()) * size / total)
+    return out
+
+
 def fleet_verdict(stand_in, report, plan, ref: FleetReference) -> dict:
     tenants, pop = plan["tenants"], plan["population"]
     pubs = report["publishes"]
@@ -151,7 +336,8 @@ def fleet_verdict(stand_in, report, plan, ref: FleetReference) -> dict:
             mismatch += 1
             if first_bad is None:
                 first_bad = (seq, tenants[t], pop[k], got_n, want_n)
-        if want_n and stand_in.qos.get(seq) != {qos}:
+        if (want_n or seq in stand_in.elected) \
+                and stand_in.qos.get(seq) != {qos}:
             qos_wrong += 1
     for seq, got in stand_in.sets.items():
         if seq >= len(pubs):
@@ -160,7 +346,7 @@ def fleet_verdict(stand_in, report, plan, ref: FleetReference) -> dict:
         want = {}
         for rid, dkey in ref.expect(tenants[t], pop[k])[2]:
             want.setdefault((tenants[t], dkey), []).append(rid)
-        if {a: sorted(b) for a, b in got.items()} != \
+        if {a: sorted(b) for a, b in got.items() if b} != \
                 {a: sorted(b) for a, b in want.items()}:
             if not (pubs[seq][3] == 0 and not got):
                 set_mismatch += 1
@@ -180,6 +366,8 @@ async def run(args, cell) -> list:
     platform = devices[0].platform
     compiles = sut.CompileCounter()
     sut.install_stage_sums()
+    warnings = Warnings()
+    logging.getLogger().addHandler(warnings)
 
     # ---- the table, from the configuration's own seed
     freeze = bool(cfg.get("runtime", {}).get("gc_freeze_after_setup"))
@@ -189,8 +377,11 @@ async def run(args, cell) -> list:
     gen = traffic_mod.generator_of(cfg)
     rows = list(gen.subscriptions(cfg))
     tries, n_rows = sut.build_tries(rows)
+    n_shared = sum(1 for row in rows if reference.is_shared(row[1]))
     log(f"table: {n_rows:,} subscriptions over {len(tries):,} tenant(s) "
-        f"generated in {time.perf_counter() - t0:.1f}s")
+        f"generated in {time.perf_counter() - t0:.1f}s"
+        + (f"; {n_shared:,} of them members of shared groups"
+           if n_shared else ""))
 
     # ---- the broker, through the entry point a user starts
     from bifromq_tpu.starter import Standalone
@@ -205,6 +396,7 @@ async def run(args, cell) -> list:
         if not isinstance(broker.settings, settings_cls):
             raise RuntimeError("the settings seat was not taken")
         stand_in = sut.FleetStandIn()
+        stand_in.shared = n_shared > 0
         broker.sub_brokers.register(stand_in)
         worker = broker.dist.worker
         seeded = sut.seed_worker(worker, tries)
@@ -266,11 +458,15 @@ async def run(args, cell) -> list:
                                    devices, shared)
             res["seed"], res["rate"] = seed, rate
             results.append(res)
+        log(f"retained scan plane (SUBSCRIBE side): "
+            f"{sut.retained_scans(broker)}")
     finally:
         if gen_proc is not None:
             await gen_proc.stop()
         await node.stop()
     log(f"compile cache: {compiles.hits} hit(s), {compiles.misses} miss(es)")
+    for msg in warnings.first:
+        log(f"program warning (of {warnings.n}): {msg}")
     return results
 
 
@@ -352,6 +548,10 @@ async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
         shared["ref"] = FleetReference(rows, with_prefixes=bool(args.trace))
     ref = shared["ref"]
     fleet = fleet_verdict(stand_in, report, plan, ref)
+    # elections: only where the table or the traffic holds a shared group
+    groups = (group_verdict(stand_in, report, plan, ref)
+              if ref.shared or "shared" in report else None)
+    skew = share_skew(stand_in, ref) if groups is not None else None
     kernels = snaps["batches"]["kernels"]
     in_window_compiles = compiles.between(t0_ns, t1_ns)
     pubs = report["publishes"]
@@ -387,6 +587,12 @@ async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
     if state["n_devices"] > 1:      # a mesh: its own step served, all of it
         floors.append(("mesh_batches", kernels.get("mesh", 0),
                        max(1, snaps["batches"]["n"])))
+    if groups is not None:
+        checks += [(n, groups[n], 0) for n in (
+            "group_missing", "group_surplus", "group_foreign",
+            "oshare_split")]
+        if ref.shared:              # seeded groups: elections were held
+            floors.append(("group_matched", groups["group_matched"], 1))
     correct = all(v <= lim for _n, v, lim in checks) and \
         all(v >= lim for _n, v, lim in floors)
     compared = {n: [v, lim] for n, v, lim in checks}
@@ -407,8 +613,11 @@ async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
         "setup_s": (t0_ns - T_START_NS) / 1e9,
     }
     failed = (fleet["fleet_lost_qos0"] + report["live_lost_qos0"]
-              + report["unacked_qos1"] + report["live_missing"])
+              + report["unacked_qos1"] + report["live_missing"]
+              + (groups["group_lost_qos0"] if groups else 0))
     b = snaps["batches"]
+    fan_out = fleet["matched_total"] + (groups["group_matched"] if groups
+                                        else 0)
     log(f"window {seconds:.1f}s: {len(pubs):,} "
         f"publishes ({len(pubs) / seconds:,.1f}/s), {stand_in.in_window:,} "
         f"fleet + {report['live_in_window']:,} live route deliveries in "
@@ -428,7 +637,7 @@ async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
         f" flushes, fallbacks {snaps['after']['patch.fallbacks']}; stand-in "
         f"{stand_in.spent_s / max(1, stand_in.total) * 1e6:.3f} us/route over "
         f"{stand_in.total:,} routes; mean fan-out "
-        f"{fleet['matched_total'] / max(1, len(pubs)):,.1f}; comparison "
+        f"{fan_out / max(1, len(pubs)):,.1f}; comparison "
         f"{cmp_s:.1f}s; failed {failed} (lost QoS 0: fleet "
         f"{fleet['fleet_lost_qos0']}, live {report['live_lost_qos0']})")
     log(f"resident tables {state['bytes_each']} B on {state['on']}, "
@@ -448,8 +657,23 @@ async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
     if fleet["first_bad"]:
         log(f"first fleet mismatch (seq, tenant, topic, got, want): "
             f"{fleet['first_bad']}")
+    if groups is not None:
+        log(f"shared groups: {groups['group_matched']:,} (publish, group) "
+            f"elections held, {groups['live_elected']} of them won by a live "
+            f"session; live joins / leaves of groups acked inside the window "
+            f"{groups['joins_in_window']} / {groups['leaves_in_window']}; "
+            f"$oshare (group, topic, epoch) keys held to one member "
+            f"{groups['ordered_keys']:,}; lost QoS 0 "
+            f"{groups['group_lost_qos0']}, fell with a member in flight "
+            f"{groups['fell_in_flight']}; largest member over the group's "
+            f"mean, groups of >= {SKEW_MIN_DELIVERIES} deliveries: {skew}")
+        if groups["first_bad"]:
+            log(f"first group fault (seq, tenant, topic, group, stand-in "
+                f"picks, live lo, live hi): {groups['first_bad']}")
     for e in report["errors"]:
         log(f"load generator error: {e}")
+    for e in report.get("notes", ()):
+        log(f"load generator: {e}")
 
     res = {"correct": correct, "attempted": len(pubs), "failed": failed,
            "e2e": e2e, "compared": compared, "peak": peak,
@@ -476,7 +700,9 @@ async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
         n_uniq = max(1, len(uniq))
         ref_work = {
             "visited_per_topic": sum(ref.visited(t, k) for t, k in uniq) / n_uniq,
-            "matched_per_topic": sum(ref.expect(t, k)[0] for t, k in uniq) / n_uniq,
+            # a matching group is ONE result slot, whatever its members
+            "matched_per_topic": sum(ref.expect(t, k)[0] + len(ref.groups(t, k))
+                                     for t, k in uniq) / n_uniq,
             "traced_topics": (sum(n for ts, n in drain.stamps
                                   if trace_info["wall_a"] <= ts
                                   <= trace_info["wall_b"])
@@ -485,6 +711,7 @@ async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
         ctx = {"report": report, "before": snaps["before"],
                "after": snaps["after"], "batches": b, "trace": reduced,
                "reference": ref_work, "device": state, "seconds": counted_s,
+               "share_skew": skew,
                "route_deliveries": (snaps["after"]["fleet.total"]
                                     - snaps["before"]["fleet.total"]),
                "peaks": (roofline.peaks_for(devices[0].device_kind)

@@ -106,7 +106,70 @@ def check_reference() -> None:
         fast = sorted(r[0] for r in table.match("t", "/".join(topic)))
         check(brute == fast, f"table.match != definition on {topic}")
     check(table.match("other", "l0") == [], "no row crosses a tenant")
+    check_shared_rows(gen, rng, names, cum)
     check(len(reference.truncated(list(range(100)), 64)) == 64, "control")
+
+
+SHARED_CASES = [  # (rows as filter strings, topic, {group filter: members})
+    (["$share/g/a/+", "$share/g/a/+", "a/+"], "a/b",
+     {"$share/g/a/+": [0, 1]}),
+    (["$oshare/g/a/#", "$share/h/a/#"], "a",
+     {"$oshare/g/a/#": [0], "$share/h/a/#": [1]}),
+    # one group name under both prefixes, or over two filters: two groups
+    (["$share/g/a/+", "$oshare/g/a/+", "$share/g/a/#"], "a/b",
+     {"$share/g/a/+": [0], "$oshare/g/a/+": [1], "$share/g/a/#": [2]}),
+    # behind the prefix a filter matches as any other: a leading wildcard
+    # does not reach a $-topic, a literal first level does
+    (["$share/g/+/x", "$share/g/#", "$share/g/$SYS/#"], "$SYS/x",
+     {"$share/g/$SYS/#": [2]}),
+    (["$share/g/+/x", "$share/g/#"], "a/x",
+     {"$share/g/+/x": [0], "$share/g/#": [1]}),
+    (["$share/g/a/b"], "a/c", {}),
+    # no share prefix without a group and a filter behind it
+    (["$share/g", "$share/+"], "$share/g", {}),
+]
+
+
+def check_shared_rows(gen, rng, names, cum) -> None:
+    for filters, topic, want in SHARED_CASES:
+        table = reference.Table()
+        for i, flt in enumerate(filters):
+            table.add("t", tuple(flt.split("/")), (i,))
+        got = {g: sorted(r[0] for r in rows)
+               for g, rows in table.match_groups("t", topic)}
+        check(got == want, f"match_groups({filters}, {topic!r}) = {got}")
+        plain = sorted(i for i, flt in enumerate(filters)
+                       if not reference.is_shared(flt.split("/"))
+                       and reference.filter_matches(flt.split("/"),
+                                                    topic.split("/")))
+        check(sorted(r[0] for r in table.match("t", topic)) == plain,
+              f"match({filters}, {topic!r}) holds a shared row or loses a "
+              "plain one")
+        check(table.match_groups("other", topic) == [],
+              "no group crosses a tenant")
+    # against the definition, on drawn filters: every third row shared
+    table, rows = reference.Table(), []
+    for i in range(3000):
+        levels = tuple(gen.gen_filter(rng, names, cum, max_depth=4,
+                                      p_plus=0.3, p_hash=0.2))
+        if i % 3 == 0:
+            levels = (reference.SHARE_PREFIXES[i % 2], f"g{i % 5}") + levels
+        rows.append(levels)
+        table.add("t", levels, (i,))
+    for _ in range(300):
+        topic = gen.gen_topic(rng, names, cum, max_depth=4)
+        brute = {}
+        for i, levels in enumerate(rows):
+            group, rest = reference.split_filter("/".join(levels))
+            if group is not None and reference.filter_matches(rest, topic):
+                brute.setdefault("/".join(levels), []).append(i)
+        fast = {g: sorted(r[0] for r in members)
+                for g, members in table.match_groups("t", "/".join(topic))}
+        check(brute == fast, f"match_groups != definition on {topic}")
+        plain = sorted(i for i, f in enumerate(rows) if i % 3
+                       and reference.filter_matches(f, topic))
+        check(plain == sorted(r[0] for r in table.match("t", "/".join(topic))),
+              f"table.match beside shared rows != definition on {topic}")
 
 
 def check_schedule() -> None:

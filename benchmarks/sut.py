@@ -20,6 +20,10 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(1, ROOT)
+if HERE not in sys.path:
+    sys.path.insert(0, HERE)
+
+from reference import is_shared  # noqa: E402  (beside this file; no program)
 
 FLEET_BROKER_ID = 7
 WARM_FLAG = 1 << 62
@@ -110,7 +114,15 @@ class FleetStandIn:
     """The receiver fleet the table's subscriptions belong to, on the
     ISubBroker seat. Cheap inside the window: per deliver call a count and
     an order-independent digest of receiver ids under the publish's seq;
-    full receiver sets only for the sampled publishes."""
+    full receiver sets only for the sampled publishes.
+
+    Where the table holds shared groups (``shared = True``, set by
+    ``run.py`` from the rows) a delivery made under a ``$share`` /
+    ``$oshare`` matcher is an ELECTION's, the program's choice: it is kept
+    out of the count and the digest and recorded as it came, (group filter,
+    receiver id, deliverer key) under the publish's seq, for
+    ``run.group_verdict`` to hold to "exactly one member a matching group".
+    A table without groups pays one test a pack for this."""
 
     id = FLEET_BROKER_ID
 
@@ -119,6 +131,7 @@ class FleetStandIn:
         self._ok = DeliveryResult.OK
         self.t0 = self.t1 = 0
         self.stride, self.offset = 1 << 62, 0
+        self.shared = False          # the table holds shared groups
         self.reset()
         self.annotate = None         # TraceAnnotation factory while tracing
 
@@ -127,6 +140,8 @@ class FleetStandIn:
         self.digest = {}             # seq -> sum of hash(receiver id)
         self.qos = {}                # seq -> set of pub qos seen
         self.sets = {}               # sampled seq -> {dkey: [receiver ids]}
+        self.elected = {}            # seq -> [(group filter, rid, dkey)]
+        self.member_load = {}        # (tenant, group filter) -> {rid: n}
         self.calls = 0
         self.in_window = 0
         self.total = 0
@@ -145,8 +160,12 @@ class FleetStandIn:
         out = {}
         for dp in packs:
             infos = dp.match_infos
-            n = len(infos)
+            n = n_plain = len(infos)
             rids = [mi.receiver_id for mi in infos]
+            if self.shared:          # the one test a table without groups pays
+                plain = self._keep_elected(tenant_id, deliverer_key, dp, now)
+                if plain is not None:
+                    rids, n_plain = plain, len(plain)
             dig = sum(map(hash, rids))
             for pmp in dp.message_pack.packs:
                 for msg in pmp.messages:
@@ -159,7 +178,7 @@ class FleetStandIn:
                     seq = HEADER.unpack_from(payload)[0]
                     if seq >= WARM_FLAG:
                         continue
-                    self.count[seq] = self.count.get(seq, 0) + n
+                    self.count[seq] = self.count.get(seq, 0) + n_plain
                     self.digest[seq] = (self.digest.get(seq, 0) + dig) & MASK
                     self.qos.setdefault(seq, set()).add(int(msg.pub_qos))
                     if seq % self.stride == self.offset:
@@ -172,6 +191,32 @@ class FleetStandIn:
         self.spent_s += time.perf_counter() - t_in
         return out
 
+    def _keep_elected(self, tenant_id, deliverer_key, dp, now):
+        """A pack's deliveries made under a shared matcher, recorded under
+        each of its window publishes; returns the receiver ids of the
+        pack's PLAIN routes, or ``None`` where it holds no elected one."""
+        infos = dp.match_infos
+        group = [(mi.matcher.mqtt_topic_filter, mi.receiver_id, deliverer_key)
+                 for mi in infos if mi.matcher.type]
+        if not group:
+            return None
+        in_window = self.t0 <= now < self.t1
+        for pmp in dp.message_pack.packs:
+            for msg in pmp.messages:
+                payload = msg.payload
+                if len(payload) < HEADER.size:
+                    continue
+                seq = HEADER.unpack_from(payload)[0]
+                if seq >= WARM_FLAG:
+                    continue
+                self.elected.setdefault(seq, []).extend(group)
+                if in_window:
+                    for flt, rid, _dkey in group:
+                        load = self.member_load.setdefault(
+                            (tenant_id, flt), {})
+                        load[rid] = load.get(rid, 0) + 1
+        return [mi.receiver_id for mi in infos if not mi.matcher.type]
+
     async def check_subscriptions(self, tenant_id, match_infos):
         return [True] * len(match_infos)
 
@@ -180,7 +225,11 @@ class FleetStandIn:
 
 def build_tries(rows):
     """The generated rows as the program's input types. Returns the tries
-    and the row count. ``rows`` yields (tenant, levels, receiver, dkey)."""
+    and the row count. ``rows`` yields (tenant, levels, receiver, dkey).
+    A row whose levels begin ``$share`` / ``$oshare``, group name, is a
+    member of that group: its matcher is the one the program makes of the
+    filter string at a SUBSCRIBE (type, group and the levels behind the
+    prefix). Every other row is a NORMAL route, as before."""
     from bifromq_tpu.models.oracle import Route, SubscriptionTrie
     from bifromq_tpu.types import RouteMatcher, RouteMatcherType
     normal = RouteMatcherType.NORMAL
@@ -189,10 +238,13 @@ def build_tries(rows):
         trie = tries.get(tenant)
         if trie is None:
             trie = tries[tenant] = SubscriptionTrie()
-        trie.add(Route(
-            matcher=RouteMatcher(type=normal, filter_levels=levels,
-                                 mqtt_topic_filter="/".join(levels)),
-            broker_id=FLEET_BROKER_ID, receiver_id=rid, deliverer_key=dkey))
+        if is_shared(levels):
+            matcher = RouteMatcher.from_topic_filter("/".join(levels))
+        else:
+            matcher = RouteMatcher(type=normal, filter_levels=levels,
+                                   mqtt_topic_filter="/".join(levels))
+        trie.add(Route(matcher=matcher, broker_id=FLEET_BROKER_ID,
+                       receiver_id=rid, deliverer_key=dkey))
         n += 1
     return tries, n
 
@@ -453,6 +505,23 @@ def host_rss_bytes() -> dict:
     return out
 
 
+def retained_scans(broker) -> list:
+    """A snapshot a range of the SUBSCRIBE-side retained scan plane: scans,
+    those it served from the host oracle by reason, its breaker. A degraded
+    scan counts under ``match_degraded`` like a degraded match; this says
+    which it was. ``[]`` where the program has no such plane."""
+    service = getattr(broker, "retain_service", None)
+    out = []
+    for coproc in getattr(getattr(service, "kvstore", None), "coprocs",
+                          {}).values():
+        plane = getattr(coproc, "scan_plane", None)
+        if plane is not None:
+            snap = plane.snapshot()
+            out.append({k: snap[k] for k in ("scans_total", "degraded",
+                                             "breaker") if k in snap})
+    return out
+
+
 def memory_peak_bytes() -> int:
     import jax
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
@@ -485,9 +554,24 @@ def _drop_one(m) -> None:
         m.normal = m.normal[:-1]
 
 
+def _share_all(m) -> None:
+    # no election: every member of a matching group is delivered to (what
+    # a fan-out that treats a group slot as plain routes would do)
+    for members in m.groups.values():
+        m.normal = m.normal + list(members)
+    m.groups = {}
+
+
+def _share_none(m) -> None:
+    # the group slots left out of the match: nobody of a group receives
+    m.groups = {}
+
+
 # name -> fn(worker): put a broken guarantee in the matcher's place. The
 # benchmark's own runs never use them; ``--control`` and the tests do.
 CONTROLS = {
     "truncate64": lambda worker: _wrap_match(worker, _truncate64),
     "drop_one": lambda worker: _wrap_match(worker, _drop_one),
+    "share_all": lambda worker: _wrap_match(worker, _share_all),
+    "share_none": lambda worker: _wrap_match(worker, _share_none),
 }
