@@ -12,21 +12,24 @@ table *is* the KV"):
   BatchDistServerCall) that emits device match batches served from the
   worker replica's derived TpuMatcher.
 - Fan-out: shared-group member election (ordered share = rendezvous hash on
-  topic, unordered = random — ≈ DeliverExecutorGroup's cached ordered pick),
-  then delivery batched per (tenant, sub-broker, deliverer key)
-  (≈ MessageDeliverer/BatchDeliveryCall.java:53) with NO_SUB/NO_RECEIVER
-  results feeding route cleanup.
+  topic, unordered = least delivery count — ≈ DeliverExecutorGroup's cached
+  ordered pick; its state kept while a group's membership stands:
+  ``GroupFanoutBalancer``), then delivery batched per (tenant, sub-broker,
+  deliverer key) (≈ MessageDeliverer/BatchDeliveryCall.java:53) with
+  NO_SUB/NO_RECEIVER results feeding route cleanup.
 """
 
 from __future__ import annotations
 
 import hashlib
 import random
-from collections import defaultdict
+from collections import OrderedDict, defaultdict
 from dataclasses import dataclass
 from itertools import chain
 from operator import attrgetter
 from typing import Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from ..models.matcher import TpuMatcher
 from ..models.oracle import (PERSISTENT_SUB_BROKER_ID, MatchedRoutes,
@@ -38,7 +41,7 @@ from ..plugin.subbroker import (DeliveryPack, DeliveryResult, ISubBroker,
 from .. import trace
 from ..scheduler.batcher import BatchCallScheduler
 from ..types import (ClientInfo, MatchInfo, Message, PublisherMessagePack,
-                     RouteMatcher, TopicMessagePack)
+                     RouteMatcher, RouteMatcherType, TopicMessagePack)
 from ..obs import OBS
 from ..utils import topic as topic_util
 
@@ -57,83 +60,160 @@ class PubResult:
     error: str = ""
 
 
+_U64 = np.uint64
+_S30, _S27, _S31 = _U64(30), _U64(27), _U64(31)
+_M1, _M2 = _U64(0xBF58476D1CE4E5B9), _U64(0x94D049BB133111EB)
+
+
+class _Kept:
+    """What the election keeps of one group while its membership stands."""
+    __slots__ = ("members", "pending", "rounds", "values")
+
+    def __init__(self) -> None:
+        self.members: Tuple[Route, ...] = ()  # what the rest was built from
+        self.pending: List[Route] = []  # $share: members still at the minimum
+        self.rounds = 0                 # $share: that minimum (refills so far)
+        self.values = None              # $oshare: one uint64 a member
+
+
+def _member_value(r: Route) -> int:
+    h = hashlib.blake2b(f"{r.receiver_id}|{r.deliverer_key}".encode(),
+                        digest_size=8).digest()
+    return int.from_bytes(h, "little")
+
+
+def _mix64(x: np.ndarray) -> np.ndarray:
+    """splitmix64's finalizer over a uint64 array, in place."""
+    x ^= x >> _S30
+    x *= _M1
+    x ^= x >> _S27
+    x *= _M2
+    x ^= x >> _S31
+    return x
+
+
 class GroupFanoutBalancer:
-    """Least-outstanding election for UNORDERED shared-subscription
-    groups (ISSUE 13 tentpole part 3, $share half).
+    """Shared-group election: exactly ONE member of a matching group a
+    publish, chosen among the members it is handed (the group as
+    subscribed at that moment). The same code on every leg.
 
-    The reference (and our pre-13 `_elect`) picks an unordered-share
-    member uniformly at random — fair in expectation, but a burst of a
-    few hundred publishes routinely lands 2-3× the mean on one member
-    (balls-into-bins), which is exactly the skew that trips slow-
-    consumer backpressure under a million-client mixed workload. This
-    balancer tracks per-member delivery counts per (tenant, group
-    filter) and elects the least-loaded member, ties broken by the
-    service rng — deterministic O(members) per publish, worst-case
-    member spread 1 instead of O(log n / log log n).
+    ``$share`` (:meth:`pick`): least delivery count, ties at random by
+    the service rng. Every member's count is the group's minimum or one
+    more (spread <= 1, where a uniform draw gives balls-into-bins skew:
+    a burst of a few hundred publishes lands 2-3x the mean on one
+    member). A first-seen member enters AT the minimum: it takes a fair
+    share at once and is not flooded until a lifetime count caught up.
+    ``$oshare`` (:meth:`pick_ordered`): rendezvous. A member's score for
+    a topic is a pure function of (``receiver_id``, ``deliverer_key``,
+    topic) with no per-process seed (BLAKE2b halves, a fixed 64-bit
+    mixer; never ``hash()``): two services holding one membership elect
+    the same member, one topic goes to one member while the membership
+    stands, a join moves only the topics the joiner wins and a leave
+    only the leaver's.
 
-    Membership churn self-heals: counts are keyed by receiver_url, a
-    first-seen member seeds at the current group MINIMUM (joining the
-    min tie for a fair share — seeding at zero would flood the cold
-    newcomer with 100% of traffic until it caught up), and departed
-    members' counts are swept once the map outgrows the live set.
-    Bounded: group entries are dropped LRU-ish past ``max_groups`` (the
-    counts are a balancing hint, not correctness state).
+    State is kept per (tenant, group filter) for as long as a MEMBERSHIP
+    stands, and a membership is known by identity: the patcher swaps a
+    group's whole ``members`` tuple at a join or leave and the matcher
+    hands that tuple on uncopied, so ``members is`` the kept one means
+    nobody joined or left. Then ``$share`` draws one of the members
+    still at the minimum (``pending``: a swap-remove at ``randrange``,
+    refilled with the whole membership when it empties) in O(1), and
+    ``$oshare`` hashes the topic once and mixes it over the members'
+    kept halves. Any other object (a new membership, or a leg that
+    builds a fresh list a call: overlay, multi-range union, a remote
+    worker's reply, the host oracle) re-syncs ON that election, before
+    it draws, in O(members): ``pending`` keeps who was pending and still
+    is a member, takes in every first-seen member, drops the departed;
+    the halves are hashed anew. Nothing is answered from a membership
+    other than the one handed in.
+
+    Bounded by ``max_groups`` entries (a list of at most ``len(members)``
+    pointers or as many uint64, and one reference, each); past it the
+    coldest entry goes, one at a time. A dropped group starts a new
+    round at its next election: a balancing hint lost, never a wrong
+    delivery.
     """
 
-    def __init__(self, rng: random.Random, max_groups: int = 8192) -> None:
+    def __init__(self, rng: random.Random, max_groups: int = 65536) -> None:
         self._rng = rng
         self.max_groups = max_groups
-        # (tenant, filter) -> {receiver_url: delivered count}
-        self._counts: Dict[Tuple[str, str], Dict[str, int]] = {}
-        self.elections = 0
+        # (tenant, filter) -> kept state, coldest first
+        self._kept: "OrderedDict[Tuple[str, str], _Kept]" = OrderedDict()
+        # since the last drain(): elections answered from kept state,
+        # that re-synced it, that found none (a group's first, or its
+        # first since it was dropped), and the members the last two scanned
+        self._tally = [0, 0, 0, 0]
 
-    def pick(self, tenant_id: str, mqtt_filter: str, members) -> "Route":
-        self.elections += 1
+    def _entry(self, tenant_id: str, mqtt_filter: str) -> _Kept:
         key = (tenant_id, mqtt_filter)
-        counts = self._counts.get(key)
-        if counts is None:
-            if len(self._counts) >= self.max_groups:
-                # drop the oldest half (insertion order ≈ LRU for the
-                # steady case: hot groups re-enter immediately)
-                for k in list(self._counts)[:self.max_groups // 2]:
-                    del self._counts[k]
-            counts = self._counts[key] = {}
-        # a first-seen member SEEDS at the current group minimum: with
-        # lifetime counts, seeding at 0 would route 100% of the group's
-        # traffic to every newcomer until it caught up — the exact cold-
-        # consumer flood this balancer exists to prevent. Seeded, it
-        # simply joins the min tie and takes a fair share from now on.
-        seed = min((counts.get(r.receiver_url) for r in members
-                    if r.receiver_url in counts),
-                   default=0)
-        lo = None
-        lo_members = []
-        for r in members:
-            c = counts.get(r.receiver_url)
-            if c is None:
-                c = counts[r.receiver_url] = seed
-            if lo is None or c < lo:
-                lo, lo_members = c, [r]
-            elif c == lo:
-                lo_members.append(r)
-        elected = (lo_members[0] if len(lo_members) == 1
-                   else lo_members[self._rng.randrange(len(lo_members))])
-        counts[elected.receiver_url] = lo + 1
-        if len(counts) > 4 * len(members) + 8:
-            # membership churned: retain only live members' counts
-            live = {r.receiver_url for r in members}
-            for url in [u for u in counts if u not in live]:
-                del counts[url]
+        e = self._kept.get(key)
+        if e is None:
+            e = self._kept[key] = _Kept()
+            if len(self._kept) > self.max_groups:
+                self._kept.popitem(last=False)
+        else:
+            self._kept.move_to_end(key)
+        return e
+
+    def _renew(self, e: _Kept, members) -> None:
+        """``e`` was just rebuilt from ``members``. A list can change
+        under its identity: a copy is kept of it, which no later call
+        ``is``, so a leg that hands lists re-syncs every time."""
+        self._tally[1 if e.members else 2] += 1
+        self._tally[3] += len(members)
+        e.members = members if type(members) is tuple else tuple(members)
+
+    def pick(self, tenant_id: str, mqtt_filter: str, members) -> Route:
+        e = self._entry(tenant_id, mqtt_filter)
+        if members is e.members:
+            self._tally[0] += 1
+        else:
+            served = ({r.receiver_url for r in e.members}
+                      - {r.receiver_url for r in e.pending})
+            e.pending = ([r for r in members if r.receiver_url not in served]
+                         if served else list(members))
+            self._renew(e, members)
+        pending = e.pending
+        if not pending:
+            # everyone stands one above the old minimum: the next round
+            pending.extend(members)
+            e.rounds += 1
+        i = self._rng.randrange(len(pending))
+        elected = pending[i]
+        last = pending.pop()
+        if i < len(pending):
+            pending[i] = last
         return elected
+
+    def pick_ordered(self, tenant_id: str, mqtt_filter: str, members,
+                     topic: str) -> Route:
+        e = self._entry(tenant_id, mqtt_filter)
+        if members is e.members:
+            self._tally[0] += 1
+        else:
+            e.values = np.fromiter(map(_member_value, members), _U64,
+                                   len(members))
+            self._renew(e, members)
+        t = hashlib.blake2b(topic.encode(), digest_size=8).digest()
+        scores = _mix64(e.values ^ _U64(int.from_bytes(t, "little")))
+        return members[int(scores.argmax())]
+
+    def drain(self) -> Tuple[int, int, int, int]:
+        """(kept, re-synced, first, members scanned) since the last
+        call."""
+        out = tuple(self._tally)
+        self._tally = [0, 0, 0, 0]
+        return out
 
     def spread(self, tenant_id: str, mqtt_filter: str) -> dict:
         """Per-group balance introspection (the fairness tests read
-        it)."""
-        counts = self._counts.get((tenant_id, mqtt_filter), {})
-        if not counts:
+        it): the counts the kept state stands for."""
+        e = self._kept.get((tenant_id, mqtt_filter))
+        if e is None or e.values is not None:
             return {"members": 0, "max": 0, "min": 0}
-        vals = list(counts.values())
-        return {"members": len(vals), "max": max(vals), "min": min(vals)}
+        n, waiting = len(e.members), len(e.pending)
+        lo = e.rounds if waiting else e.rounds + 1
+        return {"members": n, "min": lo, "max": lo + (0 < waiting < n)}
 
 
 _MATCH_INFO = attrgetter("match_info")
@@ -644,6 +724,16 @@ class DistService:
                 elected.append(member)
                 if member.broker_id == PERSISTENT_SUB_BROKER_ID:
                     n_persistent += 1
+        if matched.groups:
+            kept, resynced, first, scanned = self.group_balancer.drain()
+            if kept:
+                trace.count("share.elect.kept", kept)
+            if resynced:
+                trace.count("share.elect.resync", resynced)
+            if first:
+                trace.count("share.elect.first", first)
+            if resynced or first:
+                trace.count("share.elect.scanned", scanned)
         # byte-based persistent fan-out cap (≈ MaxPersistentFanoutBytes in
         # DeliverExecutorGroup.java:132), applied over the FULL target set
         # (normal + elected shared-group members — an elected persistent
@@ -678,23 +768,14 @@ class DistService:
         return pack, plan
 
     def _elect(self, tenant_id: str, mqtt_filter: str,
-               members: List[Route], topic: str) -> Optional[Route]:
-        """Shared-group member election (≈ DeliverExecutorGroup).
-
-        Ordered share: rendezvous hash over (member, topic) — stable per
-        topic, redistributes ~1/n on membership change (the reference caches
-        the pick; rendezvous gives the same stability statelessly).
-        Unordered share (ISSUE 13): least-outstanding balanced election
-        via :class:`GroupFanoutBalancer` — worst-case member spread 1
-        where uniform random gave balls-into-bins skew.
-        """
+               members: Sequence[Route], topic: str) -> Optional[Route]:
+        """Shared-group member election (≈ DeliverExecutorGroup): one of
+        ``members``, by the rules :class:`GroupFanoutBalancer` states
+        (ordered share: rendezvous over (member, topic); unordered:
+        least delivery count). ``members`` is read, never changed."""
         if not members:
             return None
-        if members[0].matcher.type.name == "ORDERED_SHARE":
-            def score(r: Route) -> int:
-                h = hashlib.blake2b(
-                    f"{r.receiver_id}|{r.deliverer_key}|{topic}".encode(),
-                    digest_size=8).digest()
-                return int.from_bytes(h, "little")
-            return max(members, key=score)
+        if members[0].matcher.type is RouteMatcherType.ORDERED_SHARE:
+            return self.group_balancer.pick_ordered(
+                tenant_id, mqtt_filter, members, topic)
         return self.group_balancer.pick(tenant_id, mqtt_filter, members)

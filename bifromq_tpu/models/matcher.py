@@ -1798,7 +1798,10 @@ class TpuMatcher:
                 out.max_group_fanout_exceeded = True
                 grp_slots = grp_slots[:max_group_fanout]
             for m in arr[grp_slots]:
-                out.groups[m.mqtt_topic_filter] = list(m.members)
+                # the slot's own tuple, uncopied: the patcher swaps it
+                # whole at a join or leave, so its identity tells the
+                # election that the membership stands (nobody mutates it)
+                out.groups[m.mqtt_topic_filter] = m.members
             out.normal = arr[row[~grp_mask]].tolist()
         else:
             out.normal = arr[row].tolist()

@@ -106,6 +106,23 @@ _row("deliver.fanout", "span", "dist/service.py",
 _row("deliver.group", "span", "dist/service.py",
      "election, byte cap and grouping of one publish's routes by (broker, "
      "deliverer key), message pack built", sync=True)
+_row("share.elect.kept", "counter", "dist/service.py",
+     "shared-group elections answered from the state kept for the "
+     "membership they were handed (the matcher's own `members` tuple, "
+     "unchanged since the group's last election): O(1) for `$share`, one "
+     "topic hash for `$oshare` (beside `deliver.group`)")
+_row("share.elect.resync", "counter", "dist/service.py",
+     "elections handed another object than the kept membership, which "
+     "re-synced the state first, O(members): a join or leave (the "
+     "patcher swapped the tuple), or a leg that builds a fresh member "
+     "list a call (overlay, multi-range union, remote worker, host "
+     "oracle: there `.kept` reads 0)")
+_row("share.elect.first", "counter", "dist/service.py",
+     "elections that found no kept state and built it, O(members): a "
+     "group's first, or its first since `max_groups` dropped it (more "
+     "of these than groups: the bound is thrashing)")
+_row("share.elect.scanned", "counter", "dist/service.py",
+     "members those re-syncs and first elections scanned")
 _row("deliver.call", "span", "dist/service.py",
      "one sub-broker (or remote deliverer) call: the finest grain, 64 a "
      "publish at the fan-out corner. `deliver.fanout` less these is the "

@@ -259,6 +259,40 @@ async def test_shared_groups_are_elected_every_publish(kind):
         assert len(set(elected)) > 1
 
 
+@pytest.mark.parametrize("feed", ["fresh", "kept"])
+@pytest.mark.parametrize("kind", ["$oshare", "$share"])
+async def test_elections_count_what_they_cost(kind, feed):
+    """Counted beside ``deliver.group``: while the match hands on the
+    slot's own ``members`` tuple, the group's first election scans the
+    members and 699 are answered from what it kept; a leg that builds a
+    list a publish re-syncs, and pays the scan, every time. One delivery a publish either way."""
+    svc, subs, _ = service()
+    flt = f"{kind}/g/a/#"
+    members = tuple(route(20 + i, flt=flt, dkeys=64) for i in range(7))
+    names = ("share.elect.kept", "share.elect.resync", "share.elect.first",
+             "share.elect.scanned")
+
+    def read():
+        got = trace.TRACER.totals.between(0, time.monotonic_ns() + 10**9)
+        return [got.get(n, (0, 0.0))[0] for n in names]
+
+    before = read()
+    won = dict.fromkeys(members, 0)
+    for n in range(700):
+        groups = {flt: members if feed == "kept" else list(members)}
+        fanout = await svc._fan_out(TENANT, call(topic=f"a/t{n % 70}"),
+                                    MatchedRoutes(groups=groups))
+        assert fanout == 1
+    for _d, _t, infos in subs[7].calls:
+        (mi,) = infos
+        won[next(m for m in members if m.match_info is mi)] += 1
+    assert sum(won.values()) == 700
+    if kind == "$share":
+        assert set(won.values()) == {100}
+    assert [b - a for a, b in zip(before, read())] == {
+        "kept": [699, 0, 1, 7], "fresh": [0, 699, 1, 4900]}[feed]
+
+
 async def test_dead_elected_member_is_reaped_beside_a_dead_normal_route():
     script = {"r20": NO_SUB, "r4": NO_RECEIVER}
     svc, subs, _ = service(script)

@@ -194,6 +194,37 @@ class TestTombstoneWalks:
         assert_oracle_parity(
             m, [("T", ["$sys", "health"]), ("T", ["q"])], "sys")
 
+    def test_group_membership_is_one_object_until_patched(self):
+        """The election knows a standing membership by identity: the
+        match hands on the slot's own ``members`` tuple, whatever the
+        topic, and a join or leave swaps in another."""
+        m = TpuMatcher(max_levels=8, auto_compact=False)
+        m.add_route("T", mk_route("base", "r0"))
+        m.refresh()
+        flt = "$share/g/s/+"
+        m.add_route("T", mk_route(flt, "ra"))
+        m.add_route("T", mk_route(flt, "rb"))
+        queries = [("T", ["s", "1"]), ("T", ["s", "2"])]
+
+        def memberships():
+            got = [res.groups[flt] for res in m.match_batch(queries)]
+            assert got[0] is got[1] and type(got[0]) is tuple
+            return got[0]
+
+        first = memberships()
+        assert memberships() is first
+        m.add_route("T", mk_route(flt, "rc"))               # patch_add
+        joined = memberships()
+        assert joined is not first and memberships() is joined
+        assert sorted(r.receiver_id for r in joined) == ["ra", "rb", "rc"]
+        m.remove_route("T", RouteMatcher.from_topic_filter(flt),
+                       mk_route(flt, "ra").receiver_url)    # patch_remove
+        left = memberships()
+        assert left is not joined and memberships() is left
+        assert sorted(r.receiver_id for r in left) == ["rb", "rc"]
+        assert m.overlay_size == 0 and m.compile_count == 1
+        assert_oracle_parity(m, queries, "membership identity")
+
     def test_share_filter_tombstones(self):
         m = TpuMatcher(max_levels=8, auto_compact=False)
         m.add_route("T", mk_route("s/1", "seed"))

@@ -472,21 +472,30 @@ class TestDrainGovernor:
             await b.stop()
 
 
+# the two ways a group's members reach the election: the matcher's own
+# tuple, the same object until somebody joins or leaves, and the fresh
+# list a call that the overlay, the multi-range union, a remote worker's
+# reply and the host oracle build
+FEEDS = {"kept": lambda members: members, "fresh": list}
+feeds = pytest.mark.parametrize("feed", sorted(FEEDS))
+
+
 class TestGroupBalancer:
-    def _members(self, n):
+    def _members(self, n, kind="$share", first=0, dkey="d"):
         from bifromq_tpu.models.oracle import Route
-        from bifromq_tpu.types import RouteMatcher, RouteMatcherType
-        return [Route(matcher=RouteMatcher(
-                    type=RouteMatcherType.UNORDERED_SHARE,
-                    filter_levels=("t", "#"),
-                    mqtt_topic_filter="$share/g/t/#", group="g"),
-                    broker_id=0, receiver_id=f"w{i}", deliverer_key="d")
-                for i in range(n)]
+        from bifromq_tpu.types import RouteMatcher
+        matcher = RouteMatcher.from_topic_filter(f"{kind}/g/t/#")
+        return tuple(Route(matcher=matcher, broker_id=0,
+                           receiver_id=f"w{i}", deliverer_key=dkey)
+                     for i in range(first, first + n))
+
+    def _balancer(self, **kw):
+        from bifromq_tpu.dist.service import GroupFanoutBalancer
+        return GroupFanoutBalancer(random.Random(0), **kw)
 
     def test_balanced_spread_is_tight(self):
-        from bifromq_tpu.dist.service import GroupFanoutBalancer
-        bal = GroupFanoutBalancer(random.Random(0))
-        members = self._members(7)
+        bal = self._balancer()
+        members = list(self._members(7))
         counts = {}
         for _ in range(700):
             r = bal.pick("T", "$share/g/t/#", members)
@@ -495,16 +504,34 @@ class TestGroupBalancer:
         sp = bal.spread("T", "$share/g/t/#")
         assert sp["members"] == 7 and sp["max"] - sp["min"] <= 1
 
+    @feeds
+    @pytest.mark.parametrize("n", [7, 50])
+    def test_spread_and_what_each_feed_costs(self, n, feed):
+        """Spread <= 1 at EVERY election, not only at the end; the kept
+        membership is scanned once, the fresh lists every time."""
+        bal = self._balancer()
+        members = self._members(n)
+        counts = dict.fromkeys(members, 0)
+        for _ in range(700):
+            counts[bal.pick("T", "f", FEEDS[feed](members))] += 1
+            assert max(counts.values()) - min(counts.values()) <= 1
+            sp = bal.spread("T", "f")
+            assert (sp["members"], sp["min"], sp["max"]) == (
+                n, min(counts.values()), max(counts.values()))
+        # (kept, re-synced, first, members scanned)
+        assert bal.drain() == {"kept": (699, 0, 1, n),
+                               "fresh": (0, 699, 1, 700 * n)}[feed]
+        assert bal.drain() == (0, 0, 0, 0)
+
     def test_membership_churn_seeds_newcomer_fairly(self):
         """A first-seen member seeds at the group MIN: it takes a fair
         share immediately but is NOT flooded with 100% of traffic until
         its lifetime count catches up (the cold-consumer inversion)."""
-        from bifromq_tpu.dist.service import GroupFanoutBalancer
-        bal = GroupFanoutBalancer(random.Random(0))
-        members = self._members(4)
+        bal = self._balancer()
+        members = list(self._members(4))
         for _ in range(400):
             bal.pick("T", "f", members)
-        grown = members + self._members(5)[4:]
+        grown = members + list(self._members(5)[4:])
         picks = [bal.pick("T", "f", grown).receiver_id
                  for _ in range(50)]
         newcomer = picks.count("w4")
@@ -514,13 +541,159 @@ class TestGroupBalancer:
         sp = bal.spread("T", "f")
         assert sp["max"] - sp["min"] <= 1
 
+    @feeds
+    @pytest.mark.parametrize("before", [0, 2, 400, 403])
+    def test_a_joiner_enters_at_the_minimum(self, before, feed):
+        """Whatever the round stands at when the membership is swapped:
+        the joiner is neither starved nor flooded, and the balance of
+        the members that stayed is carried over the swap."""
+        bal = self._balancer()
+        members = self._members(4)
+        counts = dict.fromkeys(self._members(5), 0)
+        for _ in range(before):
+            counts[bal.pick("T", "f", FEEDS[feed](members))] += 1
+        grown = self._members(5)        # a new tuple, as the patcher's
+        joiner = grown[4]
+        for _ in range(50):
+            counts[bal.pick("T", "f", FEEDS[feed](grown))] += 1
+        assert 9 <= counts[joiner] <= 11        # 1-25 asked; it is 10 +- 1
+        stayed = [counts[r] for r in grown[:4]]
+        assert max(stayed) - min(stayed) <= 1
+        sp = bal.spread("T", "f")
+        assert sp["members"] == 5 and sp["max"] - sp["min"] <= 1
+
+    @feeds
+    def test_a_leaver_is_never_elected_again(self, feed):
+        bal = self._balancer()
+        members = self._members(7)
+        counts = dict.fromkeys(members, 0)
+        for _ in range(3):
+            counts[bal.pick("T", "f", FEEDS[feed](members))] += 1
+        # somebody still waiting for this round's delivery leaves
+        leaver = next(r for r in members if not counts[r])
+        left = tuple(r for r in members if r is not leaver)
+        for _ in range(600):
+            counts[bal.pick("T", "f", FEEDS[feed](left))] += 1
+        assert counts.pop(leaver) == 0
+        assert max(counts.values()) - min(counts.values()) <= 1
+        assert bal.spread("T", "f")["members"] == 6
+
+    @feeds
+    def test_every_member_replaced_at_once(self, feed):
+        bal = self._balancer()
+        old = self._members(5)
+        for _ in range(3):
+            bal.pick("T", "f", FEEDS[feed](old))
+        new = self._members(5, first=5)
+        got = [bal.pick("T", "f", FEEDS[feed](new)) for _ in range(10)]
+        assert set(got[:5]) == set(got[5:]) == set(new)
+
+    @feeds
+    @pytest.mark.parametrize("kind", ["$share", "$oshare"])
+    def test_a_group_of_one(self, kind, feed):
+        bal = self._balancer()
+        only = self._members(1, kind)
+        for n in range(5):
+            got = (bal.pick("T", "f", FEEDS[feed](only))
+                   if kind == "$share" else
+                   bal.pick_ordered("T", "f", FEEDS[feed](only), f"t/{n}"))
+            assert got is only[0]
+
     def test_bounded_group_table(self):
-        from bifromq_tpu.dist.service import GroupFanoutBalancer
-        bal = GroupFanoutBalancer(random.Random(0), max_groups=8)
+        """``max_groups`` entries at most; past it the COLDEST entry goes,
+        one at a time (it was the oldest half, 4,096 dicts in one turn)."""
+        bal = self._balancer(max_groups=8)
         members = self._members(2)
+        firsts = []
         for i in range(40):
-            bal.pick("T", f"f{i}", members)
-        assert len(bal._counts) <= 8 + 1
+            firsts.append(bal.pick("T", f"f{i}", members))
+            assert len(bal._kept) == min(i + 1, 8)
+        assert [f for _t, f in bal._kept] == [f"f{i}" for i in range(32, 40)]
+        # an election warms its group: f32 is no longer the coldest
+        second = bal.pick("T", "f32", members)
+        bal.pick_ordered("T", "o0", self._members(2, "$oshare"), "t")
+        assert [f for _t, f in bal._kept] == (
+            [f"f{i}" for i in range(34, 40)] + ["f32", "o0"])
+        # and had kept its balance: the second of its round is the other
+        assert second is not firsts[32]
+        # f33 was dropped: a new round, not a wrong delivery
+        assert bal.pick("T", "f33", members) in members
+        # 40 groups' first elections, o0's, and f33's second "first"
+        assert bal.drain() == (1, 0, 42, 84)
+
+    # ---- $oshare: rendezvous over (member, topic) ---------------------
+
+    TOPICS = [f"t/s{i % 49}/d{i}/telemetry" for i in range(1000)]
+
+    def _winners(self, bal, members, topics=TOPICS, feed="kept"):
+        return [bal.pick_ordered("T", "$oshare/g/t/#", FEEDS[feed](members),
+                                 topic).receiver_id for topic in topics]
+
+    def test_ordered_is_stable_per_topic_on_every_feed(self):
+        bal = self._balancer()
+        members = self._members(50, "$oshare")
+        first = self._winners(bal, members)
+        assert bal.drain() == (999, 0, 1, 50)
+        assert self._winners(bal, members) == first
+        assert bal.drain() == (1000, 0, 0, 0)
+        # a leg that builds fresh lists, and another service with the
+        # members in another order, elect the same member a topic
+        assert self._winners(bal, members, feed="fresh") == first
+        assert bal.drain() == (0, 1000, 0, 50000)
+        assert self._winners(self._balancer(), members[::-1]) == first
+        assert len(set(first)) > 25
+
+    def test_ordered_is_the_same_in_another_process(self):
+        """No per-process seed in the score (never ``hash()``): a process
+        started under another ``PYTHONHASHSEED`` elects the same."""
+        import json
+        import os
+        import subprocess
+        import sys
+        code = (
+            "import json, random, sys\n"
+            "sys.path[:0] = json.loads(sys.argv[1])\n"
+            "from tests.test_retained_plane import TestGroupBalancer as T\n"
+            "t = T()\n"
+            "print(json.dumps(t._winners(t._balancer(), "
+            "t._members(50, '$oshare'), T.TOPICS[:200])))\n")
+        want = self._winners(self._balancer(), self._members(50, "$oshare"),
+                             self.TOPICS[:200])
+        for seed in ("1", "4242"):
+            env = dict(os.environ, PYTHONHASHSEED=seed, JAX_PLATFORMS="cpu")
+            out = subprocess.run(
+                [sys.executable, "-c", code, json.dumps(sys.path)],
+                env=env, capture_output=True, text=True, timeout=120)
+            assert out.returncode == 0, out.stderr[-2000:]
+            assert json.loads(out.stdout.splitlines()[-1]) == want
+
+    def test_ordered_is_uniform_over_the_members(self):
+        bal = self._balancer()
+        members = self._members(50, "$oshare")
+        n = 20000
+        got = self._winners(bal, members,
+                            [f"t/s{i % 49}/d{i}/telemetry" for i in range(n)])
+        mean = n / 50
+        sigma = (n * (1 / 50) * (1 - 1 / 50)) ** 0.5
+        counts = [got.count(r.receiver_id) for r in members]
+        assert max(abs(c - mean) for c in counts) <= 3 * sigma, counts
+
+    def test_ordered_join_and_leave_move_the_least(self):
+        bal = self._balancer()
+        members = self._members(50, "$oshare")
+        before = self._winners(bal, members)
+        grown = self._members(51, "$oshare")
+        after = self._winners(bal, grown)
+        moved = [(b, a) for b, a in zip(before, after) if a != b]
+        assert moved and all(a == "w50" for _b, a in moved)
+        assert len(moved) <= 3 * len(before) // 51
+        # w7 leaves: only its topics move, each to a member that stayed
+        left = tuple(r for r in grown if r.receiver_id != "w7")
+        last = self._winners(bal, left)
+        moved = [(a, z) for a, z in zip(after, last) if z != a]
+        assert "w7" in after and "w7" not in last
+        assert moved and all(a == "w7" for a, _z in moved)
+        assert bal.drain() == (2997, 2, 1, 151)
 
 
 class TestStandbySupervisor:
