@@ -10,6 +10,11 @@ port; speaks JSON lines on its standard output:
 All times are ``time.monotonic_ns()`` (CLOCK_MONOTONIC, shared with the
 broker process on the one machine). Open-loop latency is taken from the
 time a publish was DUE, not from when it was sent.
+
+A mix with ``resub`` / ``retain_set_per_s`` (``traffic.py``) adds SUBSCRIBE
+lanes and a retained SET / CLEAR stream, each on connections of their own,
+and ships what they saw under the report's ``retained`` key; ``run.py``
+holds it to the plain reference (``reference.RetainedTable``).
 """
 
 from __future__ import annotations
@@ -31,6 +36,7 @@ import traffic as traffic_mod  # noqa: E402
 WARM_FLAG = 1 << 62          # seq of a publish that is not the window's
 HEADER = struct.Struct(">Qq")  # seq, due_ns
 DRAIN_S = 60.0
+SUBACK_S = 10.0              # a lane's SUBACK / UNSUBACK later than this: an error
 
 
 def emit(obj: dict) -> None:
@@ -63,6 +69,14 @@ class Run:
         self.t0 = self.t1 = 0
         self.errors = []
         self.notes = []              # the first missing deliveries, for the log
+        # retained on subscribe (only where the plan has lanes or SETs)
+        self.lanes = []              # RetainClient a SUBSCRIBE lane
+        self.resub_ops = []          # [lane, s, suback, unsub_req, unsuback, filter, window, qos]
+        self.resub_received = []     # [lane, topic, word, version, retain, qos, t_ns]
+        self.retain_pubs = {}        # tenant index -> RetainClient
+        self.retain_events = []      # [topic id, version (-1: CLEAR), sent, ack]
+        self.retain_version = {}     # topic id -> last version sent
+        self.retain_pending = {}     # topic id -> its last event's task
 
     # ---------------- connections -------------------------------------
     def _on_publish(self, client, topic, payload, qos, t_ns) -> None:
@@ -98,6 +112,30 @@ class Run:
         for k, ((t, _j), c) in enumerate(zip(jobs, conns)):
             self.pools.setdefault(t, []).append(c)
             self.conn_index[c] = k
+        if "resub" in self.plan or "retain_sets" in self.plan:
+            await self._connect_retained()
+
+    async def _connect_retained(self) -> None:
+        async def lane(k, t):
+            c = await mqttlite.RetainClient.open(
+                self.port, f"resub{k}", f"{self.tenants[t]}/resub{k}",
+                self._on_lane_publish)
+            c.index = k
+            return c
+        lanes = self.plan.get("resub", {}).get("lanes", ())
+        self.lanes = await self._gather_limited(
+            lane(k, t) for k, (t, _pool) in enumerate(lanes))
+        for t in sorted({e[2] for e in self.plan.get("retain_sets", ())}):
+            self.retain_pubs[t] = await mqttlite.RetainClient.open(
+                self.port, f"rset{t}", f"{self.tenants[t]}/rset{t}")
+
+    def _on_lane_publish(self, client, topic, payload, qos, t_ns,
+                         retain) -> None:
+        word = version = -1
+        if len(payload) >= HEADER.size:
+            word, version = HEADER.unpack_from(payload)
+        self.resub_received.append([client.index, topic.decode(), word,
+                                    version, retain, qos, t_ns])
 
     async def _subscribe(self, idx: int, flt: str, qos: int) -> dict:
         t, _f, _q = self.plan["subs"][idx]
@@ -116,6 +154,99 @@ class Run:
         await self._gather_limited(
             self._subscribe(i, f, q)
             for i, (_t, f, q) in enumerate(self.plan["subs"]))
+        if self.lanes:
+            await self._gather_limited(
+                self._lane_touch(k, pool) for k, (_t, pool)
+                in enumerate(self.plan["resub"]["lanes"]))
+
+    async def _lane_touch(self, k: int, pool: list) -> None:
+        """Every filter of the lane once before the window, as a member of
+        a shared group: the route's trie nodes then exist, so a SUBSCRIBE
+        in the window grows no table (and its slot, once made, is revived
+        on the lane's later visits), while the retain service, which hands
+        a shared subscription nothing [MQTT-4.8.2], caches no scan of it."""
+        for flt in dict.fromkeys(pool):
+            if not await self._lane_op(k, f"$share/warm/{flt}", 0, False):
+                return
+
+    async def _lane_op(self, k: int, flt: str, qos: int, window: bool) -> bool:
+        """SUBSCRIBE, SUBACK, UNSUBSCRIBE, UNSUBACK on lane ``k``."""
+        c = self.lanes[k]
+        rec = [k, now_ns(), 0, 0, 0, flt, window, qos]
+        self.resub_ops.append(rec)
+        try:
+            rc = await c.subscribe(flt, qos, timeout=SUBACK_S)
+            rec[2] = now_ns()
+            if rc != qos:
+                self.errors.append(f"lane {k}: SUBACK {rc} for {flt!r} "
+                                   f"(asked {qos})")
+            rec[3] = now_ns()
+            await c.unsubscribe(flt, timeout=SUBACK_S)
+            rec[4] = now_ns()
+        except (asyncio.TimeoutError, ConnectionError) as e:
+            self.errors.append(f"lane {k} {flt!r}: {e!r}")
+            return False
+        return True
+
+    async def resub_lanes(self) -> None:
+        """Each lane walks its filters round until the window closes."""
+        rs = self.plan["resub"]
+        qos = rs["qos"]
+
+        async def lane(k: int, pool: list) -> None:
+            i = 0
+            while now_ns() < self.t1:
+                flt = pool[i % len(pool)]
+                ok = await self._lane_op(k, flt, qos[(i + k) % len(qos)],
+                                         now_ns() >= self.t0)
+                if not ok:
+                    return
+                i += 1
+        await asyncio.gather(*(lane(k, pool) for k, (_t, pool)
+                               in enumerate(rs["lanes"])))
+
+    # ---------------- retained SET / CLEAR ----------------------------
+    def _retain_event(self, tid: int, tenant: int, topic: str, nbytes: int,
+                      kind: str):
+        """One SET or CLEAR, sent once the topic's previous one is acked
+        (so a topic's versions take effect in the order they are sent)."""
+        task = asyncio.ensure_future(self._retain_send(
+            tid, tenant, topic, nbytes, kind, self.retain_pending.get(tid)))
+        self.retain_pending[tid] = task
+        return task
+
+    async def _retain_send(self, tid, tenant, topic, nbytes, kind,
+                           prev) -> None:
+        if prev is not None:
+            await prev
+        if kind == "set":
+            version = self.retain_version.get(tid, 0) + 1
+            self.retain_version[tid] = version
+            payload = traffic_mod.retained_payload(tid, version, nbytes)
+        else:
+            version, payload = -1, b""
+        rec = [tid, version, now_ns(), 0]
+        self.retain_events.append(rec)
+        fut = self.retain_pubs[tenant].publish_retained(topic.encode(),
+                                                        payload)
+        try:
+            rec[3] = await asyncio.wait_for(fut, 120)
+        except (asyncio.TimeoutError, ConnectionError) as e:
+            self.errors.append(f"retained {kind} {topic}: {e!r}")
+
+    async def retain_warm(self) -> None:
+        for at, tid, t, topic, nbytes, kind in self.plan["retain_sets"]:
+            if at < 0:
+                await self._retain_event(tid, t, topic, nbytes, kind)
+
+    async def retain_stream(self) -> None:
+        for at, tid, t, topic, nbytes, kind in self.plan["retain_sets"]:
+            if at < 0:
+                continue
+            await self._sleep_until(self.t0 + int(at * 1e9))
+            if now_ns() >= self.t1:
+                return
+            self._retain_event(tid, t, topic, nbytes, kind)
 
     # ---------------- publishing --------------------------------------
     def _conn(self, tenant: int):
@@ -376,6 +507,9 @@ class Run:
             except (asyncio.TimeoutError, ConnectionError) as e:
                 self.errors.append(f"fence {k}: {e!r}")
         await self._gather_limited(fence(k) for k in sorted(used))
+        if self.retain_pending:
+            await asyncio.wait(list(self.retain_pending.values()),
+                               timeout=max(1.0, t_end - time.monotonic()))
         self._done_times()
         expect = self.expectations(fence_ns)
         must_total = sum(1 for e in expect.values() if e[0])
@@ -471,11 +605,14 @@ class Run:
             "unacked_qos1": unacked, "errors": self.errors[:20],
             "notes": self.notes,
             "n_errors": len(self.errors),
-            "connections": len(self.sub_clients) + len(self.conn_index),
+            "connections": (len(self.sub_clients) + len(self.conn_index)
+                            + len(self.lanes) + len(self.retain_pubs)),
             "churn_subs": len(sub_ms),
             "churn_unsubs": sum(1 for s in self.subscriptions
                                 if s["unsuback"]),
         }
+        if self.lanes or self.retain_pubs:
+            report["retained"] = self._retained_report()
         shared = [s for s in self.lifetimes() if s["group"] is not None]
         if shared:            # the live half of "one member a group": run.py
             report["shared"] = {    # closes the sum with the stand-in's half
@@ -486,18 +623,35 @@ class Run:
                 "done_ns": [r[8] for r in pubs]}
         return report
 
+    def _retained_report(self) -> dict:
+        """What the lanes and the SET stream saw, for ``run.py``: every
+        lane operation, every PUBLISH a lane received, every SET / CLEAR
+        with its send and PUBACK instants; ``in_window``: the retained
+        deliveries (RETAIN bit) received on a lane inside the window."""
+        return {"ops": self.resub_ops, "received": self.resub_received,
+                "events": self.retain_events,
+                "in_window": sum(1 for r in self.resub_received
+                                 if r[4] and self.t0 <= r[6] < self.t1)}
+
     # ---------------- one window --------------------------------------
     async def window(self) -> dict:
         plan = self.plan
         await self.pre_churn()
+        if "retain_sets" in plan:
+            await self.retain_warm()
         await self.bursts()
         lead = plan["warmup_seconds"] + (0.5 if plan["loop"] == "open" else 0)
         self.t0 = now_ns() + int((lead + 0.2) * 1e9)
         self.t1 = self.t0 + int(plan["seconds"] * 1e9)
         emit({"event": "window", "t0_ns": self.t0, "t1_ns": self.t1})
-        driver = self.open_loop() if plan["loop"] == "open" \
+        publishing = self.open_loop() if plan["loop"] == "open" \
             else self.closed_loop()
-        await asyncio.gather(driver, self.churn())
+        jobs = [publishing, self.churn()]
+        if self.lanes:
+            jobs.append(self.resub_lanes())
+        if "retain_sets" in plan:
+            jobs.append(self.retain_stream())
+        await asyncio.gather(*jobs)
         await self._sleep_until(self.t1)
         return await self.finish()
 
@@ -505,6 +659,7 @@ class Run:
         """A further window on the same connections (sweep, many seeds)."""
         self.plan = plan
         self.received, self.pubs, self.notes = [], [], []
+        self.resub_ops, self.resub_received, self.retain_events = [], [], []
         self.subscriptions = [s for s in self.subscriptions
                               if not s["unsuback"]]
 
@@ -514,6 +669,8 @@ class Run:
         for pool in self.pools.values():
             for c in pool:
                 c.close()
+        for c in self.lanes + list(self.retain_pubs.values()):
+            c.close()
 
 
 async def amain(args) -> None:

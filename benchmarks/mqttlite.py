@@ -4,6 +4,8 @@ broker's wire format is checked by code the broker did not write.
 
 One ``asyncio.Protocol`` per connection; received PUBLISHes go to a plain
 callback with the receive time taken as the bytes come off the socket.
+``RetainClient`` also sends PUBLISHes with the RETAIN bit and hands the
+bit of every PUBLISH it receives to its callback.
 """
 
 from __future__ import annotations
@@ -187,3 +189,32 @@ class Client(asyncio.Protocol):
             self._resolve((UNSUBACK, (body[0] << 8) | body[1]), None)
         elif kind == CONNACK:
             self._resolve((CONNACK, 0), body[1])
+
+
+class RetainClient(Client):
+    """A client that speaks the RETAIN bit: ``publish_retained`` sends a
+    QoS 1 PUBLISH with it set ([MQTT-3.3.1-5]; an empty payload clears the
+    topic), and ``on_publish`` is called as fn(client, topic, payload, qos,
+    t_ns, retain)."""
+
+    def publish_retained(self, topic: bytes, payload: bytes) -> asyncio.Future:
+        pid = self._next_pid()
+        fut = self._expect(PUBACK, pid)
+        self.inflight += 1
+        body = _str(topic) + struct.pack(">H", pid) + payload
+        self.transport.write(_packet(PUBLISH, (1 << 1) | 1, body))
+        return fut
+
+    def _on_packet(self, first: int, body: bytes, now: int) -> None:
+        if first >> 4 != PUBLISH:
+            super()._on_packet(first, body, now)
+            return
+        qos = (first >> 1) & 3
+        tlen = (body[0] << 8) | body[1]
+        topic = body[2:2 + tlen]
+        pos = 2 + tlen
+        if qos:
+            self.transport.write(_packet(PUBACK, 0, body[pos:pos + 2]))
+            pos += 2
+        if self.on_publish is not None:
+            self.on_publish(self, topic, body[pos:], qos, now, first & 1)

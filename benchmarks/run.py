@@ -22,7 +22,9 @@ The comparison: plain routes by count and digest a publish
 (``fleet_verdict``); deliveries a shared group ELECTED, which are the
 program's choice, by the guarantee "exactly one member a matching group",
 summed over the stand-in and the live sessions (``group_verdict``; only in
-cells whose table or traffic holds a ``$share`` / ``$oshare`` group).
+cells whose table or traffic holds a ``$share`` / ``$oshare`` group);
+retained messages handed to a SUBSCRIBE, by count, set and version
+(``retained_verdict``; only in cells whose configuration seeds them).
 """
 
 from __future__ import annotations
@@ -33,6 +35,7 @@ T_START_NS = time.monotonic_ns()
 
 import argparse  # noqa: E402
 import asyncio  # noqa: E402
+import bisect  # noqa: E402
 import gc  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
@@ -53,6 +56,8 @@ from sut import MASK, log  # noqa: E402
 
 OUT_DIR = os.path.join(HERE, ".out")
 log.t0 = T_START_NS / 1e9
+#: the device watchdog's deadline every cell runs under (see ``main``)
+DEADLINE_ENV, DEADLINE_S = "BIFROMQ_DEVICE_DEADLINE_S", "5"
 
 
 # ---------------------------------------------------------------- the child
@@ -319,6 +324,135 @@ def share_skew(stand_in, ref: FleetReference) -> dict:
     return out
 
 
+RETAINED_NUMBERS = ("retained_missing", "retained_surplus", "retained_foreign",
+                    "retained_stale", "retained_flag")
+
+
+def retained_table(rows) -> reference.RetainedTable:
+    table = reference.RetainedTable()
+    for tenant, topic, _nbytes in rows:
+        table.add(tenant, topic)
+    return table
+
+
+def retained_verdict(report, plan, table: reference.RetainedTable,
+                     limit: int) -> dict:
+    """Retained deliveries on the SUBSCRIBE lanes against the plain
+    reference. Every PUBLISH a lane receives belongs to the lane's
+    operation whose SUBSCRIBE -> UNSUBACK holds its receipt; a window
+    operation (SUBSCRIBE sent at ``s`` inside the window, SUBACKed at
+    ``a``) with MUST / MAY the topics its filter matches that were retained
+    throughout / at some instant of ``[s, a]``:
+
+    - ``retained_missing``: fewer than min(limit, |MUST|) distinct topics
+      of MAY handed with the RETAIN bit;
+    - ``retained_surplus``: more than min(limit, |MAY|) topics of MAY
+      handed, or a topic twice; also a lane receipt outside every
+      operation of its lane;
+    - ``retained_foreign``: a delivery of a topic outside MAY (a filter
+      that does not match, another tenant's topic, a topic never
+      retained), or whose name is not its topic id's;
+    - ``retained_stale``: a version not current at some instant of [s, a];
+    - ``retained_flag``: a retained message handed without the RETAIN bit.
+      A delivery without it of a SET or CLEAR sent before the UNSUBACK of
+      a lane's SUBSCRIBE that matches it is that event's live forward
+      ([MQTT-3.3.1-9], [MQTT-3.10.4-3]): either way, no fault, and not a
+      retained delivery.
+
+    Only window operations are judged; ``table`` holds every SET / CLEAR
+    so far."""
+    ret = report["retained"]
+    tenants = plan["tenants"]
+    lane_tenant = [t for t, _pool in plan.get("resub", {}).get("lanes", ())]
+    mark = traffic_mod.RETAINED_MARK
+    ops = {}                                # lane -> its operations by s
+    for op in ret["ops"]:
+        ops.setdefault(op[0], []).append(op)
+    starts = {k: [op[1] for op in v] for k, v in ops.items()}
+    out = dict.fromkeys(RETAINED_NUMBERS, 0)
+    got = {}                                # id(op) -> its retained receipts
+    either = 0
+    first_bad = None
+    for rc in ret["received"]:
+        lane, topic, word, version, retain, _q, t_ns = rc
+        tenant = tenants[lane_tenant[lane]]
+        if word >= 0 and word & mark:
+            tid = word & ~mark
+        else:           # a CLEAR's empty payload names no topic id
+            tid = table.tid_of.get((tenant, topic), -1)
+        i = bisect.bisect_right(starts.get(lane, ()), t_ns) - 1
+        op = ops[lane][i] if i >= 0 else None
+        if retain:
+            if op is None or (op[4] and t_ns > op[4]):
+                out["retained_surplus"] += 1    # outside every SUBSCRIBE
+                first_bad = first_bad or (lane, tenant, "orphan", rc)
+            else:
+                got.setdefault(id(op), []).append((tid, topic, version))
+            continue
+        if op is None or not op[6]:
+            continue
+        # without the RETAIN bit: the live forward of a SET or CLEAR sent
+        # before an UNSUBACK of this lane whose filter matches it, or a
+        # retained message that lost its flag
+        if tid >= 0 and any(
+                o[1] <= t_ns and tid in table.match(tenant, o[5].split("/"))
+                and table.in_flight(tid, version, o[1], o[4] or reference.NEVER)
+                for o in ops[lane][:i + 1]):
+            either += 1
+        else:
+            out["retained_flag" if tid >= 0 else "retained_foreign"] += 1
+            first_bad = first_bad or (lane, tenant, "no RETAIN bit", rc)
+    subs = handed = must_total = 0
+    for lane, lane_ops in ops.items():
+        tenant = tenants[lane_tenant[lane]]
+        for op in lane_ops:
+            _k, s, a, _ureq, _uack, flt, window, qos = op
+            if not window:
+                continue
+            a = a or reference.NEVER
+            must, may = set(), set()
+            for tid in table.match(tenant, flt.split("/")):
+                m, y = table.must_may(tid, s, a)
+                if m:
+                    must.add(tid)
+                if y:
+                    may.add(tid)
+            must_total += len(must)
+            receipts = got.get(id(op), ())
+            good, dup, bad = set(), False, []
+            for tid, topic, version in receipts:
+                if tid < 0 or tid >= len(table.topics) \
+                        or table.topics[tid] != (tenant, topic) \
+                        or tid not in may:
+                    out["retained_foreign"] += 1
+                    bad.append(("foreign", topic, version))
+                elif tid in good:
+                    dup = True
+                else:
+                    good.add(tid)
+                    if not table.current_in(tid, version, s, a):
+                        out["retained_stale"] += 1
+                        bad.append(("stale", topic, version))
+            handed += len(receipts)
+            subs += bool(receipts)
+            if len(good) < min(limit, len(must)):
+                out["retained_missing"] += 1
+                bad.append(("missing", qos, len(good), min(limit, len(must)),
+                            [(table.topics[t][1], table.history(t))
+                             for t in sorted(must - good)[:3]]))
+            if dup or len(good) > min(limit, len(may)):
+                out["retained_surplus"] += 1
+                bad.append(("surplus", len(good), min(limit, len(may))))
+            if bad and first_bad is None:
+                first_bad = (lane, tenant, flt, s, a, bad[:4])
+    n_ops = sum(1 for v in ops.values() for op in v if op[6])
+    out.update({"retained_subs": subs, "window_subscribes": n_ops,
+                "handed": handed, "live_either": either,
+                "must_per_subscribe": must_total / max(1, n_ops),
+                "events": len(ret["events"]), "first_bad": first_bad})
+    return out
+
+
 def fleet_verdict(stand_in, report, plan, ref: FleetReference) -> dict:
     tenants, pop = plan["tenants"], plan["population"]
     pubs = report["publishes"]
@@ -409,13 +543,18 @@ async def run(args, cell) -> list:
         if warmed:
             log(f"mesh: {warmed} per-shard patch programs warmed; tables "
                 f"{sut.table_shapes(matcher)}, fill {sut.table_fill(matcher)}")
+        seat = {}
+        if "retained" in cfg:
+            seat = await seat_retained(cfg, gen, broker, platform, compiles)
         if freeze:
             gc.freeze()
             gc.enable()
         if args.control:
-            sut.CONTROLS[args.control](worker)
+            sut.CONTROLS[args.control](broker)
             log(f"CONTROL in place: {args.control}")
         mu = broker.mem_usage
+        log(f"device watchdog deadline: {DEADLINE_ENV}="
+            f"{os.environ.get(DEADLINE_ENV)}")
         log(f"host rss {mu.rss_bytes() >> 20:,} MiB of budget "
             f"{mu.budget_bytes >> 20:,} MiB")
 
@@ -446,7 +585,7 @@ async def run(args, cell) -> list:
             f"{sut.table_fill(matcher)}; patched "
             f"{matcher.patch_count}, fallbacks {matcher.patch_fallbacks}")
 
-        results, shared = [], {}
+        results, shared = [], {"retained": seat}
         drain = sut.BatchDrain()
         for seed, rate in windows:
             tr = dict(cell["traffic"])
@@ -470,6 +609,28 @@ async def run(args, cell) -> list:
     return results
 
 
+async def seat_retained(cfg, gen, broker, platform, compiles) -> dict:
+    """The configuration's retained messages, seeded and warmed; what the
+    windows need of them."""
+    t0 = time.perf_counter()
+    rows = list(gen.retained(cfg))
+    t_rows = time.perf_counter() - t0
+    seeded = sut.seed_retained(broker, rows)
+    limit = int(cfg["settings"]["RetainMessageMatchLimit"])
+    t0 = time.perf_counter()
+    hits = await sut.warm_retained_scans(
+        broker, gen.retained_stress_filters(cfg), limit)
+    state = sut.retained_device_state(broker, platform)
+    log(f"retained seeded: {seeded['topics']:,} topics (rows "
+        f"{t_rows:.1f}s, KV fill {seeded['kv_fill_s']:.1f}s, reset "
+        f"{seeded['reset_s']:.1f}s, index build + device put "
+        f"{seeded['build_put_s']:.1f}s); {state['bytes']:,} B on "
+        f"{state['on']}; scans warmed in {time.perf_counter() - t0:.1f}s, "
+        f"hits {hits}; cache {compiles.hits} hit(s) / {compiles.misses} "
+        f"miss(es)")
+    return {"broker": broker, "rows": rows, "limit": limit, "table": None}
+
+
 async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
                      compiles, rows, platform, devices, shared) -> dict:
     import jax
@@ -482,12 +643,18 @@ async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
     def at(t_ns, fn):
         loop.call_later(max(0.0, (t_ns - time.monotonic_ns()) / 1e9), fn)
 
+    seat = shared["retained"]
+
     def open_window():
         drain.drain()
         drain.reset()
         snaps["before"] = sut.counters(matcher, stand_in)
+        if seat:
+            snaps["scans_before"] = sut.retained_scans(seat["broker"])
 
     def close_window():
+        if seat:
+            snaps["scans_after"] = sut.retained_scans(seat["broker"])
         drain.drain()
         snaps["batches"] = {"n": drain.n, "rows": drain.rows,
                             "padded": drain.padded, "missed": drain.missed,
@@ -552,6 +719,17 @@ async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
     groups = (group_verdict(stand_in, report, plan, ref)
               if ref.shared or "shared" in report else None)
     skew = share_skew(stand_in, ref) if groups is not None else None
+    retained = None
+    if seat:
+        if seat["table"] is None:
+            seat["table"] = retained_table(seat["rows"])
+        table = seat["table"]
+        for tid, version, sent, ack in report.get("retained", {}).get(
+                "events", ()):
+            table.apply(tid, version, sent, ack)
+        retained = retained_verdict(report, plan, table, seat["limit"]) \
+            if "retained" in report else None
+        rstate = sut.retained_device_state(seat["broker"], platform)
     kernels = snaps["batches"]["kernels"]
     in_window_compiles = compiles.between(t0_ns, t1_ns)
     pubs = report["publishes"]
@@ -593,6 +771,19 @@ async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
             "oshare_split")]
         if ref.shared:              # seeded groups: elections were held
             floors.append(("group_matched", groups["group_matched"], 1))
+    if seat:    # the scan planes served every scan, and walked some inside
+        checks.append(("retained_degraded", sum(
+            n for p in snaps["scans_after"] for n in p["degraded"].values()),
+            0))
+        checks.append(("retained_tables_off_device",
+                       0 if rstate["all_on_platform"] else 1, 0))
+        walks = sut.retained_walked(snaps["scans_after"]) \
+            - sut.retained_walked(snaps["scans_before"])
+        floors.append(("retained_walks", walks, 1))
+        if retained is not None:
+            checks += [(n, retained[n], 0) for n in RETAINED_NUMBERS]
+            if "resub" in plan:     # the lanes ran
+                floors.append(("retained_subs", retained["retained_subs"], 1))
     correct = all(v <= lim for _n, v, lim in checks) and \
         all(v >= lim for _n, v, lim in floors)
     compared = {n: [v, lim] for n, v, lim in checks}
@@ -604,6 +795,8 @@ async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
     counted_s = (snaps["after"]["t_ns"] - snaps["before"]["t_ns"]) / 1e9
     lat = report["latencies_ms"]
     route_deliveries = stand_in.in_window + report["live_in_window"]
+    if "retained" in report:        # retained deliveries on the lanes
+        route_deliveries += report["retained"]["in_window"]
     e2e = {
         "deliver_p50_ms": percentile(lat, 50),
         "deliver_p95_ms": percentile(lat, 95),
@@ -670,6 +863,23 @@ async def one_window(args, cell, plan, gen_proc, stand_in, matcher, drain,
         if groups["first_bad"]:
             log(f"first group fault (seq, tenant, topic, group, stand-in "
                 f"picks, live lo, live hi): {groups['first_bad']}")
+    if seat:
+        log(f"retained scan planes: before {snaps['scans_before']}, after "
+            f"{snaps['scans_after']}; tables {rstate['bytes']:,} B on "
+            f"{rstate['on']}")
+    if retained is not None:
+        log(f"retained: {retained['window_subscribes']:,} window SUBSCRIBEs, "
+            f"{retained['retained_subs']:,} of them handed retained "
+            f"messages, {retained['handed']:,} in all ("
+            f"{report['retained']['in_window']:,} inside the window); MUST "
+            f"{retained['must_per_subscribe']:.1f} a SUBSCRIBE; live "
+            f"forwards of a SET (either way) {retained['live_either']}; "
+            f"SET / CLEAR events this window {retained['events']}; scans "
+            f"walked (not the scan cache's) {walks:,}, a share of the window "
+            f"SUBSCRIBEs {walks / max(1, retained['window_subscribes']):.4f}")
+        if retained["first_bad"]:
+            log(f"first retained fault (lane, tenant, filter, s, a, faults): "
+                f"{retained['first_bad']}")
     for e in report["errors"]:
         log(f"load generator error: {e}")
     for e in report.get("notes", ()):
@@ -781,6 +991,14 @@ def main(argv=None) -> int:
               "directory — nothing to measure", file=sys.stderr)
         return 3
     os.makedirs(OUT_DIR, exist_ok=True)
+    # The program's device watchdog serves a batch from the host oracle once
+    # it has waited 32 x the p99 of dispatch + ready, at least 0.25 s: in a
+    # cell of small batches on an idle loop that is a quarter of a second,
+    # which one host stall or the profiler's start and stop can spend. A
+    # batch that is late for that is late, not wrong, and the chip still did
+    # its work. Every cell waits the program's own cold-start deadline, 5 s,
+    # so that only a device that hangs (or raises) fails the chip clause.
+    os.environ[DEADLINE_ENV] = DEADLINE_S
     results = asyncio.run(run(args, cell))
     import jax
     devices = jax.devices()

@@ -8,10 +8,17 @@
   arithmetic gives hand-worked answers;
 - the plain reference agrees with hand-written ``+`` / ``#`` / ``$share``
   / ``$``-topic cases, and its two forms agree with each other;
-- the schedule is a pure function of ``--seed``, and seeds share the work;
+- the schedule is a pure function of ``--seed``, and seeds share the work
+  (the retained lanes and SET stream too);
 - every file under ``configs/``, ``traffic/``, ``layer_metrics/`` loads,
   every name and unit holds only the allowed characters, every per-layer
-  metric of BENCHMARK.json has its file and its reader.
+  metric of BENCHMARK.json has its file and its reader;
+- retained on subscribe: a configuration's ``retained`` section needs a
+  generator that offers ``retained(cfg)`` (and its three helpers) and a
+  ``RetainMessageMatchLimit`` among its settings; a mix with ``resub`` or
+  ``retain_set_per_s`` runs only in cells whose configuration has the
+  section (BENCHMARK.json's and the tests' files of its form); a cell of
+  BENCHMARK.json with the section keeps the program's ``MinSendPerSec``.
 """
 
 from __future__ import annotations
@@ -107,7 +114,38 @@ def check_reference() -> None:
         check(brute == fast, f"table.match != definition on {topic}")
     check(table.match("other", "l0") == [], "no row crosses a tenant")
     check_shared_rows(gen, rng, names, cum)
+    check_retained_table(gen, rng, names, cum)
     check(len(reference.truncated(list(range(100)), 64)) == 64, "control")
+
+
+def check_retained_table(gen, rng, names, cum) -> None:
+    """``RetainedTable.match`` (a filter over retained topics) against the
+    definition, on drawn topics and filters and on the ``$`` / parent
+    cases; and a version's MUST / MAY around one SET."""
+    table, topics = reference.RetainedTable(), []
+    for _ in range(2000):
+        topic = "/".join(gen.gen_topic(rng, names, cum, max_depth=4))
+        if ("t", topic) not in table.tid_of:
+            topics.append(topic)
+            table.add("t", topic)
+    for topic in ("$SYS/x", "$SYS/x/y", "a"):
+        topics.append(topic)
+        table.add("t", topic)
+    filters = [tuple(gen.gen_filter(rng, names, cum, max_depth=4, p_plus=0.3,
+                                    p_hash=0.2)) for _ in range(300)]
+    filters += [("#",), ("+",), ("+", "x"), ("$SYS", "#"), ("a", "#")]
+    for flt in filters:
+        brute = sorted(i for i, t in enumerate(topics)
+                       if reference.filter_matches(flt, t.split("/")))
+        check(sorted(table.match("t", flt)) == brute,
+              f"RetainedTable.match({flt}) != definition")
+        check(table.match("other", flt) == (), "no topic crosses a tenant")
+    table.apply(0, 1, 100, 200)          # SET v1 sent at 100, acked at 200
+    check(table.must_may(0, 50, 90) == (True, True), "before the SET")
+    check(table.must_may(0, 150, 160) == (False, True), "inside the SET")
+    check(table.current_in(0, 0, 150, 160) and table.current_in(0, 1, 150, 160)
+          and not table.current_in(0, 0, 250, 260),
+          "versions around one SET")
 
 
 SHARED_CASES = [  # (rows as filter strings, topic, {group filter: members})
@@ -176,21 +214,92 @@ def check_schedule() -> None:
     cfg = traffic.load_json("configs", "rehearsal_20k.json")
     for mix in ("rehearsal_open", "rehearsal_closed"):
         tr = traffic.load_json("traffic", mix + ".json")
-        big = 2 ** 31 + 12345
-        a = traffic.build_plan(cfg, tr, big, 5.0)
-        b = traffic.build_plan(cfg, tr, big, 5.0)
-        c = traffic.build_plan(cfg, tr, big + 1, 5.0)
-        check(traffic.fingerprint(a) == traffic.fingerprint(b),
-              f"{mix}: the same seed gives another plan")
-        check(traffic.fingerprint(a) != traffic.fingerprint(c),
-              f"{mix}: another seed gives the same plan")
-        key = "arrivals" if tr["loop"] == "open" else "cycle"
-        check(sorted(x[-2:] for x in a[key]) == sorted(x[-2:] for x in c[key]),
-              f"{mix}: seeds do not share the multiset of (tenant, topic)")
-        check(a["subs"] == c["subs"], f"{mix}: live filters differ by seed")
-        if tr["loop"] == "open":
-            check(all(0 <= x[0] < 5.0 for x in a["arrivals"]),
-                  "an arrival outside the window")
+        check_plan_purity(cfg, tr, mix)
+    check_retained_schedule()
+
+
+def check_retained_schedule() -> None:
+    cfg = traffic.load_json("configs", "rehearsal_retained_20k.json")
+    tr = traffic.load_json("traffic", "rehearsal_resub.json")
+    check_plan_purity(cfg, tr, "rehearsal_resub")
+    big = 2 ** 31 + 12345
+    a = traffic.build_plan(cfg, tr, big, 5.0)
+    c = traffic.build_plan(cfg, tr, big + 1, 5.0)
+    check([(t, sorted(p)) for t, p in a["resub"]["lanes"]]
+          == [(t, sorted(p)) for t, p in c["resub"]["lanes"]],
+          "rehearsal_resub: seeds do not share the lanes' filters")
+    check(sorted(e[1] for e in a["retain_sets"])
+          == sorted(e[1] for e in c["retain_sets"])
+          and [(e[0], e[5]) for e in a["retain_sets"]]
+          == [(e[0], e[5]) for e in c["retain_sets"]],
+          "rehearsal_resub: seeds do not share the SET / CLEAR multiset, "
+          "instants and kinds")
+    gen = traffic.generator_of(cfg)
+    for at, tid, t, topic, nbytes, _kind in a["retain_sets"]:
+        check(gen.retained_row(cfg, tid) == (a["tenants"][t], topic, nbytes)
+              and at < 5.0, f"retained event {tid} {topic!r}")
+    for i, row in enumerate(gen.retained(cfg)):
+        if i % 997 == 0:
+            check(gen.retained_row(cfg, i) == row, f"retained_row({i})")
+    check(traffic.retained_header(traffic.retained_payload(7, 3, 64))
+          == (7, 3) and traffic.retained_header(b"") is None,
+          "retained payload header")
+
+
+def check_plan_purity(cfg: dict, tr: dict, mix: str) -> None:
+    big = 2 ** 31 + 12345
+    a = traffic.build_plan(cfg, tr, big, 5.0)
+    b = traffic.build_plan(cfg, tr, big, 5.0)
+    c = traffic.build_plan(cfg, tr, big + 1, 5.0)
+    check(traffic.fingerprint(a) == traffic.fingerprint(b),
+          f"{mix}: the same seed gives another plan")
+    check(traffic.fingerprint(a) != traffic.fingerprint(c),
+          f"{mix}: another seed gives the same plan")
+    key = "arrivals" if tr["loop"] == "open" else "cycle"
+    check(sorted(x[-2:] for x in a[key]) == sorted(x[-2:] for x in c[key]),
+          f"{mix}: seeds do not share the multiset of (tenant, topic)")
+    check(a["subs"] == c["subs"], f"{mix}: live filters differ by seed")
+    if tr["loop"] == "open":
+        check(all(0 <= x[0] < 5.0 for x in a["arrivals"]),
+              "an arrival outside the window")
+
+
+def check_retained_config(cfg: dict, where: str) -> None:
+    """A ``retained`` section needs a generator that seeds it and a limit
+    the reference can read as data."""
+    if "retained" not in cfg:
+        return
+    gen = importlib.import_module(f"generators.{cfg['generator']}")
+    for fn in ("retained", "retained_count", "retained_row",
+               "retained_stress_filters"):
+        check(callable(getattr(gen, fn, None)),
+              f"{where}: a retained section, and generator "
+              f"{cfg['generator']!r} offers no {fn}(cfg)")
+    check(callable(getattr(getattr(gen, "FilterSource", None),
+                           "retained_topic", None)),
+          f"{where}: a retained section, and generator {cfg['generator']!r} "
+          "offers no FilterSource.retained_topic(rng)")
+    check("RetainMessageMatchLimit" in cfg.get("settings", {}),
+          f"{where}: a retained section without RetainMessageMatchLimit "
+          "in its settings")
+
+
+def check_retained_cell(cfg: dict, mix: dict, where: str) -> None:
+    """SUBSCRIBE lanes and retained SETs need seeded retained messages."""
+    for key in ("resub", "retain_set_per_s"):
+        check(key not in mix or "retained" in cfg,
+              f"{where}: the mix has {key!r} and the configuration no "
+              "retained section")
+
+
+def check_retained_window(cfg: dict, where: str) -> None:
+    """A cell of BENCHMARK.json that seeds retained messages runs at the
+    program's own ``MinSendPerSec``: at its default 8 a QoS 1 SUBSCRIBE
+    whose receive window has shrunk is handed 8 of its 10 retained
+    messages, and a raised floor would keep that from ``retained_missing``.
+    The rehearsal (a tests file) states its raised floor as its exception."""
+    check("retained" not in cfg or "MinSendPerSec" not in cfg.get(
+        "settings", {}), f"{where}: a retained cell sets MinSendPerSec")
 
 
 def check_files() -> None:
@@ -210,6 +319,7 @@ def check_files() -> None:
                 importlib.import_module(f"readers.{data['reader']}").read
             if kind == "configs":
                 importlib.import_module(f"generators.{data['generator']}")
+                check_retained_config(data, path)
                 for key in data.get("reduced", []):
                     check(NAME.match(key), f"{path}: reduced key {key!r}")
     cells = {w["name"] for w in bench["workloads"]}
@@ -227,6 +337,15 @@ def check_files() -> None:
     for w in bench["workloads"]:
         check(NAME.match(w["name"]) and len(w["why"]) <= 200, w["name"])
         traffic.load_cell(w["name"])
+    for path in [None] + sorted(glob.glob(os.path.join(HERE, "tests",
+                                                       "*_bench.json"))):
+        with open(path or os.path.join(root, "BENCHMARK.json")) as f:
+            cells = json.load(f)["workloads"]
+        for w in cells:
+            cell = traffic.load_cell(w["name"], path or "")
+            check_retained_cell(cell["config"], cell["traffic"], w["name"])
+            if path is None:
+                check_retained_window(cell["config"], w["name"])
     peaks = traffic.load_json("peaks.json")
     check(peaks["source"] and "TPU v5 lite" in peaks["peaks"], "peaks.json")
 

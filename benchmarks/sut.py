@@ -23,7 +23,8 @@ if ROOT not in sys.path:
 if HERE not in sys.path:
     sys.path.insert(0, HERE)
 
-from reference import is_shared  # noqa: E402  (beside this file; no program)
+import traffic  # noqa: E402  (beside this file; no program)
+from reference import is_shared  # noqa: E402
 
 FLEET_BROKER_ID = 7
 WARM_FLAG = 1 << 62
@@ -296,6 +297,93 @@ def seed_worker(worker, tries) -> dict:
             "matcher": matcher}
 
 
+def seed_retained(broker, rows) -> dict:
+    """Give the started retain service the state a restarted one holds:
+    every row ``(tenant, topic, payload bytes)`` a retained message in its
+    range's KV space, in the program's own value encoding
+    (``retain.coproc.enc_retained`` of ``schema.encode_message``), then the
+    co-processor's own ``reset`` (rebuild-from-KV: values and
+    ``RetainedIndex``), then the index's first build and device put
+    (``refresh``). No raft proposal a topic. A row's payload is
+    ``traffic.retained_payload(topic id, 0, bytes)``, its topic id its
+    place in ``rows``."""
+    import jax
+    from bifromq_tpu.kv import schema
+    from bifromq_tpu.retain.coproc import dec_retained, enc_retained
+    from bifromq_tpu.types import ClientInfo, Message, QoS
+    store = broker.retain_service.kvstore
+    (rid, coproc), = store.coprocs.items()
+    space = store.ranges[rid].space
+    frames = {}             # (tenant, bytes) -> (value head, value tail)
+
+    def frame(tenant, nbytes):
+        # the encoding of two payloads of one length differs only in the
+        # payload's own bytes: what is before and after them is the frame
+        enc = [enc_retained(schema.encode_message(Message(
+            message_id=0, pub_qos=QoS(1), payload=fill * nbytes, timestamp=0,
+            is_retain=True)), ClientInfo(tenant_id=tenant), None)
+            for fill in (b"\x00", b"\xff")]
+        at = next(i for i, (x, y) in enumerate(zip(*enc)) if x != y)
+        return enc[0][:at], enc[0][at + nbytes:]
+    t0 = time.perf_counter()
+    w = space.writer()
+    for tid, (tenant, topic, nbytes) in enumerate(rows):
+        head_tail = frames.get((tenant, nbytes))
+        if head_tail is None:
+            head_tail = frames[(tenant, nbytes)] = frame(tenant, nbytes)
+        w.put(schema.retain_key(tenant, topic), head_tail[0]
+              + traffic.retained_payload(tid, 0, nbytes) + head_tail[1])
+    w.done()
+    t_fill = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    coproc.reset(space)
+    t_reset = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    index = coproc.index
+    index.refresh()
+    jax.block_until_ready(index._device_tables)
+    t_build = time.perf_counter() - t0
+    # the value a scan decodes is the one seeded: checked on the first row
+    tenant, topic, nbytes = rows[0]
+    _exp, pub, msg = dec_retained(coproc.values[tenant][topic])
+    if pub.tenant_id != tenant or bytes(msg.payload) != \
+            traffic.retained_payload(0, 0, nbytes) or not msg.is_retain:
+        raise RuntimeError(f"seeded retained value of {topic!r} decodes "
+                           f"as {msg!r}")
+    return {"kv_fill_s": t_fill, "reset_s": t_reset, "build_put_s": t_build,
+            "topics": sum(len(v) for v in coproc.values.values())}
+
+
+async def warm_retained_scans(broker, filters, limit: int) -> list:
+    """Each set-up filter once through the public
+    ``RetainService.match_batch``, one at a time (a SUBSCRIBE scans one
+    filter: batch 16): the device walk, the probe tokenizer and, for a
+    ``+`` row past the walk's states, the native escalation, all built
+    before the window. Returns the hits a filter."""
+    from bifromq_tpu.utils import topic as topic_util
+    service = broker.retain_service
+    out = []
+    for tenant, flt in filters:
+        hits = await service.match_batch(
+            [(tenant, topic_util.parse(flt))], limit)
+        out.append(len(hits[0]))
+    return out
+
+
+def retained_device_state(broker, platform: str) -> dict:
+    """Bytes of the retain ranges' device tables and where they lie."""
+    import jax
+    on, nbytes = set(), 0
+    for coproc in broker.retain_service.kvstore.coprocs.values():
+        dev = coproc.index._device_tables
+        for a in jax.tree_util.tree_leaves(dev):
+            on |= set(a.devices())
+            nbytes += int(a.nbytes)
+    return {"bytes": nbytes, "on": sorted(str(d) for d in on),
+            "all_on_platform": bool(on) and all(d.platform == platform
+                                                for d in on)}
+
+
 def warm_patch_programs(matcher) -> int:
     """A mesh builds one scatter program a shard, a table and a donation
     mode (``parallel/sharded.py``: ``shard`` is a static argument), each on
@@ -507,7 +595,8 @@ def host_rss_bytes() -> dict:
 
 def retained_scans(broker) -> list:
     """A snapshot a range of the SUBSCRIBE-side retained scan plane: scans,
-    those it served from the host oracle by reason, its breaker. A degraded
+    those it served from the host oracle by reason, its breaker, its
+    filter-keyed result cache (hits are scans no walk served). A degraded
     scan counts under ``match_degraded`` like a degraded match; this says
     which it was. ``[]`` where the program has no such plane."""
     service = getattr(broker, "retain_service", None)
@@ -518,8 +607,15 @@ def retained_scans(broker) -> list:
         if plane is not None:
             snap = plane.snapshot()
             out.append({k: snap[k] for k in ("scans_total", "degraded",
-                                             "breaker") if k in snap})
+                                             "breaker", "cache") if k in snap})
     return out
+
+
+def retained_walked(snapshot: list) -> int:
+    """Scans the planes of a ``retained_scans`` snapshot served by a walk:
+    every scan less those the filter-keyed cache answered."""
+    return sum(p["scans_total"] - p.get("cache", {}).get("hits", 0)
+               for p in snapshot)
 
 
 def memory_peak_bytes() -> int:
@@ -530,6 +626,30 @@ def memory_peak_bytes() -> int:
 
 
 # ------------------------------------------------- controls and faults
+
+def _wrap_retained(broker, alter) -> None:
+    """Alter what the retain service answers a SUBSCRIBE (the seat the
+    session calls, ``RetainService.match``)."""
+    service = broker.retain_service
+    inner = service.match
+
+    async def match(tenant_id, filter_levels, limit):
+        return alter(await inner(tenant_id, filter_levels, limit))
+    service.match = match
+
+
+def _seed_version(hits):
+    # every hit in the version the seed gave it (a later SET not served)
+    import dataclasses
+    out = []
+    for topic, msg in hits:
+        head = traffic.retained_header(bytes(msg.payload))
+        if head is not None:
+            msg = dataclasses.replace(msg, payload=traffic.retained_payload(
+                head[0], 0, len(msg.payload)))
+        out.append((topic, msg))
+    return out
+
 
 def _wrap_match(worker, alter) -> None:
     inner = worker.match_batch
@@ -567,11 +687,16 @@ def _share_none(m) -> None:
     m.groups = {}
 
 
-# name -> fn(worker): put a broken guarantee in the matcher's place. The
-# benchmark's own runs never use them; ``--control`` and the tests do.
+# name -> fn(broker): put a broken guarantee in the matcher's (or the
+# retain service's) place. The benchmark's own runs never use them;
+# ``--control`` and the tests do.
 CONTROLS = {
-    "truncate64": lambda worker: _wrap_match(worker, _truncate64),
-    "drop_one": lambda worker: _wrap_match(worker, _drop_one),
-    "share_all": lambda worker: _wrap_match(worker, _share_all),
-    "share_none": lambda worker: _wrap_match(worker, _share_none),
+    "truncate64": lambda broker: _wrap_match(broker.dist.worker, _truncate64),
+    "drop_one": lambda broker: _wrap_match(broker.dist.worker, _drop_one),
+    "share_all": lambda broker: _wrap_match(broker.dist.worker, _share_all),
+    "share_none": lambda broker: _wrap_match(broker.dist.worker, _share_none),
+    # each SUBSCRIBE's last retained message withheld
+    "retained_drop_one": lambda broker: _wrap_retained(
+        broker, lambda hits: hits[:-1]),
+    "retained_stale": lambda broker: _wrap_retained(broker, _seed_version),
 }

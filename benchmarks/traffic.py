@@ -36,6 +36,22 @@ Keys of a traffic file (all data, no code per mix):
   order_block     the seed permutes publishes (and gaps) inside blocks of
                   this many
   shape_seed      seed of the fixed multisets
+
+Retained on subscribe (only with a configuration whose ``retained``
+section seeds retained messages; a mix without these keys plans as before):
+  resub           {"lanes": n, "qos": cycle, "filter_draw": "uniform" |
+                  "zipf", "pool": p}: n SUBSCRIBE lanes on connections of
+                  their own, each of one tenant (drawn by size) with p state
+                  filters of its own from the generator's
+                  ``FilterSource.draw(rng, retained=True)``; a lane walks its
+                  filters round, SUBSCRIBE -> SUBACK -> UNSUBSCRIBE ->
+                  UNSUBACK, one operation in flight
+  retain_set_per_s   retained PUBLISHes (RETAIN bit, QoS 1) a second on the
+                  seeded topics, from a publisher pool of their own
+  retain_clear_share the share of them with an empty payload (a CLEAR)
+The lanes' filters and the SET topics are fixed multisets (a SET's topic
+drawn by the generator's ``FilterSource.retained_topic``); ``--seed``
+orders them, as it orders the publishes.
 """
 
 from __future__ import annotations
@@ -46,9 +62,32 @@ import json
 import math
 import os
 import random
+import struct
 from typing import List
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+
+# A retained message's payload begins (RETAINED_MARK | topic id, version):
+# the first word is above every seq the load generator numbers its
+# publishes with (window seqs count from 0, warm-up ones from 1 << 62), so
+# no reader takes it for a publish of its own. Version 0 is the seed's.
+RETAINED_MARK = 1 << 63
+_HEADER = struct.Struct(">Qq")
+
+
+def retained_payload(tid: int, version: int, nbytes: int) -> bytes:
+    head = _HEADER.pack(RETAINED_MARK | tid, version)
+    return head + b"r" * max(0, nbytes - len(head))
+
+
+def retained_header(payload: bytes):
+    """(topic id, version) of a retained message's payload, else ``None``."""
+    if len(payload) < _HEADER.size:
+        return None
+    word, version = _HEADER.unpack_from(payload)
+    if not word & RETAINED_MARK:
+        return None
+    return word & ~RETAINED_MARK, version
 
 
 def load_json(*parts: str) -> dict:
@@ -220,7 +259,54 @@ def build_plan(cfg: dict, traffic: dict, seed: int, seconds: float) -> dict:
         for _ in range(int(settle["round"]) * int(settle["max_rounds"]))]
     stride = int(traffic["sample_stride"])
     plan["sample"] = (stride, order.randrange(stride))
+    if "resub" in traffic:
+        plan["resub"] = _resub_lanes(gen, cfg, traffic, fixed, order,
+                                     tenant_cum)
+    if float(traffic.get("retain_set_per_s", 0)) > 0:
+        plan["retain_sets"] = _retain_sets(gen, cfg, traffic, fixed, order,
+                                           tenants, seconds)
     return plan
+
+
+def _resub_lanes(gen, cfg, traffic, fixed, order, tenant_cum) -> dict:
+    """Each lane's tenant and its filters, in the order ``--seed`` gives."""
+    rs = traffic["resub"]
+    source = gen.FilterSource(cfg)
+    zipf = rs.get("filter_draw", "uniform") == "zipf"
+    lanes = []
+    for _k in range(int(rs["lanes"])):
+        t = bisect.bisect_left(tenant_cum, fixed.random() * tenant_cum[-1])
+        pool = [source.draw(fixed, retained=True, zipf=zipf)
+                for _ in range(int(rs["pool"]))]
+        _shuffle_blocks(order, pool, int(traffic["order_block"]))
+        lanes.append((t, pool))
+    return {"lanes": lanes, "qos": [int(q) for q in rs["qos"]]}
+
+
+def _retain_sets(gen, cfg, traffic, fixed, order, tenants, seconds) -> list:
+    """Retained SET / CLEAR events ``(at, topic id, tenant index, topic,
+    payload bytes, kind)``: instants and kinds fixed by ``shape_seed``, the
+    topics a fixed multiset that ``--seed`` orders. Three events at ``at``
+    -1 go before the window (a SET, then a CLEAR and a SET of one topic):
+    they warm the path and leave the seed's topic set as it was."""
+    per_s = float(traffic["retain_set_per_s"])
+    clear_share = float(traffic.get("retain_clear_share", 0))
+    source = gen.FilterSource(cfg)
+    n = int(seconds * per_s)
+    ats = [(j + fixed.random() * 0.8) / per_s for j in range(n)]
+    kinds = ["clear" if fixed.random() < clear_share else "set"
+             for _ in range(n)]
+    tids = [source.retained_topic(fixed) for _ in range(n)]
+    order.shuffle(tids)
+    a, b = source.retained_topic(fixed), source.retained_topic(fixed)
+    events = [(-1.0, a, "set"), (-1.0, b, "clear"), (-1.0, b, "set")]
+    events += list(zip(ats, tids, kinds))
+    index = {t: i for i, t in enumerate(tenants)}
+    out = []
+    for at, tid, kind in events:
+        tenant, topic, nbytes = gen.retained_row(cfg, tid)
+        out.append((at, tid, index[tenant], topic, nbytes, kind))
+    return out
 
 
 def fingerprint(plan: dict) -> str:
@@ -228,5 +314,9 @@ def fingerprint(plan: dict) -> str:
     import hashlib
     keys = ("subs", "warm", "bursts", "qos_phase", "pools", "churn",
             "sample", "arrivals", "cycle", "settle_filters")
-    blob = json.dumps({k: plan.get(k) for k in keys}, sort_keys=True)
+    held = {k: plan.get(k) for k in keys}
+    for k in ("resub", "retain_sets"):     # only where the mix has them
+        if k in plan:
+            held[k] = plan[k]
+    blob = json.dumps(held, sort_keys=True)
     return hashlib.sha256(blob.encode()).hexdigest()
