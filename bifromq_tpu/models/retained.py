@@ -25,6 +25,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import trace
 from ..types import RouteMatcher, RouteMatcherType
 from ..utils import topic as topic_util
 from ..utils.env import env_bool
@@ -378,6 +379,7 @@ class RetainedIndex:
         res = retained_walk_ext(self._device_tables, prep.probes,
                                 probe_len=prep.ct.probe_len,
                                 k_states=k_states or self.k_states)
+        trace.count("retain.scan.walks")
         return prep, res
 
     @staticmethod
@@ -421,18 +423,22 @@ class RetainedIndex:
             recv = self._receiver_arr
         from ..retained_plane.patched import RetainedPatchableTrie
         base = ct if isinstance(ct, RetainedPatchableTrie) else None
-        pristine = base is None or base.pristine
         kind_arr = ct.slot_kind if (base is not None
                                     and base.dead_slots) else None
+        # a base with tombstones: expand up to ``cap`` slots a row, drop
+        # the dead ones, trim back to ``limit``
+        cap = None if limit is None else limit + (
+            base.expansion_budget() if kind_arr is not None else 0)
 
         # native escalation: '+'-exploded rows resolve via the C++ DFS
-        # over the same compiled tables — ONLY while the base is
-        # pristine (the native walker reads the frozen subtree ranges;
-        # patch-era extras/tombstones route overflow rows to the exact
-        # Python oracle until the next compaction)
+        # over the same compiled tables. The walker reads the base's
+        # subtree ranges, exhaustive while no patch-era slot exists
+        # (tombstones only mark base slots dead, filtered below); with
+        # patch-era extras the rows go to the exact Python oracle until
+        # the next compaction
         native_map: Dict[int, tuple] = {}
         esc = np.nonzero(overflow & (lengths >= 0) & (roots_a >= 0))[0]
-        if esc.size and pristine:
+        if esc.size and (base is None or base.extra_live == 0):
             try:
                 from .native_retained import match_rows_native
                 sub_tok = tokenize_filters(
@@ -441,15 +447,15 @@ class RetainedIndex:
                     max_levels=ct.max_levels, salt=ct.salt)
                 rr, rn, rovf = match_rows_native(
                     ct, sub_tok.tok_h1, sub_tok.tok_h2, sub_tok.tok_kind,
-                    sub_tok.lengths, sub_tok.roots, limit=limit)
+                    sub_tok.lengths, sub_tok.roots, limit=cap)
                 for j, qi in enumerate(esc):
                     if not rovf[j]:
                         n = int(rn[j])
                         s0 = rr[j, :n, 0].astype(np.int64)
                         c0 = np.maximum(rr[j, :n, 1], 0).astype(np.int64)
-                        if limit is not None and n:
+                        if cap is not None and n:
                             cum = np.cumsum(c0)
-                            c0 = np.clip(limit - (cum - c0), 0, c0)
+                            c0 = np.clip(cap - (cum - c0), 0, c0)
                         native_map[int(qi)] = (s0, c0)
                         overflow[qi] = False
             except Exception:  # noqa: BLE001 — no compiler / load failure:
@@ -466,13 +472,9 @@ class RetainedIndex:
         for qi in native_map:
             counts[qi] = 0      # grid contributes nothing for native rows
             ecounts[qi] = 0
-        if limit is not None:
+        if cap is not None:
             # clip the CONCATENATED base+extras counts so expansion stops
-            # at the cap (scan-bounded like RetainMessageMatchLimit); a
-            # base with tombstones gets dead-slot head-room, trimmed back
-            # after host filtering
-            cap = limit if kind_arr is None \
-                else limit + base.expansion_budget()
+            # at the cap (scan-bounded like RetainMessageMatchLimit)
             all_c = np.concatenate([counts, ecounts], axis=1)
             cum = np.cumsum(all_c, axis=1)
             all_c = np.clip(cap - (cum - all_c), 0, all_c)
@@ -500,7 +502,15 @@ class RetainedIndex:
         else:
             eslots = eidx
 
+        def _live(row):
+            if kind_arr is not None and row.size:
+                row = row[kind_arr[row] != CompiledTrie.SLOT_DEAD]
+            if limit is not None and row.size > limit:
+                row = row[:limit]
+            return list(recv[row]) if row.size else []
+
         out: List[List[str]] = []
+        n_oracle = 0
         for qi, (tenant_id, levels) in enumerate(queries):
             if roots_a[qi] < 0:
                 out.append([])
@@ -508,27 +518,27 @@ class RetainedIndex:
             if qi in native_map:
                 s0, c0 = native_map[qi]
                 tot = int(c0.sum())
-                if tot:
-                    o = np.cumsum(c0) - c0
-                    flat = (np.arange(tot, dtype=np.int64)
-                            - np.repeat(o, c0) + np.repeat(s0, c0))
-                    out.append(list(recv[flat]))
-                else:
-                    out.append([])
+                o = np.cumsum(c0) - c0
+                out.append(_live(np.arange(tot, dtype=np.int64)
+                                 - np.repeat(o, c0) + np.repeat(s0, c0)))
                 continue
             if host_rows[qi]:
+                n_oracle += 1
                 trie = self.tries.get(tenant_id)
                 out.append(match_filter_host(trie, list(levels),
                                              limit=limit)
                            if trie is not None else [])
                 continue
-            row = np.concatenate([bslots[boffs[qi]:boffs[qi + 1]],
-                                  eslots[eoffs[qi]:eoffs[qi + 1]]])
-            if kind_arr is not None and row.size:
-                row = row[kind_arr[row] != CompiledTrie.SLOT_DEAD]
-            if limit is not None and row.size > limit:
-                row = row[:limit]
-            out.append(list(recv[row]) if row.size else [])
+            out.append(_live(np.concatenate(
+                [bslots[boffs[qi]:boffs[qi + 1]],
+                 eslots[eoffs[qi]:eoffs[qi + 1]]])))
+        # which path answered each row: the device walk, the native
+        # walker, the host oracle
+        trace.count("retain.rows.device", nq - len(native_map) - n_oracle)
+        if native_map:
+            trace.count("retain.rows.native", len(native_map))
+        if n_oracle:
+            trace.count("retain.rows.oracle", n_oracle)
         return out
 
     # ---------------- sync entry points -------------------------------------

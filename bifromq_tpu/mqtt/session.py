@@ -20,8 +20,9 @@ import functools
 import logging
 import time
 import uuid
+from collections import deque
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Deque, Dict, List, Optional, Sequence, Set, Tuple
 
 from ..dist.service import DistService
 from ..plugin.auth import IAuthProvider, MQTTAction
@@ -275,8 +276,9 @@ class _OutboundQoS:
 
 
 # _send_publish result: the send was gated by receive-maximum / packet-id
-# exhaustion. Transient sessions drop (and report); persistent sessions
-# stop fetching and retry after acks free the window.
+# exhaustion. Transient sessions drop (and report) a live message;
+# persistent sessions stop fetching and retry after acks free the window;
+# a SUBSCRIBE's retained messages wait in the session's retained backlog.
 BLOCKED = object()
 
 log = logging.getLogger(__name__)
@@ -370,6 +372,10 @@ class Session:
         self._will_suppressed = False
         self._pid_alloc = _PacketIdAllocator()
         self._outbound: Dict[int, _OutboundQoS] = {}
+        # retained messages a SUBSCRIBE matched that found the send window
+        # full: sent, in order, as PUBACKs / PUBCOMPs free packet ids
+        self._retained_backlog: Deque[Tuple[str, Message, Subscription]] = \
+            deque()
         self._inbound_qos2: Set[int] = set()
         self._recv_topic_alias: Dict[int, str] = {}
         # per-session publish-rate token bucket (≈ ExceedPubRate guard,
@@ -442,6 +448,7 @@ class Session:
         self.session_registry.unregister(self)
         self.local_registry.unregister(self)
         OBS.e2e.drop_watermark(self.session_id)
+        self._retained_backlog.clear()
         for tf, sub in list(self.subscriptions.items()):
             await self._unroute(sub)
         self.subscriptions.clear()
@@ -499,12 +506,16 @@ class Session:
             await self._on_publish(packet)
         elif isinstance(packet, pk.PubAck):
             self._on_puback(packet.packet_id)
+            if self._retained_backlog:
+                await self._drain_retained()
         elif isinstance(packet, pk.PubRec):
             await self._on_pubrec(packet.packet_id)
         elif isinstance(packet, pk.PubRel):
             await self._on_pubrel(packet.packet_id)
         elif isinstance(packet, pk.PubComp):
             self._on_pubcomp(packet.packet_id)
+            if self._retained_backlog:
+                await self._drain_retained()
         elif isinstance(packet, pk.Subscribe):
             await self._on_subscribe(packet)
         elif isinstance(packet, pk.Unsubscribe):
@@ -966,25 +977,57 @@ class Session:
         return granted
 
     async def _deliver_retained(self, sub: Subscription) -> None:
-        limit = self.settings[Setting.RetainMessageMatchLimit]
-        try:
-            matches = await self.retain_service.match(
-                self.client_info.tenant_id,
-                list(sub.matcher.filter_levels), limit)
-        except Exception:  # noqa: BLE001 — retain backend failure
-            log.exception("retain match failed")
-            # ≈ MatchRetainError: the SUBSCRIBE itself stays granted
-            self.events.report(Event(
-                EventType.MATCH_RETAIN_ERROR, self.client_info.tenant_id,
-                {"filter": sub.matcher.mqtt_topic_filter}))
-            return
-        if matches:
-            self.events.report(Event(
-                EventType.RETAIN_MSG_MATCHED, self.client_info.tenant_id,
-                {"filter": sub.matcher.mqtt_topic_filter,
-                 "count": len(matches)}))
-        for topic, msg in matches:
-            await self._send_publish(topic, msg, sub, retained=True)
+        with trace.span("sub.retained", tenant=self.client_info.tenant_id):
+            limit = self.settings[Setting.RetainMessageMatchLimit]
+            try:
+                matches = await self.retain_service.match(
+                    self.client_info.tenant_id,
+                    list(sub.matcher.filter_levels), limit)
+            except Exception:  # noqa: BLE001 — retain backend failure
+                log.exception("retain match failed")
+                # ≈ MatchRetainError: the SUBSCRIBE itself stays granted
+                self.events.report(Event(
+                    EventType.MATCH_RETAIN_ERROR, self.client_info.tenant_id,
+                    {"filter": sub.matcher.mqtt_topic_filter}))
+                return
+            if matches:
+                self.events.report(Event(
+                    EventType.RETAIN_MSG_MATCHED, self.client_info.tenant_id,
+                    {"filter": sub.matcher.mqtt_topic_filter,
+                     "count": len(matches)}))
+            # what an earlier SUBSCRIBE of this filter left queued is void
+            self._discard_retained(sub.matcher.mqtt_topic_filter)
+            backlog = self._retained_backlog
+            for topic, msg in matches:
+                # behind a backlog, or on a full window: wait in line
+                if not backlog and await self._send_publish(
+                        topic, msg, sub, retained=True) is not BLOCKED:
+                    continue
+                backlog.append((topic, msg, sub))
+                trace.count("retain.deliver.deferred")
+
+    async def _drain_retained(self) -> None:
+        """Send the retained backlog, in order, while the window has room;
+        a subscription that ended (or was made anew) meanwhile takes its
+        queued messages with it."""
+        backlog = self._retained_backlog
+        while backlog and not self.closed:
+            topic, msg, sub = entry = backlog.popleft()
+            if self.subscriptions.get(sub.matcher.mqtt_topic_filter) \
+                    is not sub:
+                continue
+            if await self._send_publish(topic, msg, sub,
+                                        retained=True) is BLOCKED:
+                backlog.appendleft(entry)
+                return
+
+    def _discard_retained(self, tf: str) -> None:
+        """Drop what is still queued for the subscription of ``tf``."""
+        backlog = self._retained_backlog
+        kept = [e for e in backlog if e[2].matcher.mqtt_topic_filter != tf]
+        if len(kept) != len(backlog):
+            backlog.clear()
+            backlog.extend(kept)
 
     # ------- on-behalf management surface (≈ SessionDictService sub/unsub/
     # inboxState, SessionDictService.proto:38-40) -----------------------------
@@ -1022,6 +1065,7 @@ class Session:
         sub = self.subscriptions.pop(tf, None)
         if sub is None:
             return "no_sub"
+        self._discard_retained(tf)
         await self._unroute(sub)
         return "ok"
 
@@ -1072,6 +1116,7 @@ class Session:
         sub = self.subscriptions.pop(tf, None)
         if sub is None:
             return ReasonCode.NO_SUBSCRIPTION_EXISTED if v5 else 0
+        self._discard_retained(tf)
         await self._unroute(sub)
         return ReasonCode.SUCCESS
 
@@ -1343,7 +1388,8 @@ class Session:
         if self._recv_quota.has_room(len(self._outbound)):
             pid = self._pid_alloc.alloc()
         if pid is None:
-            if self._drop_on_recv_max:
+            # a retained message is not dropped: _deliver_retained queues it
+            if self._drop_on_recv_max and not retained:
                 dropped = (EventType.QOS1_DROPPED if qos == 1
                            else EventType.QOS2_DROPPED)
                 self.events.report(Event(dropped,

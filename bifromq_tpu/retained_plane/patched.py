@@ -415,9 +415,9 @@ class RetainedPatchableTrie(PatchableTrie):
     @property
     def pristine(self) -> bool:
         """True when no patch-era slots or tombstones exist — the state
-        in which base subtree ranges alone are exhaustive and exact (the
-        native escalation walker and range-level ``limit`` clipping are
-        only valid here)."""
+        in which base subtree ranges alone are exhaustive and exact (with
+        tombstones only they are exhaustive: the expander filters the
+        dead slots, ``expansion_budget`` gives the head-room)."""
         return self.extra_live == 0 and self.dead_slots == 0
 
     def expansion_budget(self) -> int:

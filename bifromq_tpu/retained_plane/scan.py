@@ -16,7 +16,8 @@ the same serving discipline the forward matcher earned over PRs 6–11:
   admits ONE canary scan that re-closes only on oracle parity,
 - results memoize in a filter-keyed :class:`RetainedScanCache` whose
   evictions are EXACT, fed per-mutation by the retained delta hooks,
-- every batch lands a ``retain.scan`` span + stage sample and the
+- every batch lands a ``retain.scan`` span (the serve's call -> rows
+  filled) + stage sample, ``retain.scan.queries`` / ``.cache_hits``, and the
   per-tenant latency/fanout feed ``TenantSLO`` (the ISSUE 13 satellite
   bugfix: retained scans used to bypass the RED windows entirely).
 """
@@ -114,7 +115,8 @@ class RetainedScanPlane:
         blocking (a plain list means the serve completed)."""
         if not queries:
             return []
-        t0 = time.perf_counter()
+        t0, t0_ns = time.perf_counter(), time.monotonic_ns()
+        ctx = trace.current_ctx()
         self.scans_total += len(queries)
         cache = self.cache
         out: List[Optional[List[str]]] = [None] * len(queries)
@@ -131,6 +133,10 @@ class RetainedScanPlane:
                 if cache is not None and tenant not in tokens:
                     tokens[tenant] = cache.token(tenant)
         miss_queries = [queries[qi] for qi in miss_rows]
+        trace.count("retain.scan.queries", len(queries))
+        if len(miss_rows) < len(queries):
+            trace.count("retain.scan.cache_hits",
+                        len(queries) - len(miss_rows))
         front_s = time.perf_counter() - t0
         miss_set = set(miss_rows)
 
@@ -143,12 +149,14 @@ class RetainedScanPlane:
                               tokens[tenant])
             dt = time.perf_counter() - t0
             STAGES.record("retain.scan", dt)
-            with trace.span("retain.scan", n_queries=len(queries),
-                            misses=len(miss_rows), limit=limit) as sp:
-                if reason is not None:
-                    self.degraded_total[reason] = \
-                        self.degraded_total.get(reason, 0) + 1
-                    sp.set_tag("degraded", reason)
+            tags = {"n_queries": len(queries), "misses": len(miss_rows),
+                    "limit": limit}
+            if reason is not None:
+                self.degraded_total[reason] = \
+                    self.degraded_total.get(reason, 0) + 1
+                tags["degraded"] = reason
+            trace.record_finished("retain.scan", ctx, start_ns=t0_ns,
+                                  end_ns=time.monotonic_ns(), tags=tags)
             # ISSUE 13 satellite bugfix: retained scans feed the tenant
             # RED windows like deliver.fanout does — latency per scanned
             # tenant, achieved retained fan-out into the fanout share.
@@ -297,6 +305,7 @@ class RetainedScanPlane:
                         exc)
         from ..utils.metrics import FABRIC, FabricMetric
         FABRIC.inc(FabricMetric.MATCH_DEGRADED, len(queries))
+        trace.count("retain.rows.oracle", len(queries))
         rows = (rows_override if rows_override is not None
                 else self._oracle_rows(queries, limit))
         return rows, reason

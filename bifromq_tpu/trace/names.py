@@ -244,10 +244,36 @@ _row("repl.audit", "span", "obs/audit.py",
      "arena scope folded into the delta stream; the stage counts only "
      "audits that emitted", stage="repl.audit", by_hand=True)
 _row("retain.scan", "span", "retained_plane/scan.py",
-     "one retained wildcard-scan batch on SUBSCRIBE, tagged `degraded` "
-     "on oracle serves (a marker span closed when the scan completes; the "
-     "site feeds the stage and each scanned tenant's window per query)",
-     stage="retain.scan", by_hand=True)
+     "one retained wildcard-scan batch on SUBSCRIBE, from the serve's "
+     "call (cache probe) to its rows filled, tagged `degraded` on oracle "
+     "serves (deferred span; the site feeds the stage and each scanned "
+     "tenant's window per query)", stage="retain.scan", by_hand=True)
+_row("retain.scan.queries", "counter", "retained_plane/scan.py",
+     "filters handed to the scan plane (beside `retain.scan`)")
+_row("retain.scan.cache_hits", "counter", "retained_plane/scan.py",
+     "of those, the filters the filter-keyed scan cache answered: they "
+     "reach no walk")
+_row("retain.scan.walks", "counter", "models/retained.py",
+     "retained walks dispatched on the device (one a scan batch that "
+     "missed the cache)")
+_row("retain.rows.device", "counter", "models/retained.py",
+     "walked filter rows the device walk answered (its ranges expanded "
+     "on the host)")
+_row("retain.rows.native", "counter", "models/retained.py",
+     "walked rows the device flagged (a `+` frontier past its states) "
+     "that the native walker answered over the same tables")
+_row("retain.rows.oracle", "counter", "models/retained.py, "
+     "retained_plane/scan.py",
+     "rows the exact host oracle answered (`match_filter_host`): flagged "
+     "rows while patch-era extras exist, unhashable filters, degraded "
+     "scans")
+_row("sub.retained", "span", "mqtt/session.py",
+     "one SUBSCRIBE's retained delivery inside `sub.route`: the retain "
+     "service's match -> the last message handed to the send path or "
+     "queued")
+_row("retain.deliver.deferred", "counter", "mqtt/session.py",
+     "retained messages a SUBSCRIBE matched that found the send window "
+     "full and were queued, to be sent as PUBACKs free packet ids")
 _row("inbox.drain", "span", "mqtt/persistent.py",
      "a persistent session's catch-up drain at reconnect, tagged "
      "`fetched`", stage="inbox.drain", window="inbox.drain")
