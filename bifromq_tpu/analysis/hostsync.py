@@ -79,7 +79,7 @@ HOT_SCOPES: Dict[str, Set[str]] = {
     # the retained twin of the matcher's designated _fetch_walk readback
     "models/retained.py": {"RetainedIndex.dispatch_scan",
                            "RetainedIndex.flush_device"},
-    "ops/retained.py": {"retained_walk", "retained_walk_ext",
+    "ops/retained.py": {"_bucket_lookup", "retained_walk_ext",
                         "patch_retained_tables", "_patch_retained"},
     "retained_plane/scan.py": {"RetainedScanPlane._device_serve_async"},
 }

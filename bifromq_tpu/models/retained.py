@@ -377,7 +377,6 @@ class RetainedIndex:
         # entirely — emitted ids must expand against THIS world
         prep.recv = self._receiver_arr
         res = retained_walk_ext(self._device_tables, prep.probes,
-                                probe_len=prep.ct.probe_len,
                                 k_states=k_states or self.k_states)
         trace.count("retain.scan.walks")
         return prep, res
@@ -396,7 +395,6 @@ class RetainedIndex:
         self.refresh()
         self.flush_device()
         res = retained_walk_ext(self._device_tables, probes,
-                                probe_len=self._compiled.probe_len,
                                 k_states=k_states or self.k_states)
         return res.start, res.overflow
 

@@ -7,8 +7,10 @@ the automaton stores *concrete retained topics*; probes are SUBSCRIBE
 bifromq-retain .../store/RetainStoreCoProc.batchMatch with
 RetainTopicIndex.java:35 + RetainMatcher.java:36 semantics.
 
-Per probe level:
-- literal  → the same two-bucket edge lookup as ops.match
+One program, :func:`retained_walk_ext`. Per probe level:
+- literal  → the same single-choice bucket and hit compare as
+             ops.match._edge_lookup, gathered straight from the resident
+             [NB, P, 4] edge table (:func:`_bucket_lookup`)
 - '+'      → expand to ALL literal children of every active node (a CSR
              range read + cumsum-partitioned compaction; overflow → host)
 - '#'      → terminal: every active node's whole DFS subtree matches; with
@@ -39,7 +41,26 @@ from ..models.automaton import (
     KIND_PLUS, NODE_CCOUNT, NODE_CSTART, NODE_RCOUNT, NODE_RSTART,
     NODE_SUB_RCOUNT, NODE_SYS_CCOUNT, NODE_SYS_SLOTS, TokenizedFilters,
 )
-from .match import DeviceTrie, _edge_lookup
+from .match import _mix_u32
+
+
+def _bucket_lookup(edge_tab: jax.Array, node: jax.Array, h1: jax.Array,
+                   h2: jax.Array) -> jax.Array:
+    """Exact literal-child lookup; node/h1/h2 are [B,K]; returns child or -1.
+
+    The answer of ops.match._edge_lookup (same bucket, same compare), but
+    the bucket rows are gathered from the [NB, P, 4] table as it lies in
+    HBM. Viewing it as [NB, P*4] first makes XLA re-lay-out the whole
+    table (bucket index minor on the chip) on every loop step: 6.2 of a
+    7.6 ms walk at 1,048,576 buckets on v5e.
+    """
+    mask = jnp.uint32(edge_tab.shape[0] - 1)
+    b1 = (_mix_u32(node, h1, h2) & mask).astype(jnp.int32)
+    rows = edge_tab[b1]                                      # [B,K,P,4]
+    hit = ((rows[..., 0] == node[..., None])
+           & (rows[..., 1] == h1[..., None])
+           & (rows[..., 2] == h2[..., None]))
+    return jnp.max(jnp.where(hit, rows[..., 3], -1), axis=-1)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -64,97 +85,6 @@ class FilterProbes:
         put = functools.partial(jax.device_put, device=device)
         return FilterProbes(put(t.tok_h1), put(t.tok_h2), put(t.tok_kind),
                             put(t.lengths), put(t.roots))
-
-
-@functools.partial(jax.jit, static_argnames=("probe_len", "k_states"))
-def retained_walk(trie: DeviceTrie, probes: FilterProbes, *, probe_len: int,
-                  k_states: int = 32) -> Tuple[jax.Array, jax.Array]:
-    """Returns (ranges [B, K, 2] int32 (slot_start, slot_count), overflow [B]).
-
-    Ranges with count <= 0 are empty. Padding probes produce no ranges.
-    """
-    b, width = probes.tok_h1.shape
-    max_levels = width - 1
-    k = k_states
-    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
-
-    act0 = jnp.full((b, k), -1, dtype=jnp.int32)
-    act0 = act0.at[:, 0].set(jnp.where(probes.lengths >= 0, probes.roots, -1))
-    ranges0 = jnp.zeros((b, k, 2), dtype=jnp.int32)
-    overflow0 = jnp.zeros((b,), dtype=bool)
-
-    def body(i, carry):
-        act, ranges, overflow = carry
-        valid = act >= 0                                     # [B,K]
-        stepping = (i < probes.lengths)[:, None]
-        node_rec = trie.node_tab[act.clip(0)]                # [B,K,12]
-        kind = jax.lax.dynamic_index_in_dim(probes.tok_kind, i, axis=1)  # [B,1]
-        at_root = i == 0  # active set == {root} only before the first step
-
-        # ---- '#': emit subtree slot ranges and stop this probe -------------
-        is_hash = stepping & (kind == KIND_HASH)
-        sys_skip = jnp.where(at_root, node_rec[..., NODE_RCOUNT]
-                             + node_rec[..., NODE_SYS_SLOTS], 0)
-        h_start = node_rec[..., NODE_RSTART] + sys_skip
-        h_count = node_rec[..., NODE_SUB_RCOUNT] - sys_skip
-        hash_ranges = jnp.stack([h_start, jnp.where(valid, h_count, 0)],
-                                axis=-1)
-        ranges = jnp.where((is_hash & valid)[..., None], hash_ranges, ranges)
-
-        # ---- final level consumed: emit own-slot ranges ---------------------
-        is_final = (i == probes.lengths)[:, None]
-        own = jnp.stack([node_rec[..., NODE_RSTART],
-                         jnp.where(valid, node_rec[..., NODE_RCOUNT], 0)],
-                        axis=-1)
-        ranges = jnp.where((is_final & valid)[..., None], own, ranges)
-
-        # ---- successors -----------------------------------------------------
-        live = stepping & (kind != KIND_HASH) & valid
-        # literal
-        h1 = jnp.broadcast_to(
-            jax.lax.dynamic_index_in_dim(probes.tok_h1, i, axis=1), (b, k))
-        h2 = jnp.broadcast_to(
-            jax.lax.dynamic_index_in_dim(probes.tok_h2, i, axis=1), (b, k))
-        exact = _edge_lookup(trie.edge_tab, probe_len, act.clip(0), h1, h2)
-        exact = jnp.where(live & (kind == KIND_LIT), exact, -1)
-
-        # '+': expand all children of all active nodes via cumsum partition
-        sys_cskip = jnp.where(at_root, node_rec[..., NODE_SYS_CCOUNT], 0)
-        c_start = node_rec[..., NODE_CSTART] + sys_cskip
-        c_count = jnp.where(live & (kind == KIND_PLUS),
-                            node_rec[..., NODE_CCOUNT] - sys_cskip, 0)
-        offsets = jnp.cumsum(c_count, axis=1)                # [B,K] inclusive
-        total = offsets[:, -1]
-        overflow = overflow | (total > k)
-        slot_ids = jnp.arange(k, dtype=jnp.int32)[None, :]   # [1,K]
-        # source state j for output slot s: first j with offsets[j] > s
-        src = jnp.sum(offsets[:, None, :] <= slot_ids[..., None],
-                      axis=-1)                               # [B,K]
-        src_c = src.clip(0, k - 1)
-        base = jnp.take_along_axis(offsets, src_c, axis=1) \
-            - jnp.take_along_axis(c_count, src_c, axis=1)
-        within = slot_ids - base
-        list_idx = (jnp.take_along_axis(c_start, src_c, axis=1) + within)
-        plus_kids = trie.child_list[
-            list_idx.clip(0, trie.child_list.shape[0] - 1)]
-        plus_kids = jnp.where(slot_ids < total[:, None], plus_kids, -1)
-
-        is_plus_row = kind == KIND_PLUS                      # [B,1]
-        cand = jnp.where(is_plus_row, plus_kids, exact)      # [B,K]
-        # compact (exact path produces at most one successor per state but
-        # holes remain; reuse the scatter-drop compaction)
-        cvalid = cand >= 0
-        pos = jnp.cumsum(cvalid, axis=1) - 1
-        pos = jnp.where(cvalid & (pos < k), pos, 2 * k)
-        new_act = jnp.full((b, k), -1, dtype=jnp.int32)
-        new_act = new_act.at[rows, pos].set(cand, mode="drop")
-        return new_act, ranges, overflow
-
-    upper = jnp.clip(jnp.max(probes.lengths, initial=-1) + 1, 0,
-                     max_levels + 1)
-    act, ranges, overflow = jax.lax.fori_loop(
-        0, upper, body, (act0, ranges0, overflow0))
-    return ranges, overflow
 
 
 # ---------------- patched retained tables & extras-aware walk (ISSUE 13) ----
@@ -230,16 +160,16 @@ class RetainedScanResult:
         return cls(*children)
 
 
-@functools.partial(jax.jit, static_argnames=("probe_len", "k_states"))
+@functools.partial(jax.jit, static_argnames=("k_states",))
 def retained_walk_ext(tables: RetainedDeviceTables, probes: FilterProbes,
-                      *, probe_len: int, k_states: int = 32
-                      ) -> RetainedScanResult:
-    """The extras-aware twin of :func:`retained_walk`.
+                      *, k_states: int = 32) -> RetainedScanResult:
+    """The retained walk over the compiled tables and their extras plane.
 
     Returns base slot ranges, extras index ranges (resolved through
     ``extra_list`` host-side) and the overflow flags, all [B, K, ...].
-    Shares the '#'/'+'/final semantics with retained_walk; the only
-    additions are the 16B ext-row gather and the second emission pair.
+    Ranges with count <= 0 are empty; padding probes produce no ranges.
+    Beside the base ranges, each active state gathers one 16B ext row
+    and emits a second (start, count) pair.
     """
     b, width = probes.tok_h1.shape
     max_levels = width - 1
@@ -291,13 +221,13 @@ def retained_walk_ext(tables: RetainedDeviceTables, probes: FilterProbes,
         ext_ranges = jnp.where((is_final & valid)[..., None], own_ext,
                                ext_ranges)
 
-        # ---- successors (identical to retained_walk) ----------------------
+        # ---- successors -----------------------------------------------------
         live = stepping & (kind != KIND_HASH) & valid
         h1 = jnp.broadcast_to(
             jax.lax.dynamic_index_in_dim(probes.tok_h1, i, axis=1), (b, k))
         h2 = jnp.broadcast_to(
             jax.lax.dynamic_index_in_dim(probes.tok_h2, i, axis=1), (b, k))
-        exact = _edge_lookup(tables.edge_tab, probe_len, act.clip(0), h1, h2)
+        exact = _bucket_lookup(tables.edge_tab, act.clip(0), h1, h2)
         exact = jnp.where(live & (kind == KIND_LIT), exact, -1)
 
         sys_cskip = jnp.where(at_root, node_rec[..., NODE_SYS_CCOUNT], 0)

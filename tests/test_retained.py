@@ -117,6 +117,77 @@ class TestRetainedIndex:
         assert idx.match("t2", ["a"]) == ["a"]
 
 
+@pytest.mark.parametrize("seed", [0, 1])
+def test_bucket_lookup_matches_edge_lookup(seed):
+    """The retained walk's direct bucket gather answers every key as the
+    publish walk's [NB, P*4] view does: hits, absent keys (some of them
+    hashing into a FULL bucket), near misses (a stored key with one
+    column changed, in the stored key's bucket), and the dead states'
+    ``act.clip(0)`` lanes, which probe node 0 with the level's hashes."""
+    import jax.numpy as jnp
+    import numpy as np
+    from bifromq_tpu.models import automaton as A
+    from bifromq_tpu.ops import match as M
+    from bifromq_tpu.ops import retained as R
+
+    nb, p = 256, 16
+    rng = np.random.default_rng(seed)
+    mask = np.uint32(nb - 1)
+
+    def bucket(keys):
+        return (A._mix_u32(keys[:, 0], keys[:, 1], keys[:, 2])
+                & mask).astype(np.int64)
+
+    cand = np.concatenate([
+        rng.integers(0, 64, (20_000, 1)),
+        rng.integers(-2 ** 31, 2 ** 31, (20_000, 2))], axis=1).astype(
+            np.int32)
+    cand = np.unique(cand, axis=0)
+    cand = cand[rng.permutation(len(cand))]
+    per = np.zeros(nb, dtype=np.int64)
+    edges = {}
+    for key, b1 in zip(cand, bucket(cand)):
+        if per[b1] < (p if b1 == 0 else 6):    # bucket 0 fills up
+            per[b1] += 1
+            edges[tuple(int(v) for v in key)] = len(edges) + 1
+    tab = A._build_edge_table([k + (c,) for k, c in edges.items()], p,
+                              min_cap=nb)
+    assert tab.shape == (nb, p, 4)
+    assert (tab[0, :, 0] != A._EMPTY).all()    # one full bucket
+    stored = np.array(list(edges), dtype=np.int32)
+    absent = cand[~np.isin(cand.view([("", cand.dtype)] * 3),
+                           stored.view([("", stored.dtype)] * 3)).ravel()]
+    assert (bucket(absent) == 0).any()
+    hits = stored[rng.choice(len(stored), 256)]
+    missing = absent[rng.choice(len(absent), 256)]
+    dead = np.concatenate([np.zeros((64, 1), np.int32),
+                           hits[:64, 1:]], axis=1)       # clip(0) lanes
+    node0 = stored[stored[:, 0] == 0][:64]
+    # near misses: a stored key with ONE column changed, in the same bucket
+    near = []
+    for col in range(3):
+        base = np.repeat(stored[:32], 2048, axis=0)
+        lo, hi = (0, 64) if col == 0 else (-2 ** 31, 2 ** 31)
+        base[:, col] = rng.integers(lo, hi, len(base))
+        same = (bucket(base) == np.repeat(bucket(stored[:32]), 2048)) \
+            & (base[:, col] != np.repeat(stored[:32, col], 2048))
+        near.append(base[same][:32])
+    near = np.concatenate(near)
+    assert not any(tuple(int(v) for v in k) in edges for k in near)
+    keys = np.concatenate([hits, missing, dead, node0, near])
+    keys = keys[rng.permutation(len(keys))]
+    keys = keys[: len(keys) // 32 * 32].reshape(-1, 32, 3)   # [B,K,3]
+    node, h1, h2 = (jnp.asarray(keys[..., i]) for i in range(3))
+    dev = jnp.asarray(tab)
+    got = np.asarray(R._bucket_lookup(dev, node, h1, h2))
+    want = np.asarray(M._edge_lookup(dev, p, node, h1, h2))
+    truth = np.array([edges.get(tuple(int(v) for v in k), -1)
+                      for k in keys.reshape(-1, 3)]).reshape(got.shape)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got, truth)
+    assert (got > 0).sum() >= 200 and (got == -1).sum() >= 200
+
+
 def mk_msg(payload=b"x", expiry=0xFFFFFFFF):
     return Message(message_id=0, pub_qos=QoS.AT_MOST_ONCE, payload=payload,
                    timestamp=0, expiry_seconds=expiry, is_retain=True)

@@ -239,3 +239,41 @@ def test_mesh_shard_scatter(mesh4, no_compile_cache, record_property):
                             _spec((8,), i32, rep), _spec((8, 8), i32, rep),
                             shard=2).compile()
         _fits(compiled, record_property, "mesh shard scatter")
+
+
+@pytest.mark.parametrize("n_buckets,n_nodes", [(1_048_576, 4_194_304),
+                                               (2_097_152, 8_388_608)],
+                         ids=["2.5M", "5M"])
+def test_retained_walk_reads_resident_edge_table(one_chip, no_compile_cache,
+                                                 record_property, n_buckets,
+                                                 n_nodes):
+    """The retained walk at the retained topics' shapes (2.5M and 5M
+    Homie topics: the arenas ``RetainedIndex`` pads them to) gathers its
+    edge buckets from the [NB, P, 4] table as it lies in HBM: no copy,
+    reshape or transpose of a table-sized operand, and next to no temp.
+    A [NB, P*4] view of the table re-lays it out inside the walk's loop
+    (805,919,232 temp bytes at 2.5M)."""
+    import re
+    import jax.numpy as jnp
+    from bifromq_tpu.models.automaton import EXT_COLS, NODE_COLS
+    from bifromq_tpu.ops import retained as R
+    i32 = jnp.int32
+    b = 16
+    tables = R.RetainedDeviceTables(
+        node_tab=_spec((n_nodes, NODE_COLS), i32, one_chip),
+        edge_tab=_spec((n_buckets, PROBE_LEN, 4), i32, one_chip),
+        child_list=_spec((n_nodes,), i32, one_chip),
+        ext_tab=_spec((n_nodes, EXT_COLS), i32, one_chip),
+        extra_list=_spec((64,), i32, one_chip))
+    probes = R.FilterProbes(
+        _spec((b, WIDTH), i32, one_chip), _spec((b, WIDTH), i32, one_chip),
+        _spec((b, WIDTH), i32, one_chip), _spec((b,), i32, one_chip),
+        _spec((b,), i32, one_chip))
+    compiled = R.retained_walk_ext.lower(tables, probes,
+                                         k_states=32).compile()
+    ma = _fits(compiled, record_property, f"retained walk NB={n_buckets}")
+    relayouts = [line.strip() for line in compiled.as_text().splitlines()
+                 if re.search(r"= \S+\[%d[,\]]\S* (copy|reshape|transpose)\("
+                              % n_buckets, line)]
+    assert not relayouts, relayouts[:4]
+    assert ma.temp_size_in_bytes < 64 * 1024 ** 2, ma.temp_size_in_bytes
